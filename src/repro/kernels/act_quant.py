@@ -11,6 +11,12 @@ pipeline over each local quantization region in one VMEM pass:
 Block: (bm, K) rows -- a row's regions are contiguous along K, so one block
 holds whole regions and the reductions stay in-registers.  Outputs:
 packed (M, K/cpb) uint8, scale (M, G) f32, zmin (M, G) f32.
+
+Packing runs on the MXU: ``cpb`` adjacent codes fold into one byte as
+``sum_i code_i * 2^(i*bits)``, a matmul with a 0/power-of-two matrix over
+each ``128*cpb``-lane chunk.  Codes and weights are exact in bf16 and the
+sums (< 256) exact in the f32 accumulator, so the bytes match
+``core/packing.pack`` bit for bit with no lane interleave in the kernel.
 """
 from __future__ import annotations
 
@@ -34,15 +40,24 @@ def _kernel(x_ref, p_ref, s_ref, z_ref, *, bits: int, group_size: int):
     rng = xmax - xmin
     scale = jnp.where(rng > 0, rng / levels, jnp.ones_like(rng))
     codes = jnp.clip(jnp.round((xg - xmin[..., None]) / scale[..., None]),
-                     0, levels).astype(jnp.int32).reshape(bm, k)
-    if bits in packing.PACKABLE_BITS:
-        cpb = packing.codes_per_byte(bits)
-        c = codes.reshape(bm, k // cpb, cpb)
-        shifts = jnp.arange(cpb, dtype=jnp.int32) * bits
-        packed = (c << shifts[None, None, :]).sum(axis=-1)
+                     0, levels).reshape(bm, k)
+    cpb = packing.codes_per_byte(bits)
+    if cpb == 1:
+        p_ref[...] = codes.astype(jnp.int32).astype(jnp.uint8)
     else:
-        packed = codes
-    p_ref[...] = packed.astype(jnp.uint8)
+        cw = min(k, 128 * cpb)                          # codes per chunk
+        lane = jax.lax.broadcasted_iota(jnp.int32, (cw, cw // cpb), 0)
+        byte = jax.lax.broadcasted_iota(jnp.int32, (cw, cw // cpb), 1)
+        fold = jnp.where(lane // cpb == byte,
+                         jnp.left_shift(1, (lane % cpb) * bits), 0)
+        fold = fold.astype(jnp.bfloat16)
+        for c in range(k // cw):
+            chunk = codes[:, c * cw:(c + 1) * cw].astype(jnp.bfloat16)
+            packed = jax.lax.dot_general(
+                chunk, fold, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            p_ref[:, c * cw // cpb:(c + 1) * cw // cpb] = \
+                packed.astype(jnp.int32).astype(jnp.uint8)
     s_ref[...] = scale
     z_ref[...] = xmin
 

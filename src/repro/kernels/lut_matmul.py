@@ -21,7 +21,9 @@ Grid: (M/bm, N/bn, G) with G = K / group_size -- one local region per K step.
 
 Block shapes:
   codes (bm, group_size) uint8 (unpacked codes)
-  scale (bm, 1) f32 ; zmin (bm, 1) f32     (this region's affine, per row)
+  scale (1, bm, 1) f32 ; zmin (1, bm, 1) f32   (this region's affine, per
+        row; the wrapper lays them out (G, M, 1) so a region's column is a
+        full-lane block rather than a 1-lane slice of (M, G))
   w     (group_size, bn)
   out   (bm, bn)  f32 accumulation across regions
 """
@@ -35,7 +37,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import packing
-from .pallas_compat import CompilerParams as _CompilerParams
 
 
 def _kernel(c_ref, s_ref, z_ref, w_ref, o_ref, acc_ref, *,
@@ -46,8 +47,8 @@ def _kernel(c_ref, s_ref, z_ref, w_ref, o_ref, acc_ref, *,
 
     codes = c_ref[...].astype(jnp.int32)            # (bm, gs)
     w = w_ref[...].astype(jnp.float32)              # (gs, bn)
-    s = s_ref[...]                                  # (bm, 1)
-    z = z_ref[...]
+    s = s_ref[0]                                    # (bm, 1)
+    z = z_ref[0]
 
     # table build + combine: sum_v v * (mask_v @ W), v = 1 .. 2^bits-1
     # (v = 0 contributes nothing -- the paper's same skip, section V.C)
@@ -97,20 +98,21 @@ def lut_matmul(a_packed, a_scale, a_zmin, w, *, bits: int, group_size: int,
         a_scale = jnp.pad(a_scale, ((0, mp - m), (0, 0)))
         a_zmin = jnp.pad(a_zmin, ((0, mp - m), (0, 0)))
     w_p = jnp.pad(w, ((0, 0), (0, np_ - n))) if np_ != n else w
+    a_scale, a_zmin = (a.T[:, :, None] for a in (a_scale, a_zmin))
 
     out = pl.pallas_call(
         functools.partial(_kernel, bits=bits, g_steps=g),
         grid=(mp // bm, np_ // bn, g),
         in_specs=[
             pl.BlockSpec((bm, group_size), lambda i, j, r: (i, r)),
-            pl.BlockSpec((bm, 1), lambda i, j, r: (i, r)),
-            pl.BlockSpec((bm, 1), lambda i, j, r: (i, r)),
+            pl.BlockSpec((1, bm, 1), lambda i, j, r: (r, i, 0)),
+            pl.BlockSpec((1, bm, 1), lambda i, j, r: (r, i, 0)),
             pl.BlockSpec((group_size, bn), lambda i, j, r: (r, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, r: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name=f"lut_matmul_b{bits}g{group_size}",

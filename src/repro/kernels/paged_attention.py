@@ -12,8 +12,10 @@ traffic is the wire bytes, once.
 
 Dequant paths per page (``dequant=``):
 
-  "affine"  unpack codes, ``k = codes * scale + zmin`` per local region,
-            then the q@k / p@v matmuls — the throughput path, any bits.
+  "affine"  unpack codes and apply ``k = codes * scale + zmin`` per local
+            region by algebra: one matmul on the codes per region, the
+            scale and zmin applied to its result — the throughput path,
+            any bits.
   "lut"     bits <= 4: the paper's Table-Lookup trick (section V) applied
             to attention, reusing the ``core/lut.py`` /
             ``kernels/lut_matmul.py`` masked-matmul dataflow.  With n-bit
@@ -27,20 +29,28 @@ Dequant paths per page (``dequant=``):
   "auto"    "lut" when the pool is quantized at bits <= 4, else "affine"
             ("fp" pools skip dequant entirely).
 
-Grid ``(B, KV, P)`` — batch and kv-head parallel, the page axis sequential
-("arbitrary") so the m/l/acc VMEM scratch carries the running softmax state
-across pages.  Queries arrive as (B, Lq, KV, G, D) — GQA groups and the
+Grid ``(B, P)`` — batch parallel, the page axis sequential ("arbitrary")
+so the m/l/acc VMEM scratch carries the running softmax state across
+pages.  Each step reads one page as ``(page_size*KV, X)`` rows (a free
+reshape of the pool leaf), so every block is full-width in its last two
+dims.  Queries arrive as (B, Lq, KV, G, D); kv heads, GQA groups and the
 multi-query run (Lq = k+1, the speculative verify) flatten onto one
-(Lq*G, D) row block so both decode shapes share this kernel.  Masking
-matches ``decode_attention`` over the gathered view: key position
-``p*page_size + r`` is visible to query row i iff it is ``<= pos[b] + i``,
-which also hides scratch-padded table entries (their positions lie past the
-slot's live prefix) — an all-masked page contributes nothing because masked
-probabilities are forced to zero *after* the running-max update.
+(KV*Lq*G, D) row block, scored against every page row at once, with a
+head mask keeping each query row on its own kv head.  Masking matches
+``decode_attention`` over the gathered view: key position
+``p*page_size + t`` is visible to query row i iff it is ``<= pos[b] + i``,
+which also hides scratch-padded table entries (their positions lie past
+the slot's live prefix) — an all-masked page contributes nothing because
+masked probabilities are forced to zero *after* the running-max update.
 
-``interpret=True`` runs the same kernel on CPU (CI parity tests); real-TPU
-deployments should keep D and page_size lane/sublane aligned (see
-``quant_matmul.py`` for the padding idiom).
+Quantized pages unpack into ``cpb`` code planes by shift and mask (plane i
+holds feature ``cpb*j + i`` at lane j).  The wrapper hands the kernel its
+queries in the same plane order, stacked once per local region with the
+other regions' lanes zeroed, and restores feature order on the output, so
+the kernel never interleaves lanes.
+
+``interpret=True`` runs the same kernel on CPU (CI parity tests);
+``tests/test_chip_compile.py`` compiles it for a v5e.
 """
 from __future__ import annotations
 
@@ -52,10 +62,9 @@ import jax.numpy as jnp
 try:  # pallas is optional at import time: gate, don't crash (ROADMAP env)
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    from .pallas_compat import CompilerParams as _CompilerParams
     _PALLAS_ERR = None
 except Exception as e:  # pragma: no cover - exercised on pallas-less hosts
-    pl = pltpu = _CompilerParams = None
+    pl = pltpu = None
     _PALLAS_ERR = e
 
 NEG_INF = -1e30
@@ -73,8 +82,9 @@ def default_mode() -> str:
 
 
 def resolve_mode(fused: bool, *, obs=None) -> str | None:
-    """Map an engine flag to a kernel mode, falling back to the XLA
-    gather+dequant path (``None``) when Pallas is unavailable.
+    """Map an engine flag to a kernel mode.  Off the TPU, a missing Pallas
+    toolchain falls back to the XLA gather+dequant path (``None``); on a
+    TPU it is an error, never a quiet switch away from the kernel.
 
     A downgrade (fused requested, Pallas missing) is an SLO-relevant
     silent failure: when an enabled ``obs`` is passed, it is reported via
@@ -83,6 +93,9 @@ def resolve_mode(fused: bool, *, obs=None) -> str | None:
     if not fused:
         return None
     if not available():
+        if jax.default_backend() == "tpu":
+            raise RuntimeError("fused attention requested on a TPU but "
+                               f"Pallas failed to import: {_PALLAS_ERR!r}")
         report_fallback(obs)
         return None
     return default_mode()
@@ -120,36 +133,51 @@ def dequant_path(bits: int | None, dequant: str = "auto") -> str:
     return "lut" if lut else "affine"
 
 
-def _unpack(pk, bits: int, d: int):
-    """In-register unpack of uint8 lanes -> int32 codes (..., d).
+def _unpack_planes(pk, bits: int) -> list:
+    """In-register unpack of uint8 lanes -> ``cpb`` int32 code planes.
 
-    Must match ``core/packing.pack``: code j of a byte sits at shift
-    ``(j % cpb) * bits``.
+    Plane i holds code i of every byte (shift ``i * bits``, matching
+    ``core/packing.pack``), i.e. features ``d = cpb*j + i``.  The planes
+    are never interleaved back along lanes: the wrapper hands the kernel
+    queries in the same plane order and restores feature order on the
+    output, so the kernel needs no lane shuffle.
     """
+    p = pk.astype(jnp.int32)
     if bits == 8:
-        return pk.astype(jnp.int32)
-    cpb = 8 // bits
-    shifts = jnp.arange(cpb, dtype=jnp.int32) * bits
-    vals = (pk.astype(jnp.int32)[..., None] >> shifts) & ((1 << bits) - 1)
-    return vals.reshape(*pk.shape[:-1], pk.shape[-1] * cpb)
+        return [p]
+    mask = (1 << bits) - 1
+    return [(p >> (i * bits)) & mask for i in range(8 // bits)]
 
 
-def _row_positions(lqg: int, gq: int, page_size: int, pos_b, p):
-    """(allowed (LqG, ps)) mask for this page: key pos <= query pos."""
-    row = jax.lax.broadcasted_iota(jnp.int32, (lqg, 1), 0)
-    qpos = pos_b + row // gq                                   # (LqG, 1)
-    spos = p * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, page_size), 1)                          # (1, ps)
-    return spos <= qpos
+def _dot_t(a, b):
+    """a (M, K) @ b (N, K)^T in f32."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
 
 
-def _online_step(s, allowed, acc_ref, m_ref, l_ref, pv_fn):
-    """One flash-decode page update; returns nothing (scratch in place).
+def _dot(a, b):
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
 
-    ``pv_fn(pmat)`` produces the page's (LqG, D) probability-weighted
-    values.  Masked probabilities are zeroed AFTER the max update: an
-    all-masked page has m == NEG_INF and exp(s - m) == 1 there, which
-    would otherwise poison l with phantom mass.
+
+def _allowed(nq: int, rows: int, *, lqg: int, gq: int, kvh: int,
+             page_size: int, pos_b, p):
+    """(nq, rows) mask: query row ``h*lqg + l*gq + g`` may see page row
+    ``t*kvh + h'`` iff it is the same kv head and key pos <= query pos."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (nq, rows), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (nq, rows), 1)
+    qpos = pos_b + (row % lqg) // gq
+    kpos = p * page_size + col // kvh
+    return (row // lqg == col % kvh) & (kpos <= qpos)
+
+
+def _online_step(s, allowed, m_ref, l_ref):
+    """One flash-decode page update of the running max / denominator;
+    returns (probabilities, accumulator correction).
+
+    Masked probabilities are zeroed AFTER the max update: an all-masked
+    page has m == NEG_INF and exp(s - m) == 1 there, which would otherwise
+    poison l with phantom mass.
     """
     s = jnp.where(allowed, s, NEG_INF)
     m_prev = m_ref[...]
@@ -157,113 +185,100 @@ def _online_step(s, allowed, acc_ref, m_ref, l_ref, pv_fn):
     pmat = jnp.where(allowed, jnp.exp(s - m_new), 0.0)
     corr = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * corr + pmat.sum(axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + pv_fn(pmat)
     m_ref[...] = m_new
+    return pmat, corr
+
+
+def _init(p, acc_ref, m_ref, l_ref):
+    @pl.when(p == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+
+def _flush(p, p_steps, o_ref, acc_ref, l_ref):
+    @pl.when(p == p_steps - 1)
+    def _():
+        o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
 
 
 def _kernel_fp(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-               acc_ref, m_ref, l_ref, *, page_size: int, gq: int,
-               p_steps: int, sm_scale: float):
-    p = pl.program_id(2)
-
-    @pl.when(p == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    q = q_ref[0, 0].astype(jnp.float32)                        # (LqG, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)                  # (ps, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
-    allowed = _row_positions(q.shape[0], gq, page_size,
-                             pos_ref[pl.program_id(0)], p)
-    _online_step(s, allowed, acc_ref, m_ref, l_ref,
-                 lambda pmat: jax.lax.dot_general(
-                     pmat, v, (((1,), (0,)), ((), ())),
-                     preferred_element_type=jnp.float32))
-
-    @pl.when(p == p_steps - 1)
-    def _flush():
-        o_ref[0, 0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+               acc_ref, m_ref, l_ref, *, page_size: int, gq: int, lqg: int,
+               kvh: int, p_steps: int, sm_scale: float):
+    p = pl.program_id(1)
+    _init(p, acc_ref, m_ref, l_ref)
+    q = q_ref[0, 0]                                            # (nq, D)
+    k = k_ref[0].astype(jnp.float32)                           # (R, D)
+    v = v_ref[0].astype(jnp.float32)
+    s = _dot_t(q, k) * sm_scale
+    allowed = _allowed(q.shape[0], k.shape[0], lqg=lqg, gq=gq, kvh=kvh,
+                       page_size=page_size, pos_b=pos_ref[pl.program_id(0)],
+                       p=p)
+    pmat, corr = _online_step(s, allowed, m_ref, l_ref)
+    acc_ref[0] = acc_ref[0] * corr + _dot(pmat, v)
+    _flush(p, p_steps, o_ref, acc_ref, l_ref)
 
 
-def _kernel_quant(tbl_ref, pos_ref, q_ref, kp_ref, ks_ref, kz_ref,
+def _kernel_quant(tbl_ref, pos_ref, q_ref, qsum_ref, kp_ref, ks_ref, kz_ref,
                   vp_ref, vs_ref, vz_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                  bits: int, group_size: int, page_size: int, gq: int,
-                  p_steps: int, sm_scale: float, lut: bool, d: int):
-    p = pl.program_id(2)
+                  bits: int, gr: int, lut: bool, page_size: int, gq: int,
+                  lqg: int, kvh: int, p_steps: int, sm_scale: float):
+    p = pl.program_id(1)
+    _init(p, acc_ref, m_ref, l_ref)
+    nq = m_ref.shape[0]
+    codes_v = range(1, 1 << bits)                              # v=0 adds 0
 
-    @pl.when(p == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def code_matmul(mm, a, codes):
+        """``mm(a, codes)`` directly (affine), or as the section-V table:
+        sum_v v * mm(a, codes == v) — adds over binary matmuls."""
+        if not lut:
+            return mm(a, codes.astype(jnp.float32))
+        out = None
+        for vcode in codes_v:
+            t = jnp.float32(vcode) * mm(a, (codes == vcode).astype(
+                jnp.float32))
+            out = t if out is None else out + t
+        return out
 
-    gr = d // group_size                                       # regions
-    q = q_ref[0, 0].astype(jnp.float32)                        # (LqG, D)
-    lqg = q.shape[0]
-    k_codes = _unpack(kp_ref[0, :, 0, :], bits, d)             # (ps, D) i32
-    k_sc = ks_ref[0, :, 0, :]                                  # (ps, Gr)
-    k_zm = kz_ref[0, :, 0, :]
-    v_codes = _unpack(vp_ref[0, :, 0, :], bits, d)
-    v_sc = vs_ref[0, :, 0, :]
-    v_zm = vz_ref[0, :, 0, :]
-
-    if lut:
-        # table-lookup scores: s*sum_v v*(q_g @ mask_v) + zmin*(q row sums)
-        qg = q.reshape(lqg, gr, group_size)
-        qsum = qg.sum(axis=-1)                                 # (LqG, Gr)
-        kc = k_codes.reshape(page_size, gr, group_size)
-        code_dot = jnp.zeros((lqg, page_size, gr), jnp.float32)
-        for vcode in range(1, 1 << bits):                      # v=0 adds 0
-            mask_v = (kc == vcode).astype(jnp.float32)
-            code_dot += jnp.float32(vcode) * jnp.einsum(
-                "lgj,sgj->lsg", qg, mask_v,
-                preferred_element_type=jnp.float32)
-        s = (code_dot * k_sc[None]).sum(axis=-1) \
-            + jax.lax.dot_general(qsum, k_zm, (((1,), (1,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-    else:
-        kf = (k_codes.astype(jnp.float32)
-              .reshape(page_size, gr, group_size)
-              * k_sc[..., None] + k_zm[..., None]).reshape(page_size, d)
-        s = jax.lax.dot_general(q, kf, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+    # scores: per region r, scale_r * (q_r . codes) + zmin_r * sum(q_r).
+    # q_ref stacks the region-masked query rows (row r*nq + i holds query
+    # row i restricted to region r), so one matmul per plane gives every
+    # region's code dot at once.
+    k_sc_t = ks_ref[0].T                                       # (Gr, R)
+    cd = None
+    for i, codes in enumerate(_unpack_planes(kp_ref[0], bits)):
+        t = code_matmul(_dot_t, q_ref[0, i], codes)            # (Gr*nq, R)
+        cd = t if cd is None else cd + t
+    s = _dot_t(qsum_ref[0], kz_ref[0])                         # zmin terms
+    for r in range(gr):
+        s = s + cd[r * nq:(r + 1) * nq] * k_sc_t[r:r + 1]
     s = s * sm_scale
-    allowed = _row_positions(lqg, gq, page_size,
-                             pos_ref[pl.program_id(0)], p)
+    allowed = _allowed(nq, s.shape[1], lqg=lqg, gq=gq, kvh=kvh,
+                       page_size=page_size, pos_b=pos_ref[pl.program_id(0)],
+                       p=p)
+    pmat, corr = _online_step(s, allowed, m_ref, l_ref)
 
-    if lut:
-        vc = v_codes.reshape(page_size, gr, group_size)
-
-        def pv_fn(pmat):
-            # p@v per region: sum_v v*((p*scale) @ mask_v) + (p @ zmin)
-            ps_mat = pmat[:, :, None] * v_sc[None]             # (LqG,ps,Gr)
-            pv = jnp.zeros((lqg, gr, group_size), jnp.float32)
-            for vcode in range(1, 1 << bits):
-                mask_v = (vc == vcode).astype(jnp.float32)
-                pv += jnp.float32(vcode) * jnp.einsum(
-                    "lsg,sgj->lgj", ps_mat, mask_v,
-                    preferred_element_type=jnp.float32)
-            pz = jax.lax.dot_general(pmat, v_zm, (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            return (pv + pz[..., None]).reshape(lqg, d)
-    else:
-        vf = (v_codes.astype(jnp.float32)
-              .reshape(page_size, gr, group_size)
-              * v_sc[..., None] + v_zm[..., None]).reshape(page_size, d)
-
-        def pv_fn(pmat):
-            return jax.lax.dot_general(pmat, vf, (((1,), (0,)), ((), ())),
-                                       preferred_element_type=jnp.float32)
-
-    _online_step(s, allowed, acc_ref, m_ref, l_ref, pv_fn)
-
-    @pl.when(p == p_steps - 1)
-    def _flush():
-        o_ref[0, 0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+    # values: out[:, d in r] = sum_t p_t * (scale_tr * code_td + zmin_tr);
+    # the scale folds into p (one stacked row block per region), the zmin
+    # term is a per-region row sum, and a lane mask keeps region r's block
+    # on region r's lanes.
+    v_sc_t = vs_ref[0].T
+    v_zm_t = vz_ref[0].T
+    p_sc = jnp.concatenate([pmat * v_sc_t[r:r + 1] for r in range(gr)],
+                           axis=0)                             # (Gr*nq, R)
+    zsum = jnp.concatenate(
+        [(pmat * v_zm_t[r:r + 1]).sum(axis=-1, keepdims=True)
+         for r in range(gr)], axis=0)                          # (Gr*nq, 1)
+    w = acc_ref.shape[-1]
+    region = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1) // (w // gr)
+    for i, codes in enumerate(_unpack_planes(vp_ref[0], bits)):
+        pv = code_matmul(_dot, p_sc, codes) + zsum             # (Gr*nq, W)
+        out = jnp.zeros((nq, w), jnp.float32)
+        for r in range(gr):
+            out = out + jnp.where(region == r, pv[r * nq:(r + 1) * nq], 0.0)
+        acc_ref[i] = acc_ref[i] * corr + out
+    _flush(p, p_steps, o_ref, acc_ref, l_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("dequant", "interpret"))
@@ -287,73 +302,81 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *,
                            "XLA gather+dequant path instead")
     b, lq, kvh, gq, d = q.shape
     lqg = lq * gq
+    nq = kvh * lqg
     n_tbl = page_table.shape[1]
     quant = isinstance(k_pages, dict)
     sm_scale = d ** -0.5
 
-    qm = q.transpose(0, 2, 1, 3, 4).reshape(b, kvh, lqg, d)
-    qm = qm.astype(jnp.float32)
+    # query rows (h, l, g) = h*lqg + l*gq + g; page rows (t, h') = t*KV + h'
+    qm = q.transpose(0, 2, 1, 3, 4).reshape(b, nq, d).astype(jnp.float32)
     tbl = page_table.astype(jnp.int32)
     posb = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
 
-    def q_map(bi, h, p, tbl_ref, pos_ref):
-        return (bi, h, 0, 0)
+    def fixed(bi, p, tbl_ref, pos_ref):
+        return (bi, 0, 0, 0)
 
-    def page_map(bi, h, p, tbl_ref, pos_ref):
-        return (tbl_ref[bi, p], 0, h, 0)
+    def page_map(bi, p, tbl_ref, pos_ref):
+        return (tbl_ref[bi, p], 0, 0)
 
+    def rows(a):                       # (n_pages, ps, KV, X) -> (n, ps*KV, X)
+        return a.reshape(a.shape[0], a.shape[1] * a.shape[2], a.shape[3])
+
+    def page_spec(a):
+        return pl.BlockSpec((1,) + a.shape[1:], page_map)
+
+    statics = dict(gq=gq, lqg=lqg, kvh=kvh, p_steps=n_tbl,
+                   sm_scale=sm_scale)
     if quant:
         packed_d = k_pages["packed"].shape[-1]
         gr = k_pages["scale"].shape[-1]
         bits = _infer_bits(packed_d, d)
-        group_size = d // gr
+        cpb, w = d // packed_d, packed_d
         page_size = k_pages["packed"].shape[1]
         lut = dequant_path(bits, dequant) == "lut"
-        kernel = functools.partial(
-            _kernel_quant, bits=bits, group_size=group_size,
-            page_size=page_size, gq=gq, p_steps=n_tbl, sm_scale=sm_scale,
-            lut=lut, d=d)
-        leaf_specs = [
-            pl.BlockSpec((1, page_size, 1, packed_d), page_map),
-            pl.BlockSpec((1, page_size, 1, gr), page_map),
-            pl.BlockSpec((1, page_size, 1, gr), page_map),
-        ]
-        in_specs = [pl.BlockSpec((1, 1, lqg, d), q_map)] \
-            + leaf_specs + leaf_specs
-        operands = (qm, k_pages["packed"], k_pages["scale"],
-                    k_pages["zmin"], v_pages["packed"], v_pages["scale"],
-                    v_pages["zmin"])
+        kernel = functools.partial(_kernel_quant, bits=bits, gr=gr, lut=lut,
+                                   page_size=page_size, **statics)
+        # query planes (feature cpb*j + i -> plane i, lane j), stacked per
+        # region: row r*nq + n holds query row n masked to region r's lanes
+        qp = qm.reshape(b, nq, w, cpb).transpose(0, 3, 1, 2)
+        onehot = (jnp.arange(w) // (w // gr))[None] == jnp.arange(gr)[:, None]
+        qs = jnp.where(onehot[None, None, :, None, :], qp[:, :, None], 0.0)
+        qs = qs.reshape(b, cpb, gr * nq, w)
+        qsum = qm.reshape(b, nq, gr, d // gr).sum(-1)          # (b, nq, Gr)
+        leaves = [rows(t[f]) for t in (k_pages, v_pages)
+                  for f in ("packed", "scale", "zmin")]
+        in_specs = [pl.BlockSpec((1, cpb, gr * nq, w), fixed),
+                    pl.BlockSpec((1, nq, gr), lambda bi, p, t, s: (bi, 0, 0))
+                    ] + [page_spec(a) for a in leaves]
+        operands = (qs, qsum, *leaves)
         name = f"paged_attention_{'lut' if lut else 'affine'}_b{bits}"
     else:
         dequant_path(None, dequant)            # still validates the mode
+        cpb, w = 1, d
         page_size = k_pages.shape[1]
-        kernel = functools.partial(
-            _kernel_fp, page_size=page_size, gq=gq, p_steps=n_tbl,
-            sm_scale=sm_scale)
-        in_specs = [
-            pl.BlockSpec((1, 1, lqg, d), q_map),
-            pl.BlockSpec((1, page_size, 1, d), page_map),
-            pl.BlockSpec((1, page_size, 1, d), page_map),
-        ]
-        operands = (qm, k_pages, v_pages)
+        kernel = functools.partial(_kernel_fp, page_size=page_size,
+                                   **statics)
+        leaves = [rows(k_pages), rows(v_pages)]
+        in_specs = [pl.BlockSpec((1, 1, nq, d), fixed)] \
+            + [page_spec(a) for a in leaves]
+        operands = (qm[:, None], *leaves)
         name = "paged_attention_fp"
 
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b, kvh, n_tbl),
+            grid=(b, n_tbl),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, lqg, d), q_map),
-            scratch_shapes=[pltpu.VMEM((lqg, d), jnp.float32),
-                            pltpu.VMEM((lqg, 1), jnp.float32),
-                            pltpu.VMEM((lqg, 1), jnp.float32)],
+            out_specs=pl.BlockSpec((1, cpb, nq, w), fixed),
+            scratch_shapes=[pltpu.VMEM((cpb, nq, w), jnp.float32),
+                            pltpu.VMEM((nq, 1), jnp.float32),
+                            pltpu.VMEM((nq, 1), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, kvh, lqg, d), jnp.float32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        out_shape=jax.ShapeDtypeStruct((b, cpb, nq, w), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name=name,
     )(tbl, posb, *operands)
-    out = out.reshape(b, kvh, lq, gq, d).transpose(0, 2, 1, 3, 4)
-    return out.astype(q.dtype)
+    out = out.transpose(0, 2, 3, 1).reshape(b, kvh, lq, gq, d)
+    return out.transpose(0, 2, 1, 3, 4).astype(q.dtype)

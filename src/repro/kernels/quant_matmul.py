@@ -13,10 +13,20 @@ the paper's scheme (DESIGN.md section 5.1):
 
 Grid: (M/bm, N/bn, K/bk), K innermost ("arbitrary") with an f32 VMEM
 accumulator; bk is a multiple of the local-region (group) size so each block
-sees whole regions.
+sees whole regions, and a multiple of 8 regions (or all of K) so the
+scale/zmin blocks are sublane-aligned.
+
+Unpacking without an interleave: packed row r of a K block holds the codes
+of rows ``cpb*r + i`` (i < cpb) at shift ``i*bits``.  Shift-and-mask gives
+``cpb`` planes, plane i holding rows ``i, cpb+i, 2cpb+i, ...``; instead of
+interleaving the planes back along sublanes, the wrapper permutes the
+columns of x the same way, so block ``kk`` computes
+``sum_i x_plane_i @ dequant(plane_i)``.  Every row of plane i in local
+region g lies in rows ``[g*gs/cpb, (g+1)*gs/cpb)``, so one scale row per
+region still covers a contiguous run of plane rows.
 
 Block shapes:
-  x      (bm, bk)            float32 / bfloat16
+  x      (bm, bk)            float32 / bfloat16 (columns plane-permuted)
   packed (bk // cpb, bn)     uint8, codes packed along K
   scale  (bk // gs, bn)      f32
   zmin   (bk // gs, bn)      f32
@@ -32,19 +42,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import packing
-from .pallas_compat import CompilerParams as _CompilerParams
-
-
-def _unpack_block(packed, bits: int, bk: int):
-    """uint8 (bk/cpb, bn) -> int-code f32 (bk, bn), codes packed along axis 0."""
-    if bits not in packing.PACKABLE_BITS:
-        return packed.astype(jnp.float32)
-    cpb = packing.codes_per_byte(bits)
-    mask = (1 << bits) - 1
-    p = packed.astype(jnp.int32)                       # (bk/cpb, bn)
-    shifts = jnp.arange(cpb, dtype=jnp.int32) * bits   # code i at bit i*bits
-    vals = (p[:, None, :] >> shifts[None, :, None]) & mask  # (bk/cpb, cpb, bn)
-    return vals.reshape(bk, -1).astype(jnp.float32)
 
 
 def _kernel(x_ref, p_ref, s_ref, z_ref, o_ref, acc_ref, *,
@@ -53,39 +50,51 @@ def _kernel(x_ref, p_ref, s_ref, z_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    codes = _unpack_block(p_ref[...], bits, bk)            # (bk, bn) f32
+    cpb = packing.codes_per_byte(bits)
+    rows = bk // cpb                                       # packed rows
     g = bk // group_size
-    s = s_ref[...]                                         # (g, bn)
-    z = z_ref[...]
-    w = (codes.reshape(g, group_size, -1) * s[:, None, :]
-         + z[:, None, :]).reshape(bk, -1)                  # dequant in VMEM
-    acc_ref[...] += jax.lax.dot_general(
-        x_ref[...], w.astype(x_ref.dtype),
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    packed = p_ref[...].astype(jnp.int32)                  # (rows, bn)
+    s = s_ref[...][:, None, :]                             # (g, 1, bn)
+    z = z_ref[...][:, None, :]
+    for i in range(cpb):
+        codes = packed if cpb == 1 else \
+            (packed >> (i * bits)) & ((1 << bits) - 1)
+        w = (codes.astype(jnp.float32).reshape(g, rows // g, -1) * s
+             + z).reshape(rows, -1)                        # dequant in VMEM
+        acc_ref[...] += jax.lax.dot_general(
+            x_ref[:, i * rows:(i + 1) * rows], w.astype(x_ref.dtype),
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _flush():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-def _pick_bk(k: int, group_size: int, target: int = 512) -> int:
-    """Largest multiple of group_size that divides K and is <= target."""
-    g = k // group_size
-    best = group_size
-    for mult in range(1, g + 1):
-        bk = group_size * mult
-        if bk > target:
-            break
-        if g % mult == 0:
+def _pick_bk(k: int, group_size: int, target: int = 1024) -> int:
+    """All of K when it fits ``target``; else the largest multiple of
+    8 regions that divides K and fits (scale blocks need 8 sublanes)."""
+    step = 8 * group_size
+    best = k
+    for bk in range(step, min(k, target) + 1, step):
+        if k % bk == 0:
             best = bk
     return best
+
+
+def _plane_permute(x, bk: int, cpb: int):
+    """Columns of each bk block reordered to match the unpacked planes."""
+    if cpb == 1:
+        return x
+    m, k = x.shape
+    return (x.reshape(m, k // bk, bk // cpb, cpb)
+            .transpose(0, 1, 3, 2).reshape(m, k))
 
 
 @functools.partial(jax.jit, static_argnames=(
     "bits", "group_size", "bm", "bn", "bk", "interpret"))
 def quant_matmul(x, packed, scale, zmin, *, bits: int, group_size: int,
-                 bm: int = 128, bn: int = 128, bk: int | None = None,
+                 bm: int = 256, bn: int = 256, bk: int | None = None,
                  interpret: bool = False):
     """x (M, K) @ dequant(packed/scale/zmin) (K, N) -> (M, N).
 
@@ -111,6 +120,7 @@ def quant_matmul(x, packed, scale, zmin, *, bits: int, group_size: int,
     bn = min(bn, _round_up(n, 128))
     mp, np_ = _round_up(m, bm), _round_up(n, bn)
     x_p = jnp.pad(x, ((0, mp - m), (0, 0))) if mp != m else x
+    x_p = _plane_permute(x_p, bk, cpb)
     if np_ != n:
         packed = jnp.pad(packed, ((0, 0), (0, np_ - n)))
         scale = jnp.pad(scale, ((0, 0), (0, np_ - n)))
@@ -131,7 +141,7 @@ def quant_matmul(x, packed, scale, zmin, *, bits: int, group_size: int,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name=f"quant_matmul_b{bits}g{group_size}",

@@ -1,9 +1,18 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id> [...]``.
 
-Spins up the batched engine on the reduced config, optionally with the
-paper's quantization applied to weights (--scheme lq4w), activations
-(--a-bits) and the KV cache (--kv-bits), and reports tokens/s plus the
-cache-bytes saving.
+Spins up the batched engine on the reduced config (``--full``: the
+published config), optionally with the paper's quantization applied to
+weights (--scheme lq4w), activations (--a-bits) and the KV cache
+(--kv-bits), and reports tokens/s plus the cache-bytes saving.  Under
+``--full --scheme`` the weights are drawn and packed one layer at a time
+(``transformer.init_params(qcfg=...)``), so a published-width model never
+holds its fp32 masters on the device next to the packed copy.  Kernels
+run through ``backend="auto"``: compiled Pallas on a TPU, the jnp
+reference elsewhere.
+
+Compiled programs persist in JAX's compilation cache: the directory
+``JAX_COMPILATION_CACHE_DIR`` names when it is set, else ``.jax_cache``
+at the root of the checkout (:func:`enable_compile_cache`).
 
 ``--continuous N`` switches to the continuous-batching serve layer
 (serve/server.py): N requests with staggered arrivals are scheduled over
@@ -44,15 +53,39 @@ persists the final per-tenant budget/burn/episode report —
 from __future__ import annotations
 
 import argparse
+import os
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 
 from repro import configs
+from repro.core import schemes
 from repro.models import transformer
 from repro.obs import Observability, Stopwatch
 from repro.serve import (Engine, EngineConfig, PagedConfig, RequestParams,
                          Server)
+
+
+def enable_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at one fixed directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is read by JAX itself and
+    no other directory is set here.  Otherwise the cache lives at
+    ``<checkout>/.jax_cache`` — a fixed path, since the path is part of
+    what a later run must find again.  Entry points call this from
+    ``main()``; importing the module changes nothing.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def _config(arch: str, full: bool):
+    return configs.get(arch) if full else configs.smoke(arch)
 
 
 def _make_obs(args) -> Observability | None:
@@ -193,8 +226,13 @@ def _save_obs(obs, args):
         print(f"wrote {args.metrics_out}")
 
 
-def _continuous(cfg, params, ecfg, args):
-    """Staggered-arrival continuous batching over the paged pool."""
+def run_continuous(cfg, params, ecfg, args) -> dict:
+    """Staggered-arrival continuous batching over the paged pool.
+
+    Returns ``{"server", "rids", "warmup_s", "serve_s", "tokens"}``:
+    the live server, the measured requests' ids, the wall time of the
+    warm-up request (both jits compile there) and of the measured run.
+    """
     import dataclasses
     want = args.prompt_len + args.steps + 8
     mc = -(-want // args.page_size) * args.page_size
@@ -215,8 +253,11 @@ def _continuous(cfg, params, ecfg, args):
     rng = jax.random.key(2)
     warm = jax.random.randint(jax.random.fold_in(rng, args.continuous),
                               (args.prompt_len,), 0, cfg.vocab_size)
+    sw = Stopwatch()
     server.submit(warm.tolist(), RequestParams(max_new_tokens=2))
     server.drain()                          # warm both jits off the clock
+    warmup_s = sw.elapsed()
+    print(f"warm-up (compiles prefill + decode): {warmup_s:.2f}s")
     obs = _make_obs(args)
     flight = msrv = quality = profiler = tracker = health = None
     if obs is not None:
@@ -316,6 +357,8 @@ def _continuous(cfg, params, ecfg, args):
     _save_obs(obs, args)
     _finish_extras(flight, msrv, args)
     print("sample:", server.output(rids[0])[:16])
+    return {"server": server, "rids": rids, "warmup_s": warmup_s,
+            "serve_s": dt, "tokens": toks}
 
 
 def _fleet(args):
@@ -325,10 +368,9 @@ def _fleet(args):
     from repro.fleet import FleetAdmissionError, build_fleet, load_manifest
 
     manifest = load_manifest(args.fleet)
-    cfg = configs.smoke(manifest.arch)
+    cfg = _config(manifest.arch, args.full)
     params = transformer.init_params(cfg, jax.random.key(0))
     router = build_fleet(manifest, cfg, params, budget_mb=args.budget_mb,
-                         backend="ref",
                          fused_attention=args.fused_attention)
     print(router.registry.describe())
 
@@ -426,10 +468,14 @@ def _fleet(args):
     _finish_extras(flight, msrv, args)
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None, choices=list(configs.names()),
                     help="required unless --fleet supplies the arch")
+    ap.add_argument("--full", action="store_true",
+                    help="the published config (configs.get) instead of "
+                         "the reduced smoke config; with --scheme the "
+                         "weights are packed one layer at a time")
     ap.add_argument("--scheme", default=None, help="weight scheme, e.g. lq4w")
     ap.add_argument("--plan", default=None, metavar="PLAN.json",
                     help="mixed-precision QuantPlan (repro.launch.plan "
@@ -454,10 +500,10 @@ def main():
                          "kernel (kernels/paged_attention.py): wire pages "
                          "stream through VMEM and dequantize in-register "
                          "(LUT path at kv bits <= 4) instead of gather -> "
-                         "fp pool view -> attend; compiled on TPU, "
-                         "interpret-mode elsewhere, with automatic "
-                         "fallback to the XLA gather path when Pallas is "
-                         "unavailable; --continuous and --fleet")
+                         "fp pool view -> attend; compiled on TPU (a "
+                         "kernel failure there is an error), "
+                         "interpret-mode elsewhere; --continuous and "
+                         "--fleet")
     ap.add_argument("--spec-plan", default=None, metavar="DRAFT.json",
                     help="speculative decoding (with --continuous): a "
                          "low-bit draft QuantPlan of the same checkpoint "
@@ -528,7 +574,31 @@ def main():
                     help="persist the measured/predicted decode-ms "
                          "correction factor for repro.launch.plan "
                          "--calibration")
-    args = ap.parse_args()
+    return ap
+
+
+def build(args):
+    """The single-engine setup: ``(cfg, params, ecfg)`` for ``args``."""
+    cfg = _config(args.arch, args.full)
+    qcfg = schemes.get(args.scheme) if args.full and args.scheme else None
+    params = transformer.init_params(cfg, jax.random.key(0), qcfg=qcfg)
+    plan = None
+    if args.plan is not None:
+        from repro.plan import QuantPlan
+        plan = QuantPlan.load(args.plan)
+        print(plan.describe(cfg))
+    ecfg = EngineConfig(max_len=args.prompt_len + args.steps + 8,
+                        kv_bits=args.kv_bits, kv_group=args.kv_group,
+                        weight_scheme=args.scheme, a_bits=args.a_bits,
+                        plan=plan, temperature=args.temperature,
+                        fused_attention=args.fused_attention)
+    return cfg, params, ecfg
+
+
+def main(argv=None):
+    ap = build_parser()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     obs_flags = (args.trace_out or args.metrics_out or args.numerics
                  or args.flight_out or args.calibration_out or args.profile
@@ -556,27 +626,19 @@ def main():
         return
     if args.arch is None:
         ap.error("--arch is required without --fleet")
-
-    cfg = configs.smoke(args.arch)
-    params = transformer.init_params(cfg, jax.random.key(0))
-    plan = None
-    if args.plan is not None:
-        from repro.plan import QuantPlan
-        plan = QuantPlan.load(args.plan)
-        print(plan.describe(cfg))
     if args.fused_attention and not args.continuous:
         ap.error("--fused-attention fuses the *paged* decode path; use it "
                  "with --continuous or --fleet")
-    ecfg = EngineConfig(max_len=args.prompt_len + args.steps + 8,
-                        kv_bits=args.kv_bits, kv_group=args.kv_group,
-                        weight_scheme=args.scheme, a_bits=args.a_bits,
-                        plan=plan, backend="ref",
-                        temperature=args.temperature,
-                        fused_attention=args.fused_attention)
+    if args.full and args.scheme and args.numerics:
+        ap.error("--numerics compares against fp32 masters, which --full "
+                 "--scheme does not keep")
+
+    cfg, params, ecfg = build(args)
     if args.continuous:
-        print(f"arch={args.arch} scheme={args.scheme} plan={args.plan} "
+        print(f"arch={args.arch} ({cfg.n_layers}L d_model={cfg.d_model}) "
+              f"scheme={args.scheme} plan={args.plan} "
               f"a_bits={args.a_bits} kv_bits={args.kv_bits}")
-        _continuous(cfg, params, ecfg, args)
+        run_continuous(cfg, params, ecfg, args)
         return
     engine = Engine(cfg, params, ecfg)
 

@@ -69,13 +69,9 @@ def annotate(name: str):
 
     Labels the enclosed host work — the dispatch of a prefill/decode/
     draft/verify call — in programmatic profiler captures.  Metadata
-    only: a TraceMe never touches computation, and an unavailable
-    profiler degrades to a null context.
+    only: a TraceMe never touches computation.
     """
-    try:
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(name)
 
 
 @contextlib.contextmanager
@@ -83,23 +79,15 @@ def xprof_capture(out_dir: str):
     """Programmatic ``jax.profiler`` capture around a block.
 
     Writes a TensorBoard/XProf trace under ``out_dir`` (the
-    ``--xprof-out`` flag of ``repro.launch.serve``).  Capture failures
-    degrade to a warning — profiling must never take the serve run down.
+    ``--xprof-out`` flag of ``repro.launch.serve``).  A profiler that
+    fails to start or stop is an error: a run asked to trace must not
+    exit cleanly without its trace.
     """
-    started = False
-    try:
-        jax.profiler.start_trace(out_dir)
-        started = True
-    except Exception as e:                                # pragma: no cover
-        print(f"xprof capture unavailable: {e}")
+    jax.profiler.start_trace(out_dir)
     try:
         yield
     finally:
-        if started:
-            try:
-                jax.profiler.stop_trace()
-            except Exception as e:                        # pragma: no cover
-                print(f"xprof capture failed to stop: {e}")
+        jax.profiler.stop_trace()
 
 
 # ---------------------------------------------------------------------------

@@ -65,8 +65,9 @@ class EngineConfig:
     # paged decode through the fused flash-decode kernel
     # (kernels/paged_attention.py): wire pages stream through VMEM and
     # dequantize in-register instead of gather -> fp pool view -> attend.
-    # Compiled on TPU, interpret-mode elsewhere; silently falls back to
-    # the XLA gather path when Pallas is unavailable.
+    # Compiled on TPU, interpret-mode elsewhere; off the TPU a missing
+    # Pallas falls back to the XLA gather path (reported, see
+    # attention_mode), on a TPU it is an error.
     fused_attention: bool = False
 
 

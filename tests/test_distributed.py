@@ -22,11 +22,8 @@ TINY = ModelConfig(name="tiny", family="dense", n_layers=2, d_model=64,
 
 
 def _mesh11():
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:    # older jax: meshes are implicitly Auto-typed
-        return jax.make_mesh((1, 1), ("data", "model"))
     return jax.make_mesh((1, 1), ("data", "model"),
-                         axis_types=(axis_type.Auto,) * 2)
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 # ---------------------------------------------------------------------------

@@ -13,23 +13,6 @@ from repro.kernels import ops, ref
 KEY = jax.random.key(0)
 
 
-def _pallas_interpret_available() -> bool:
-    """Probe the Pallas interpret path once; env/version gaps become skips."""
-    try:
-        w = jax.random.normal(KEY, (64, 32))
-        qw = ops.quantize_weight(w, 8, 32)
-        x = jax.random.normal(KEY, (2, 64))
-        ops.quant_matmul(x, qw, backend="interpret")
-        return True
-    except Exception:
-        return False
-
-
-needs_pallas = pytest.mark.skipif(
-    not _pallas_interpret_available(),
-    reason="Pallas interpret backend unavailable in this jax build")
-
-
 def _w(k, n, seed=0):
     return 2.0 * jax.random.normal(jax.random.fold_in(KEY, seed), (k, n))
 
@@ -49,7 +32,6 @@ def _x(m, k, dtype=jnp.float32, seed=1):
     (16, 512, 256, 128),
     (4, 128, 384, 32),
 ])
-@needs_pallas
 def test_quant_matmul_interpret_vs_ref(bits, m, k, n, gs):
     w = _w(k, n, seed=bits)
     qw = ops.quantize_weight(w, bits, gs)
@@ -61,7 +43,6 @@ def test_quant_matmul_interpret_vs_ref(bits, m, k, n, gs):
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@needs_pallas
 def test_quant_matmul_dtypes(dtype):
     w = _w(256, 128, seed=3)
     qw = ops.quantize_weight(w, 4, 64)
@@ -74,7 +55,6 @@ def test_quant_matmul_dtypes(dtype):
                                rtol=2e-2, atol=2e-1)
 
 
-@needs_pallas
 def test_quant_matmul_unaligned_mn():
     """M, N not multiples of the tile: the kernel pads internally."""
     w = _w(256, 100, seed=4)
@@ -105,7 +85,6 @@ def test_quant_matmul_vs_float():
 @pytest.mark.parametrize("bits", [8, 4, 2])
 @pytest.mark.parametrize("m,k,gs", [(8, 256, 64), (16, 128, 32),
                                     (3, 512, 128)])
-@needs_pallas
 def test_act_quant_interpret_vs_ref(bits, m, k, gs):
     x = _x(m, k, seed=bits + 20)
     gp, gs_, gz = ops.act_quant(x, bits=bits, group_size=gs,
@@ -131,7 +110,6 @@ def test_act_quant_reconstruction(bits):
 
 @pytest.mark.parametrize("bits", [4, 2, 1])
 @pytest.mark.parametrize("m,k,n,gs", [(8, 256, 128, 64), (4, 128, 96, 32)])
-@needs_pallas
 def test_lut_matmul_interpret_vs_ref(bits, m, k, n, gs):
     x = _x(m, k, seed=bits + 40)
     w = _w(k, n, seed=bits + 41)

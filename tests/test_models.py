@@ -114,3 +114,34 @@ def test_scan_tail_layers():
     assert cfg.n_super == 1 and cfg.n_tail == 2
     params = transformer.init_params(cfg, jax.random.key(0))
     assert len(params["decoder"]["tail"]) == 2
+
+
+@pytest.mark.parametrize("arch", ["granite-3-2b", "recurrentgemma-2b",
+                                  "whisper-large-v3"])
+def test_packed_init_matches_quantize_params(arch):
+    """init_params(qcfg=...) draws and packs one layer at a time; the
+    result is quantize_params(init_params(...)) up to float rounding of
+    the per-region affine (same tree, same dequantized weights)."""
+    from repro.core import schemes
+    from repro.kernels import ops
+    cfg = configs.smoke(arch)
+    qcfg = schemes.get("lq4w")
+    want = transformer.quantize_params(
+        transformer.init_params(cfg, jax.random.key(3)), cfg, qcfg)
+    got = transformer.init_params(cfg, jax.random.key(3), qcfg=qcfg)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    is_q = lambda x: isinstance(x, ops.QWeight)
+
+    def dense(q):
+        """A (possibly stacked) QWeight dequantized to (..., K, N)."""
+        if not is_q(q):
+            return q
+        if q.packed.ndim == 2:
+            return ops.dequantize_weight(q)
+        return jax.vmap(dense)(q)
+
+    for g, w in zip(jax.tree.leaves(got, is_leaf=is_q),
+                    jax.tree.leaves(want, is_leaf=is_q)):
+        np.testing.assert_allclose(np.asarray(dense(g)),
+                                   np.asarray(dense(w)), rtol=1e-5,
+                                   atol=1e-6)

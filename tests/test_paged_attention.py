@@ -266,8 +266,9 @@ def test_fused_fleet_routing_matches_baseline(params):
 def test_decode_attention_accumulates_f32_without_upcast_copy():
     """Regression: the fallback used to ``.astype(f32)`` both caches,
     materializing full upcast copies.  ``preferred_element_type`` gives
-    the same f32 accumulation with the caches staying in storage dtype —
-    same outputs, and compiled temp memory well under one upcast copy."""
+    the same f32 accumulation with the caches staying in storage dtype.
+    This is the parity half; the temp-bytes bound is checked where the
+    property matters, on the v5e compile (tests/test_chip_compile.py)."""
     b, s, kvh, g, d = 2, 2048, 2, 2, 64
     q = jax.random.normal(KEY, (b, 1, kvh, g, d), jnp.float32)
     kc = jax.random.normal(jax.random.fold_in(KEY, 1), (b, s, kvh, d),
@@ -281,19 +282,6 @@ def test_decode_attention_accumulates_f32_without_upcast_copy():
                                       vc.astype(jnp.float32), pos)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
-
-    compiled = jax.jit(attention.decode_attention).lower(
-        q, kc, vc, pos).compile()
-    try:
-        temp = compiled.memory_analysis().temp_size_in_bytes
-    except (AttributeError, NotImplementedError):
-        pytest.skip("backend exposes no compiled memory analysis")
-    one_upcast_copy = b * s * kvh * d * 4
-    # the old explicit .astype floor is BOTH caches resident as f32 temps
-    # (2 copies); CPU XLA may still stage ~one operand internally for the
-    # bf16 dot, so the bound sits strictly between the two behaviors
-    assert temp < 1.5 * one_upcast_copy, \
-        f"temps {temp}B ~ both caches upcast ({2 * one_upcast_copy}B floor)"
 
 
 def test_fused_solo_engine_unaffected(params):

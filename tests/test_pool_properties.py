@@ -138,6 +138,14 @@ if HAVE_HYPOTHESIS:
             TINY, kv_map, N_PAGES, PAGE_SIZE, KV_GROUP)
 
 
+def _segment_bits(kv_map):
+    """The kv bits of each ``super_segments`` run of the pool: runs of
+    equal consecutive layers share one stacked array, so segment ``s``
+    is not layer ``s`` once a bit width repeats."""
+    return [key[0] for _, _, key in
+            kvwire.segment_runs(list(kv_map), 1, TINY.n_layers)]
+
+
 def _defrag_data_check(kv_map, sizes, victim):
     """Write a sentinel token row into every allocated page of every layer
     (at that layer's own wire format), shuffle the pool with frees +
@@ -155,8 +163,8 @@ def _defrag_data_check(kv_map, sizes, victim):
     toks = {r: jax.random.normal(jax.random.key(r),
                                  (1, 1, TINY.n_kv_heads, TINY.head_dim))
             for r in rids}
-    for s, seg in enumerate(pool.pages["super_segments"]):
-        bits = kv_map[s]
+    for bits, seg in zip(_segment_bits(kv_map),
+                         pool.pages["super_segments"]):
         kw = {} if bits is None else dict(bits=bits, group_size=KV_GROUP)
         leaf = jax.tree.map(lambda a: a[0], seg[0]["self"]["k"])
         for r in rids:
@@ -225,8 +233,8 @@ def _truncate_data_check(kv_map, keep_tokens):
     total = n_pages_each * PAGE_SIZE
     x = jax.random.normal(jax.random.key(7),
                           (1, total, TINY.n_kv_heads, TINY.head_dim))
-    for s, seg in enumerate(pool.pages["super_segments"]):
-        bits = kv_map[s]
+    for bits, seg in zip(_segment_bits(kv_map),
+                         pool.pages["super_segments"]):
         kw = {} if bits is None else dict(bits=bits, group_size=KV_GROUP)
         leaf = jax.tree.map(lambda a: a[0], seg[0]["self"]["k"])
         for r in rids:

@@ -135,3 +135,53 @@ def test_mamba_state_quant_close_to_fp():
     a, _ = fp.generate(batch, steps=8)
     b, _ = q8.generate(batch, steps=8)
     assert (np.asarray(a) == np.asarray(b)).mean() > 0.8
+
+
+# ---------------------------------------------------------------------------
+# launcher: compile-cache placement (subprocesses: the setting is global)
+# ---------------------------------------------------------------------------
+
+_CACHE_PROBE = (
+    "import jax, jax.numpy as jnp\n"
+    "from repro.launch.serve import enable_compile_cache\n"
+    "print(enable_compile_cache())\n"
+    "print(jax.config.jax_compilation_cache_dir)\n"
+    "import sys\n"
+    "if len(sys.argv) > 1:\n"
+    "    jax.jit(lambda x: x * 2 + 1)(jnp.ones(3)).block_until_ready()\n")
+
+
+def _probe_cache(env_dir, compile_: bool):
+    import os
+    import subprocess
+    import sys
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0")
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(env_dir)
+    out = subprocess.run(
+        [sys.executable, "-c", _CACHE_PROBE] + (["compile"] if compile_
+                                                 else []),
+        env=env, capture_output=True, text=True, check=True)
+    return out.stdout.split()
+
+
+def test_compile_cache_uses_env_dir_when_set(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: compiled programs land there and
+    the launcher sets no directory of its own."""
+    cache = tmp_path / "xla"
+    returned, configured = _probe_cache(cache, compile_=True)
+    assert returned == configured == str(cache)
+    assert any(cache.iterdir())
+
+
+def test_compile_cache_defaults_to_ignored_checkout_dir():
+    """Unset: one fixed directory at the root of the checkout, listed in
+    .gitignore (no temp name, pid or time in the path)."""
+    from pathlib import Path
+    root = Path(__file__).resolve().parents[1]
+    returned, configured = _probe_cache(None, compile_=False)
+    assert returned == configured == str(root / ".jax_cache")
+    assert ".jax_cache/" in (root / ".gitignore").read_text().split()
