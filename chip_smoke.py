@@ -35,6 +35,10 @@ import time
 import traceback
 from pathlib import Path
 
+import jax
+import jax.numpy as jnp
+import numpy as np
+
 ROOT = Path(__file__).resolve().parent
 SERVE_ARGV = ["--arch", "granite-3-2b", "--full", "--scheme", "lq4w",
               "--kv-bits", "4", "--kv-group", "16", "--fused-attention",
@@ -58,7 +62,6 @@ def check(ok: bool, what: str):
 
 
 def rel_err(got, want) -> float:
-    import numpy as np
     got = np.asarray(got, np.float32)
     want = np.asarray(want, np.float32)
     if not (np.isfinite(got).all() and np.isfinite(want).all()):
@@ -66,10 +69,8 @@ def rel_err(got, want) -> float:
     return float(np.abs(got - want).max() / max(np.abs(want).max(), 1e-30))
 
 
-def check_decode_hlo(server, args):
+def check_decode_hlo(server):
     """The compiled decode step runs both Pallas kernels."""
-    import jax
-    import jax.numpy as jnp
     engine, pcfg = server.engine, server.engine.pcfg
     zeros = jnp.zeros((pcfg.max_slots,), jnp.int32)
     t = time.perf_counter()
@@ -91,9 +92,6 @@ def check_decode_hlo(server, args):
 def check_live_pool_attention(server, args):
     """Fused kernel vs gather -> dequantize -> decode_attention on the
     pool as live requests left it, first and last layer."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
     from repro.core import kvwire
     from repro.kernels import paged_attention as paged_attn
     from repro.models import attention
@@ -140,8 +138,6 @@ def check_live_pool_attention(server, args):
 
 def check_quant_matmul(params, pcfg):
     """Compiled quant_matmul vs the jnp reference on layer-0 weights."""
-    import jax
-    import jax.numpy as jnp
     from repro.kernels import ops
 
     blk = params["decoder"]["super"][0]
@@ -163,8 +159,6 @@ def check_quant_matmul(params, pcfg):
 
 
 def run() -> dict:
-    import jax
-
     devices = jax.devices()
     dev = devices[0]
     if dev.platform != "tpu":
@@ -208,7 +202,7 @@ def run() -> dict:
     check_live_pool_attention(server, args)
     check(server.engine.decode_compilations == 1,
           "still one compiled decode step after the live-pool phase")
-    check_decode_hlo(server, args)
+    check_decode_hlo(server)
     check_quant_matmul(server.engine.params, server.engine.pcfg)
 
     stats = dev.memory_stats() or {}

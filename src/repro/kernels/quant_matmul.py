@@ -94,7 +94,7 @@ def _plane_permute(x, bk: int, cpb: int):
 @functools.partial(jax.jit, static_argnames=(
     "bits", "group_size", "bm", "bn", "bk", "interpret"))
 def quant_matmul(x, packed, scale, zmin, *, bits: int, group_size: int,
-                 bm: int = 256, bn: int = 256, bk: int | None = None,
+                 bm: int = 128, bn: int = 128, bk: int | None = None,
                  interpret: bool = False):
     """x (M, K) @ dequant(packed/scale/zmin) (K, N) -> (M, N).
 
