@@ -44,8 +44,10 @@ SERVE_ARGV = ["--arch", "granite-3-2b", "--full", "--scheme", "lq4w",
               "--kv-bits", "4", "--kv-group", "16", "--fused-attention",
               "--continuous", "4", "--prompt-len", "128", "--steps", "15",
               "--max-slots", "4"]
-# max |kernel - reference| / max |reference|.  Both sides round through
-# bf16 (activations, dequantized weights, outputs) at ~2^-9 relative; a
+# max |kernel - reference| / max |reference|, the references at HIGHEST
+# precision.  The kernels' f32 dots run at the compiler's default, one
+# bf16 MXU pass (~2^-9 relative per input), as the XLA path's do; on a
+# v5e that alone gives attention 4e-3 to 3e-2 as scores sharpen.  A
 # wrong plane, region or page shows up at order 1.
 MATMUL_TOL = 2e-2
 ATTN_TOL = 2e-2

@@ -14,7 +14,7 @@ packed (M, K/cpb) uint8, scale (M, G) f32, zmin (M, G) f32.
 
 Packing runs on the MXU: ``cpb`` adjacent codes fold into one byte as
 ``sum_i code_i * 2^(i*bits)``, a matmul with a 0/power-of-two matrix over
-each ``128*cpb``-lane chunk.  Codes and weights are exact in bf16 and the
+each ``128*cpb``-lane chunk (the last one may be shorter).  Codes and weights are exact in bf16 and the
 sums (< 256) exact in the f32 accumulator, so the bytes match
 ``core/packing.pack`` bit for bit with no lane interleave in the kernel.
 """
@@ -45,18 +45,18 @@ def _kernel(x_ref, p_ref, s_ref, z_ref, *, bits: int, group_size: int):
     if cpb == 1:
         p_ref[...] = codes.astype(jnp.int32).astype(jnp.uint8)
     else:
-        cw = min(k, 128 * cpb)                          # codes per chunk
-        lane = jax.lax.broadcasted_iota(jnp.int32, (cw, cw // cpb), 0)
-        byte = jax.lax.broadcasted_iota(jnp.int32, (cw, cw // cpb), 1)
-        fold = jnp.where(lane // cpb == byte,
-                         jnp.left_shift(1, (lane % cpb) * bits), 0)
-        fold = fold.astype(jnp.bfloat16)
-        for c in range(k // cw):
-            chunk = codes[:, c * cw:(c + 1) * cw].astype(jnp.bfloat16)
+        cw = 128 * cpb                                  # codes per chunk
+        for lo in range(0, k, cw):                      # last may be short
+            w = min(cw, k - lo)
+            lane = jax.lax.broadcasted_iota(jnp.int32, (w, w // cpb), 0)
+            byte = jax.lax.broadcasted_iota(jnp.int32, (w, w // cpb), 1)
+            fold = jnp.where(lane // cpb == byte,
+                             jnp.left_shift(1, (lane % cpb) * bits), 0)
             packed = jax.lax.dot_general(
-                chunk, fold, (((1,), (0,)), ((), ())),
+                codes[:, lo:lo + w].astype(jnp.bfloat16),
+                fold.astype(jnp.bfloat16), (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            p_ref[:, c * cw // cpb:(c + 1) * cw // cpb] = \
+            p_ref[:, lo // cpb:(lo + w) // cpb] = \
                 packed.astype(jnp.int32).astype(jnp.uint8)
     s_ref[...] = scale
     z_ref[...] = xmin

@@ -150,7 +150,8 @@ def _unpack_planes(pk, bits: int) -> list:
 
 
 def _dot_t(a, b):
-    """a (M, K) @ b (N, K)^T in f32."""
+    """a (M, K) @ b (N, K)^T, f32 accumulation.  At the default precision
+    Mosaic feeds f32 operands to the MXU as one bf16 pass, as XLA does."""
     return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
                                preferred_element_type=jnp.float32)
 

@@ -84,10 +84,6 @@ def enable_compile_cache() -> str:
     return path
 
 
-def _config(arch: str, full: bool):
-    return configs.get(arch) if full else configs.smoke(arch)
-
-
 def _make_obs(args) -> Observability | None:
     """One Observability per run when any instrumentation was requested."""
     if (args.trace_out or args.metrics_out or args.numerics
@@ -368,7 +364,7 @@ def _fleet(args):
     from repro.fleet import FleetAdmissionError, build_fleet, load_manifest
 
     manifest = load_manifest(args.fleet)
-    cfg = _config(manifest.arch, args.full)
+    cfg = configs.smoke(manifest.arch)
     params = transformer.init_params(cfg, jax.random.key(0))
     router = build_fleet(manifest, cfg, params, budget_mb=args.budget_mb,
                          fused_attention=args.fused_attention)
@@ -474,8 +470,9 @@ def build_parser() -> argparse.ArgumentParser:
                     help="required unless --fleet supplies the arch")
     ap.add_argument("--full", action="store_true",
                     help="the published config (configs.get) instead of "
-                         "the reduced smoke config; with --scheme the "
-                         "weights are packed one layer at a time")
+                         "the reduced smoke config (not with --fleet); "
+                         "with --scheme the weights are packed one layer "
+                         "at a time")
     ap.add_argument("--scheme", default=None, help="weight scheme, e.g. lq4w")
     ap.add_argument("--plan", default=None, metavar="PLAN.json",
                     help="mixed-precision QuantPlan (repro.launch.plan "
@@ -579,7 +576,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def build(args):
     """The single-engine setup: ``(cfg, params, ecfg)`` for ``args``."""
-    cfg = _config(args.arch, args.full)
+    cfg = configs.get(args.arch) if args.full else configs.smoke(args.arch)
     qcfg = schemes.get(args.scheme) if args.full and args.scheme else None
     params = transformer.init_params(cfg, jax.random.key(0), qcfg=qcfg)
     plan = None
@@ -598,6 +595,9 @@ def build(args):
 def main(argv=None):
     ap = build_parser()
     args = ap.parse_args(argv)
+    if args.full and args.fleet is not None:
+        ap.error("--full serves one published config; --fleet runs the "
+                 "reduced configs")
     enable_compile_cache()
 
     obs_flags = (args.trace_out or args.metrics_out or args.numerics

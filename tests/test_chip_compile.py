@@ -117,7 +117,8 @@ def test_paged_attention_compiles(one_chip, bits, dequant, lq):
 
 
 @pytest.mark.parametrize("bits", [2, 4, 8])
-@pytest.mark.parametrize("k", [D_MODEL, D_FF])
+@pytest.mark.parametrize("k", [D_MODEL, D_FF,
+                               896, 1280])      # K not a whole 128*cpb chunk
 def test_act_quant_compiles(one_chip, bits, k):
     c = _compile(lambda x: aq.act_quant(x, bits=bits, group_size=GS),
                  _shape(one_chip, (BUCKET, k), jnp.bfloat16))

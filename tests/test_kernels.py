@@ -84,7 +84,9 @@ def test_quant_matmul_vs_float():
 
 @pytest.mark.parametrize("bits", [8, 4, 2])
 @pytest.mark.parametrize("m,k,gs", [(8, 256, 64), (16, 128, 32),
-                                    (3, 512, 128)])
+                                    (3, 512, 128),
+                                    (4, 896, 128),      # internvl2-1b width
+                                    (2, 1280, 128)])    # whisper-large-v3
 def test_act_quant_interpret_vs_ref(bits, m, k, gs):
     x = _x(m, k, seed=bits + 20)
     gp, gs_, gz = ops.act_quant(x, bits=bits, group_size=gs,

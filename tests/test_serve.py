@@ -185,3 +185,13 @@ def test_compile_cache_defaults_to_ignored_checkout_dir():
     returned, configured = _probe_cache(None, compile_=False)
     assert returned == configured == str(root / ".jax_cache")
     assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+
+
+def test_full_is_refused_with_fleet(capsys):
+    """--full builds one published config; the fleet path keeps the
+    reduced configs, so the pair is a usage error, not a silent smoke run."""
+    from repro.launch import serve
+    with pytest.raises(SystemExit) as e:
+        serve.main(["--fleet", "examples/fleet_smoke.json", "--full"])
+    assert e.value.code == 2
+    assert "--full" in capsys.readouterr().err
