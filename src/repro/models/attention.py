@@ -384,34 +384,60 @@ def attn_apply(p, x, *, n_heads: int, n_kv: int, head_dim: int,
         base = 0 if cache_pos is None else cache_pos
         positions = base + jnp.arange(l)[None]
     src = x if kind != "cross" else kv_src
-    q, k, v = _project_qkv(p, x, src, n_heads=n_heads, n_kv=n_kv,
-                           head_dim=head_dim, qk_norm=qk_norm,
-                           rope=rope and kind != "cross",
-                           positions=positions, rope_theta=rope_theta,
-                           policy=policy)
-    if cache is None and kind != "cross" and \
-            getattr(policy, "kv_fq", None) is not None:
-        # cache-free forward under a kv-quantized policy: round K/V through
-        # the wire format so sensitivity profiling sees exactly the decode
-        # numerics (post-rope, per-position local regions along head_dim)
-        fq_bits, fq_group = policy.kv_fq
-        k = kvcache.dequantize_kv(kvcache.quantize_kv(k, fq_bits, fq_group),
-                                  head_dim, k.dtype)
-        v = kvcache.dequantize_kv(kvcache.quantize_kv(v, fq_bits, fq_group),
-                                  head_dim, v.dtype)
+    # layer-kind scopes (HLO metadata only): qkv, kv_write, attention,
+    # attn_out; block_apply adds norm and ffn
+    with jax.named_scope("qkv"):
+        q, k, v = _project_qkv(p, x, src, n_heads=n_heads, n_kv=n_kv,
+                               head_dim=head_dim, qk_norm=qk_norm,
+                               rope=rope and kind != "cross",
+                               positions=positions, rope_theta=rope_theta,
+                               policy=policy)
+        if cache is None and kind != "cross" and \
+                getattr(policy, "kv_fq", None) is not None:
+            # cache-free forward under a kv-quantized policy: round K/V
+            # through the wire format so sensitivity profiling sees
+            # exactly the decode numerics (post-rope, per-position local
+            # regions along head_dim)
+            fq_bits, fq_group = policy.kv_fq
+            k = kvcache.dequantize_kv(
+                kvcache.quantize_kv(k, fq_bits, fq_group), head_dim, k.dtype)
+            v = kvcache.dequantize_kv(
+                kvcache.quantize_kv(v, fq_bits, fq_group), head_dim, v.dtype)
 
     new_cache = cache
-    ring = kind in ("local", "chunked")   # fixed-size rotating cache
-    quant = cache is not None and kvcache.is_quant_kv(cache.get("k"))
-    if quant:
-        qbits, qgroup = kvcache._infer(
-            cache["k"]["packed"].shape[-1], head_dim,
-            cache["k"]["scale"].shape[-1])
     if cache is not None and kind != "cross" and page_table is not None:
         if kind != "full":
             raise ValueError("paged cache supports decode of 'full' "
                              "attention only")
-        page_size = (cache["k"]["packed"] if quant else cache["k"]).shape[1]
+        out, new_cache = _paged_attend(q, k, v, cache, page_table,
+                                       cache_pos, fused)
+    elif cache is not None and kind != "cross":
+        out, new_cache = _cached_attend(q, k, v, cache, cache_pos, kind,
+                                        causal, window)
+    else:
+        with jax.named_scope("attention"):
+            out = _dispatch(q, k, v, kind, causal, window)
+
+    with jax.named_scope("attn_out"):
+        out = out.reshape(b, l, n_heads * head_dim)
+        return layers.dense_apply(p["wo"], out, policy), new_cache
+
+
+def _quant_spec(cache, head_dim):
+    """(bits, group) of a quantized cache's wire format, else None."""
+    if not kvcache.is_quant_kv(cache.get("k")):
+        return None
+    return kvcache._infer(cache["k"]["packed"].shape[-1], head_dim,
+                          cache["k"]["scale"].shape[-1])
+
+
+def _paged_attend(q, k, v, cache, page_table, cache_pos, fused):
+    """Paged decode: write this step's K/V into the slots' pages, then
+    attend over them (fused kernel, or gather + dequant + attend)."""
+    l, head_dim = q.shape[1], q.shape[-1]
+    spec = _quant_spec(cache, head_dim)
+    with jax.named_scope("kv_write"):
+        page_size = (cache["k"]["packed"] if spec else cache["k"]).shape[1]
         wpos = cache_pos[:, None] + jnp.arange(l)           # (B, L) absolute
         # positions beyond the slot's table (a speculative run tailing past
         # max_context) write the scratch page instead of clamping onto the
@@ -422,47 +448,59 @@ def attn_apply(p, x, *, n_heads: int, n_kv: int, head_dim: int,
                                     page_table.shape[1] - 1), axis=1)
         page_idx = jnp.where(wpos < limit, page_idx, 0)
         row = wpos % page_size
-        kw = dict(bits=qbits, group_size=qgroup) if quant else {}
+        kw = dict(bits=spec[0], group_size=spec[1]) if spec else {}
         qk = kvcache.scatter_tokens(cache["k"], k, page_idx, row, **kw)
         qv = kvcache.scatter_tokens(cache["v"], v, page_idx, row, **kw)
+    with jax.named_scope("attention"):
         if fused is not None:
-            new_cache = {"k": qk, "v": qv}
             out = paged_attn.paged_attention(
                 q, qk, qv, page_table, cache_pos,
                 interpret=fused == "interpret")
-            out = out.reshape(b, l, n_heads * head_dim)
-            return layers.dense_apply(p["wo"], out, policy), new_cache
-        if quant:
-            k_cache = kvcache.dequantize_kv(
-                kvcache.gather_pages(qk, page_table), head_dim, q.dtype)
-            v_cache = kvcache.dequantize_kv(
-                kvcache.gather_pages(qv, page_table), head_dim, q.dtype)
         else:
             k_cache = kvcache.gather_pages(qk, page_table)
             v_cache = kvcache.gather_pages(qv, page_table)
-        new_cache = {"k": qk, "v": qv}
-        out = decode_attention(q, k_cache, v_cache, cache_pos)
-    elif cache is not None and kind != "cross":
-        s_len = (cache["k"]["packed"] if quant else cache["k"]).shape[1]
-        if l == 1:  # decode step
-            slot = cache_pos % s_len if ring else cache_pos
-            if quant:
+            if spec:
+                k_cache = kvcache.dequantize_kv(k_cache, head_dim, q.dtype)
+                v_cache = kvcache.dequantize_kv(v_cache, head_dim, q.dtype)
+            out = decode_attention(q, k_cache, v_cache, cache_pos)
+    return out, {"k": qk, "v": qv}
+
+
+def _cached_attend(q, k, v, cache, cache_pos, kind, causal, window):
+    """Contiguous cache: a decode step writes its slot and attends over
+    the cache; a prefill writes [0:L) and attends within the prefix."""
+    l, head_dim = q.shape[1], q.shape[-1]
+    ring = kind in ("local", "chunked")   # fixed-size rotating cache
+    spec = _quant_spec(cache, head_dim)
+    s_len = (cache["k"]["packed"] if spec else cache["k"]).shape[1]
+    if l == 1:  # decode step
+        slot = cache_pos % s_len if ring else cache_pos
+        with jax.named_scope("kv_write"):
+            if spec:
                 # LQ-quantized cache (serve/kvcache.py): write the new slot
                 # in wire format, attend over the dequantized view.  HBM
                 # holds only packed codes + per-region affine.
-                qk = kvcache.update_quant_kv(cache["k"], k, slot, axis=1,
-                                             bits=qbits, group_size=qgroup)
-                qv = kvcache.update_quant_kv(cache["v"], v, slot, axis=1,
-                                             bits=qbits, group_size=qgroup)
-                new_cache = {"k": qk, "v": qv}
-                k_cache = kvcache.dequantize_kv(qk, head_dim, q.dtype)
-                v_cache = kvcache.dequantize_kv(qv, head_dim, q.dtype)
+                new_cache = {
+                    "k": kvcache.update_quant_kv(cache["k"], k, slot, axis=1,
+                                                 bits=spec[0],
+                                                 group_size=spec[1]),
+                    "v": kvcache.update_quant_kv(cache["v"], v, slot, axis=1,
+                                                 bits=spec[0],
+                                                 group_size=spec[1])}
             else:
-                k_cache = jax.lax.dynamic_update_slice_in_dim(
-                    cache["k"], k.astype(cache["k"].dtype), slot, axis=1)
-                v_cache = jax.lax.dynamic_update_slice_in_dim(
-                    cache["v"], v.astype(cache["v"].dtype), slot, axis=1)
-                new_cache = {"k": k_cache, "v": v_cache}
+                new_cache = {
+                    "k": jax.lax.dynamic_update_slice_in_dim(
+                        cache["k"], k.astype(cache["k"].dtype), slot, axis=1),
+                    "v": jax.lax.dynamic_update_slice_in_dim(
+                        cache["v"], v.astype(cache["v"].dtype), slot, axis=1)}
+        with jax.named_scope("attention"):
+            if spec:
+                k_cache = kvcache.dequantize_kv(new_cache["k"], head_dim,
+                                                q.dtype)
+                v_cache = kvcache.dequantize_kv(new_cache["v"], head_dim,
+                                                q.dtype)
+            else:
+                k_cache, v_cache = new_cache["k"], new_cache["v"]
             key_pos = None
             if ring:  # slot s holds absolute position pos - ((pos - s) % S)
                 key_pos = cache_pos - ((cache_pos - jnp.arange(s_len))
@@ -472,43 +510,42 @@ def attn_apply(p, x, *, n_heads: int, n_kv: int, head_dim: int,
                 window=window if kind == "local" else None,
                 chunk=window if kind == "chunked" else None,
                 key_positions=key_pos)
-        else:       # prefill: write cache, attend within the prefix
-            if quant:
-                if ring and l >= s_len:
-                    idx = (jnp.arange(s_len) - l) % s_len
-                    keep_k, keep_v = k[:, l - s_len:][:, idx], \
-                        v[:, l - s_len:][:, idx]
-                    new_cache = {
-                        "k": kvcache.quantize_kv(keep_k, qbits, qgroup),
-                        "v": kvcache.quantize_kv(keep_v, qbits, qgroup)}
-                else:
-                    new_cache = {
-                        "k": kvcache.update_quant_kv(
-                            cache["k"], k, 0, axis=1, bits=qbits,
-                            group_size=qgroup),
-                        "v": kvcache.update_quant_kv(
-                            cache["v"], v, 0, axis=1, bits=qbits,
-                            group_size=qgroup)}
+        return out, new_cache
+    # prefill: write cache, attend within the prefix
+    with jax.named_scope("kv_write"):
+        if spec:
+            if ring and l >= s_len:
+                idx = (jnp.arange(s_len) - l) % s_len
+                keep_k, keep_v = k[:, l - s_len:][:, idx], \
+                    v[:, l - s_len:][:, idx]
+                new_cache = {
+                    "k": kvcache.quantize_kv(keep_k, *spec),
+                    "v": kvcache.quantize_kv(keep_v, *spec)}
             else:
-                kc = k.astype(cache["k"].dtype)
-                vc = v.astype(cache["v"].dtype)
-                if ring and l >= s_len:
-                    # keep the last s_len tokens at slots (t % s_len)
-                    idx = (jnp.arange(s_len) - l) % s_len
-                    k_cache = kc[:, l - s_len:][:, idx]
-                    v_cache = vc[:, l - s_len:][:, idx]
-                else:
-                    k_cache = jax.lax.dynamic_update_slice_in_dim(
-                        cache["k"], kc, 0, axis=1)
-                    v_cache = jax.lax.dynamic_update_slice_in_dim(
-                        cache["v"], vc, 0, axis=1)
-                new_cache = {"k": k_cache, "v": v_cache}
-            out = _dispatch(q, k, v, kind, causal, window)
-    else:
+                new_cache = {
+                    "k": kvcache.update_quant_kv(
+                        cache["k"], k, 0, axis=1, bits=spec[0],
+                        group_size=spec[1]),
+                    "v": kvcache.update_quant_kv(
+                        cache["v"], v, 0, axis=1, bits=spec[0],
+                        group_size=spec[1])}
+        else:
+            kc = k.astype(cache["k"].dtype)
+            vc = v.astype(cache["v"].dtype)
+            if ring and l >= s_len:
+                # keep the last s_len tokens at slots (t % s_len)
+                idx = (jnp.arange(s_len) - l) % s_len
+                new_cache = {"k": kc[:, l - s_len:][:, idx],
+                             "v": vc[:, l - s_len:][:, idx]}
+            else:
+                new_cache = {
+                    "k": jax.lax.dynamic_update_slice_in_dim(
+                        cache["k"], kc, 0, axis=1),
+                    "v": jax.lax.dynamic_update_slice_in_dim(
+                        cache["v"], vc, 0, axis=1)}
+    with jax.named_scope("attention"):
         out = _dispatch(q, k, v, kind, causal, window)
-
-    out = out.reshape(b, l, n_heads * head_dim)
-    return layers.dense_apply(p["wo"], out, policy), new_cache
+    return out, new_cache
 
 
 def _dispatch(q, k, v, kind, causal, window):

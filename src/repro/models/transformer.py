@@ -117,7 +117,11 @@ def block_apply(p, x, spec, cfg: ModelConfig, *, policy: QuantPolicy,
     aux = jnp.zeros((), jnp.float32)
     new_cache = dict(cache) if cache is not None else None
 
-    h = _norm_apply(cfg, p["norm1"], x)
+    # layer-kind scopes (norm / ffn here; qkv / kv_write / attention /
+    # attn_out in attention.attn_apply) are HLO metadata only: a device
+    # trace attributes each op to the kind of work it does
+    with jax.named_scope("norm"):
+        h = _norm_apply(cfg, p["norm1"], x)
     if mixer.startswith("attn"):
         kind, causal, wattr = _attn_kind(mixer)
         window = getattr(cfg, wattr) if wattr else None
@@ -147,7 +151,8 @@ def block_apply(p, x, spec, cfg: ModelConfig, *, policy: QuantPolicy,
     x = x + out
 
     if "cross" in p:
-        h = _norm_apply(cfg, p["norm_cross"], x)
+        with jax.named_scope("norm"):
+            h = _norm_apply(cfg, p["norm_cross"], x)
         ccache = cache.get("cross") if cache else None
         if ccache is not None and enc_out is None:
             # decode: attend over precomputed encoder K/V
@@ -179,13 +184,15 @@ def block_apply(p, x, spec, cfg: ModelConfig, *, policy: QuantPolicy,
         x = x + out
 
     if ffn != "none":
-        h = _norm_apply(cfg, p["norm2"], x)
-        if ffn == "moe":
-            out, aux = moe.moe_apply(
-                p["ffn"], h, n_experts=cfg.n_experts, top_k=cfg.top_k,
-                capacity_factor=cfg.capacity_factor, policy=policy)
-        else:
-            out = mlp.ffn_apply(p["ffn"], h, ffn, policy)
+        with jax.named_scope("norm"):
+            h = _norm_apply(cfg, p["norm2"], x)
+        with jax.named_scope("ffn"):
+            if ffn == "moe":
+                out, aux = moe.moe_apply(
+                    p["ffn"], h, n_experts=cfg.n_experts, top_k=cfg.top_k,
+                    capacity_factor=cfg.capacity_factor, policy=policy)
+            else:
+                out = mlp.ffn_apply(p["ffn"], h, ffn, policy)
         x = x + out
     return x, new_cache, aux
 
@@ -315,7 +322,11 @@ def _stack_apply(params, x, cfg: ModelConfig, pattern, *,
     sup_caches = caches["super"] if caches is not None else None
     xs = (params["super"], sup_caches)
     if params["super"]:
-        (x, aux_total), new_sup = jax.lax.scan(body, (x, aux_total), xs)
+        # the scan's own ops (slicing each layer's weights and cache out
+        # of the stack, stacking the new cache) sit under layer_scan; the
+        # body's ops under their layer kind's scope
+        with jax.named_scope("layer_scan"):
+            (x, aux_total), new_sup = jax.lax.scan(body, (x, aux_total), xs)
     else:
         new_sup = sup_caches
 
@@ -457,8 +468,9 @@ def _stack_apply_planned(params, x, cfg: ModelConfig, pattern, *, policy,
 
         body = _maybe_remat(body, cfg, training)
         # one named scope per walker segment: xprof attributes device time
-        # to the same stack runs serve_phase_ms{layer_run=...} reports
-        with jax.named_scope(f"segment{k}"):
+        # to the same stack runs serve_phase_ms{layer_run=...} reports;
+        # the scan's own ops sit under layer_scan (_stack_apply)
+        with jax.named_scope(f"segment{k}"), jax.named_scope("layer_scan"):
             (x, aux_total), new_seg = jax.lax.scan(
                 body, (x, aux_total), (seg_params, seg_caches))
         if cache_runs is not None:

@@ -16,14 +16,14 @@ from .metrics import (DEFAULT_CLOCK, DEFAULT_MS_BUCKETS, Counter, Gauge,
                       Histogram, MetricsRegistry, NoopMetrics, NOOP_METRICS,
                       Stopwatch, time_fn)
 from .obs import NOOP, Observability
-from .trace import NOOP_TRACER, NULL_CONTEXT, NoopTracer, Tracer
+from .trace import NOOP_TRACER, NoopTracer, Tracer
 
 __all__ = [
     "DEFAULT_CLOCK", "DEFAULT_MS_BUCKETS", "Counter", "Gauge", "Histogram",
     "MetricsRegistry", "NoopMetrics", "NOOP_METRICS", "Stopwatch",
     "time_fn",
     "NOOP", "Observability",
-    "NOOP_TRACER", "NULL_CONTEXT", "NoopTracer", "Tracer",
+    "NOOP_TRACER", "NoopTracer", "Tracer",
     "FlightRecorder", "MetricsServer",
     # quality plane (lazy: numerics/residuals pull in jax + the model
     # stack, which the lightweight consumers of this package never need)
@@ -31,7 +31,7 @@ __all__ = [
     "attach_fleet_quality", "record_weight_wire_error",
     "engine_weight_configs", "record_residuals", "fit_calibration",
     "save_calibration", "load_calibration", "calibrated_hw",
-    "PhaseProfiler", "annotate", "attach_fleet_profilers",
+    "PhaseProfiler", "attach_fleet_profilers",
     "record_utilization", "xprof_capture",
     "SLOSpec", "TenantSLO", "SLOTracker", "good_fraction",
     "validate_report", "HealthMonitor", "attach_fleet_health",
@@ -44,7 +44,7 @@ _LAZY = {
     "engine_weight_configs": "residuals", "record_residuals": "residuals",
     "fit_calibration": "residuals", "save_calibration": "residuals",
     "load_calibration": "residuals", "calibrated_hw": "residuals",
-    "PhaseProfiler": "profile", "annotate": "profile",
+    "PhaseProfiler": "profile",
     "attach_fleet_profilers": "profile", "record_utilization": "profile",
     "xprof_capture": "profile",
     "SLOSpec": "slo", "TenantSLO": "slo", "SLOTracker": "slo",

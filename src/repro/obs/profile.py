@@ -7,10 +7,11 @@ nothing here ever enters the engine's compiled functions, so
 ``decode_compilations`` stays 1 and token streams are bit-identical with
 profiling on:
 
-* **annotations** — :func:`annotate` (a ``jax.profiler.TraceAnnotation``
-  host TraceMe) labels prefill / decode / draft / verify host calls in
-  xprof captures, and ``jax.named_scope`` markers inside the model code
-  (transformer.py) label the HLO ops per phase / walker segment.  Both
+* **annotations** — every ``repro.obs`` span is a profiler TraceMe
+  (obs/trace.py), so step / admit / prefill / decode / fetch / emit /
+  draft / verify host spans show in xprof captures, and
+  ``jax.named_scope`` markers inside the model code (transformer.py,
+  attention.py) label the HLO ops per layer kind / walker segment.  Both
   are metadata-only: numerics and trace caches are untouched.
 * **phase profiler** — :class:`PhaseProfiler`, a scheduler tap (attach
   via ``Server.attach_profiler``).  Every ``every_n_steps`` decode steps
@@ -62,16 +63,6 @@ from repro.obs.metrics import Stopwatch
 # honest attribution is a single fused_attention phase per stack run
 PHASES = ("gather", "dequant", "attention", "lm_head", "other")
 FUSED_PHASES = ("fused_attention", "lm_head", "other")
-
-
-def annotate(name: str):
-    """Host-side xprof annotation (``jax.profiler.TraceAnnotation``).
-
-    Labels the enclosed host work — the dispatch of a prefill/decode/
-    draft/verify call — in programmatic profiler captures.  Metadata
-    only: a TraceMe never touches computation.
-    """
-    return jax.profiler.TraceAnnotation(name)
 
 
 @contextlib.contextmanager

@@ -17,16 +17,23 @@ clock:
 ``to_chrome()`` renders the whole timeline as a ``chrome://tracing`` /
 Perfetto-loadable JSON object; ``save(path)`` writes it.
 
-The serving convention for lanes: ``tid 0`` is the engine lane (prefill /
-decode / draft / verify spans, serialized host-side), and every request
-gets its own lane from :meth:`Tracer.new_tid` carrying its lifecycle
-spans (``queued``, ``request``) and events (``first_token``, ``preempt``,
-``rewind``).
+The serving convention for lanes: ``tid 0`` is the engine lane (step /
+admit / prefill / decode / fetch / emit / draft / verify spans,
+serialized host-side), and every request gets its own lane from
+:meth:`Tracer.new_tid` carrying its lifecycle spans (``queued``,
+``request``) and events (``first_token``, ``preempt``, ``rewind``).
 
-:class:`NoopTracer` is the disabled counterpart: every method is a
-constant-time no-op and ``span()`` returns a shared null context
-manager, so instrumented hot paths pay one attribute lookup when
-tracing is off.
+Every span, enabled or not, is also a profiler TraceMe
+(``jax.profiler.TraceAnnotation(name, **args)``): under a
+``jax.profiler`` session it lands on the host plane, on the clock the
+device ops are stamped with, its args as the event's stats.  With no
+session a TraceMe records nothing and costs one object.
+
+:class:`NoopTracer` is the disabled counterpart: it records nothing of
+its own, and ``span()`` is the TraceMe alone.  ``recording`` says
+whether a span's args are kept anywhere (always for :class:`Tracer`,
+only under a profiler session for :class:`NoopTracer`), so an args
+computation that costs more than a lookup runs only then.
 """
 from __future__ import annotations
 
@@ -36,27 +43,42 @@ import time
 PID = 0   # one serving cell == one trace process
 
 
-class _NullContext:
-    """Reusable do-nothing context manager (the disabled span)."""
-    __slots__ = ()
-
-    def __enter__(self):
-        return None
-
-    def __exit__(self, *exc):
-        return False
+_TRACE_ME = None
 
 
-NULL_CONTEXT = _NullContext()
+def _trace_me_cls():
+    """``jax.profiler.TraceAnnotation``; jax is imported on the first
+    span, so the package's lightweight consumers never load it."""
+    global _TRACE_ME
+    if _TRACE_ME is None:
+        from jax.profiler import TraceAnnotation
+        _TRACE_ME = TraceAnnotation
+    return _TRACE_ME
+
+
+def _trace_me(name: str, args: dict):
+    return _trace_me_cls()(name, **args)
+
+
+def profiling() -> bool:
+    """Whether a profiler session is collecting TraceMes."""
+    return _trace_me_cls().is_enabled()
 
 
 class NoopTracer:
-    """Tracing disabled: records nothing, costs (almost) nothing."""
+    """Tracing disabled: records nothing of its own; a span is still a
+    profiler TraceMe."""
     enabled = False
     events: tuple = ()
 
+    @property
+    def recording(self) -> bool:
+        """Whether a span's args are kept anywhere: only while a profiler
+        session runs, so callers compute them only then."""
+        return profiling()
+
     def span(self, name, *, tid=0, **args):
-        return NULL_CONTEXT
+        return _trace_me(name, args)
 
     def complete(self, name, start, duration, *, tid=0, **args):
         pass
@@ -75,16 +97,19 @@ NOOP_TRACER = NoopTracer()
 
 
 class _Span:
-    """Context manager backing :meth:`Tracer.span`; fills ``dur`` on exit."""
-    __slots__ = ("_tracer", "_ev")
+    """Context manager backing :meth:`Tracer.span`: the span's TraceMe,
+    and ``dur`` filled on exit."""
+    __slots__ = ("_tracer", "_ev", "_tm")
 
-    def __init__(self, tracer, ev):
-        self._tracer, self._ev = tracer, ev
+    def __init__(self, tracer, ev, tm):
+        self._tracer, self._ev, self._tm = tracer, ev, tm
 
     def __enter__(self):
+        self._tm.__enter__()
         return self
 
     def __exit__(self, *exc):
+        self._tm.__exit__(*exc)
         ev = self._ev
         tr = self._tracer
         ev["dur"] = tr._ts_now() - ev["ts"]
@@ -99,6 +124,7 @@ class Tracer:
     tracer's construction instant (Chrome's ``ts`` unit), taken from the
     injectable ``clock`` (seconds, default ``time.perf_counter``)."""
     enabled = True
+    recording = True     # the Chrome JSON keeps every span's args
 
     def __init__(self, clock=time.perf_counter):
         self._clock = clock
@@ -133,6 +159,7 @@ class Tracer:
 
     # ------------------------------------------------------------ record
     def span(self, name: str, *, tid: int = 0, **args):
+        tm = _trace_me(name, args)
         d = self._depth.get(tid, 0)
         ev = {"name": name, "ph": "X", "ts": self._ts_now(), "dur": 0.0,
               "pid": PID, "tid": tid, "depth": d}
@@ -140,7 +167,7 @@ class Tracer:
             ev["args"] = args
         self._depth[tid] = d + 1
         self.events.append(ev)
-        return _Span(self, ev)
+        return _Span(self, ev, tm)
 
     def complete(self, name: str, start: float, duration: float, *,
                  tid: int = 0, **args):

@@ -33,7 +33,6 @@ from repro.models import transformer
 from repro.models.config import ModelConfig
 from repro.models.layers import QuantPolicy, NO_QUANT
 from repro.obs import NOOP, Stopwatch
-from repro.obs.profile import annotate
 from repro.serve.pool import PagedKVPool
 
 
@@ -258,11 +257,12 @@ class PagedEngine(Engine):
         format each layer carries.
         """
         sup_key = "super_segments" if "super_segments" in pages else "super"
-        return {sup_key: kvwire.scatter_prefill(pages[sup_key],
-                                                cache[sup_key], page_ids,
-                                                stacked=True),
-                "tail": kvwire.scatter_prefill(pages["tail"], cache["tail"],
-                                               page_ids)}
+        with jax.named_scope("kv_write"):
+            return {sup_key: kvwire.scatter_prefill(pages[sup_key],
+                                                    cache[sup_key], page_ids,
+                                                    stacked=True),
+                    "tail": kvwire.scatter_prefill(pages["tail"],
+                                                   cache["tail"], page_ids)}
 
     def _prefill_paged_impl(self, params, tokens, pages, page_ids,
                             logits_pos, key):
@@ -272,13 +272,15 @@ class PagedEngine(Engine):
             params, self.cfg, {"tokens": tokens}, cache, policy=self.policy,
             logits_pos=logits_pos)
         pages = self._scatter_bucket(pages, cache, page_ids)
-        return self._sample(logits[:, -1], key), pages
+        with jax.named_scope("sample"):
+            return self._sample(logits[:, -1], key), pages
 
     def _step_paged_impl(self, params, pages, tokens, page_table, pos, key):
         logits, pages = transformer.paged_decode_step(
             params, self.cfg, tokens[:, None], pages, page_table, pos,
             policy=self.policy, fused=self.fused_mode)
-        return self._sample(logits[:, -1], key), pages
+        with jax.named_scope("sample"):
+            return self._sample(logits[:, -1], key), pages
 
     def _multi_paged_impl(self, params, pages, tokens, page_table, pos):
         logits, pages = transformer.paged_decode_multi(
@@ -295,18 +297,18 @@ class PagedEngine(Engine):
         if len(tokens) > bucket:
             raise ValueError(f"prompt len {len(tokens)} > bucket {bucket}")
         obs = self.obs
-        if not obs.enabled:
-            return self._prefill_host(pool, tokens, page_ids, key)
-        # measured wall clock brackets the compiled step end to end:
-        # block_until_ready on the scattered pages, not just the token
-        sw = Stopwatch(obs.clock)
+        sw = Stopwatch(obs.clock) if obs.enabled else None
         with obs.tracer.span("prefill", n_tokens=len(tokens),
                              **self.obs_metric_labels):
             tok = self._prefill_host(pool, tokens, page_ids, key)
-            jax.block_until_ready(pool.pages)
-        obs.metrics.histogram("serve_prefill_ms",
-                              **self.obs_metric_labels).record(
-            sw.elapsed_ms())
+            if sw is not None:
+                # measured wall clock brackets the compiled step end to
+                # end: the scattered pages, not just the token
+                jax.block_until_ready(pool.pages)
+        if sw is not None:
+            obs.metrics.histogram("serve_prefill_ms",
+                                  **self.obs_metric_labels).record(
+                sw.elapsed_ms())
         return tok
 
     def _prefill_host(self, pool: PagedKVPool, tokens, page_ids,
@@ -315,12 +317,26 @@ class PagedEngine(Engine):
         padded[0, :len(tokens)] = tokens
         ids = np.zeros((self.pcfg.pages_per_slot,), np.int32)
         ids[:len(page_ids)] = page_ids
-        with annotate("prefill"):       # xprof TraceMe; metadata only
-            tok, pool.pages = self._prefill_paged(
-                self.params, jnp.asarray(padded), pool.pages,
-                jnp.asarray(ids), jnp.asarray(len(tokens) - 1, jnp.int32),
-                key)
-        return int(tok[0])
+        tok, pool.pages = self._prefill_paged(
+            self.params, jnp.asarray(padded), pool.pages,
+            jnp.asarray(ids), jnp.asarray(len(tokens) - 1, jnp.int32), key)
+        return int(self._fetch(tok)[0])
+
+    def _fetch(self, toks) -> np.ndarray:
+        """A program's sampled tokens on the host, called right after its
+        dispatch.  While a trace records, the wait splits in two:
+        ``fetch.ready`` waits for the program's end, ``fetch.to_host``
+        for the copy, queued behind the program before the wait as one
+        ``np.asarray`` queues it."""
+        tracer = self.obs.tracer
+        with tracer.span("fetch"):
+            if not tracer.recording:
+                return np.asarray(toks)
+            toks.copy_to_host_async()
+            with tracer.span("fetch.ready"):
+                toks.block_until_ready()
+            with tracer.span("fetch.to_host"):
+                return np.asarray(toks)
 
     def decode_step_batch(self, pool: PagedKVPool, tokens, page_table, pos,
                           key) -> np.ndarray:
@@ -328,12 +344,12 @@ class PagedEngine(Engine):
         page_table (max_slots, pages_per_slot).  Returns sampled tokens."""
         obs = self.obs
         sw = Stopwatch(obs.clock) if obs.enabled else None
-        with annotate("decode_step"):   # xprof TraceMe; metadata only
+        with obs.tracer.span("decode_step"):
             toks, pool.pages = self._step_paged(
                 self.params, pool.pages, jnp.asarray(tokens, jnp.int32),
                 jnp.asarray(page_table, jnp.int32),
                 jnp.asarray(pos, jnp.int32), key)
-        out = np.asarray(toks)
+        out = self._fetch(toks)
         if sw is not None:
             jax.block_until_ready(pool.pages)
             obs.metrics.histogram("serve_decode_step_ms",

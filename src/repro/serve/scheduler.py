@@ -43,8 +43,9 @@ class Request:
     rejected_tokens: int = 0  # draft tokens a speculative verify rejected
     arrival: int = 0          # submit order; FCFS tiebreak + victim choice
     tenant: str | None = None  # fleet routing tag (fleet/router.py)
-    # observability state (populated only when the scheduler's obs is
-    # enabled; None otherwise — absolute clock readings in seconds)
+    # observability state, absolute clock readings in seconds: t_queued
+    # always (the admit span's queued_ms), the rest only when the
+    # scheduler's obs is enabled (None otherwise)
     t_submit: float | None = None   # submit() instant
     t_queued: float | None = None   # last (re-)enqueue instant
     t_first: float | None = None    # first emitted token (TTFT anchor)
@@ -120,8 +121,10 @@ class Scheduler:
         self._next_rid += 1
         req = Request(rid, prompt, max_new_tokens, priority=priority,
                       on_token=on_token, arrival=rid, tenant=tenant)
+        # taken whether or not obs is on: the admit span's queued_ms
+        req.t_queued = self.obs.clock()
         if self.obs.enabled:
-            req.t_submit = req.t_queued = self.obs.clock()
+            req.t_submit = req.t_queued
             label = f"{tenant}/r{rid}" if tenant else f"req-{rid}"
             req.trace_tid = self.obs.tracer.new_tid(label)
             self.obs.event("submit", tid=req.trace_tid, rid=rid,
@@ -244,30 +247,41 @@ class Scheduler:
             if not self.pool.alloc(req.rid, need):
                 self._requeue_front(req)
                 return
-            if self.obs.enabled and req.t_queued is not None:
-                now = self.obs.clock()
-                wait = now - req.t_queued
-                self.obs.metrics.histogram(
-                    "serve_queue_wait_ms",
-                    tenant=self._tenant_label(req)).record(wait * 1e3)
-                self.obs.tracer.complete("queued", req.t_queued, wait,
-                                         tid=req.trace_tid, rid=req.rid)
-            first = self.engine.prefill_request(
-                self.pool, tokens, self.pool.pages_of(req.rid),
-                self._fold_key())
-            slot = self._slots.index(None)
-            req.state = RUNNING
-            if resume:
-                tok = req.generated[-1]
-            else:
-                tok = first
-                self._emit(req, tok)
-                if len(req.generated) >= req.max_new_tokens:
-                    self._finish(req, None, events)
-                    continue
-            self._slots[slot] = req
-            self._pos[slot] = len(tokens)
-            self._last_tok[slot] = tok
+            tracer = self.obs.tracer
+            args = (dict(rid=req.rid, prompt_len=len(tokens),
+                         queued_ms=1e3 * (self.obs.clock() - req.t_queued))
+                    if tracer.recording else {})
+            with tracer.span("admit", **args):
+                self._admit_one(req, tokens, resume, events)
+
+    def _admit_one(self, req: Request, tokens: list[int], resume: bool,
+                   events: list[Completion]):
+        """Prefill an allocated request, emit its first token, and give it
+        a slot (or finish it if that token was its last)."""
+        if self.obs.enabled:
+            now = self.obs.clock()
+            wait = now - req.t_queued
+            self.obs.metrics.histogram(
+                "serve_queue_wait_ms",
+                tenant=self._tenant_label(req)).record(wait * 1e3)
+            self.obs.tracer.complete("queued", req.t_queued, wait,
+                                     tid=req.trace_tid, rid=req.rid)
+        first = self.engine.prefill_request(
+            self.pool, tokens, self.pool.pages_of(req.rid),
+            self._fold_key())
+        slot = self._slots.index(None)
+        req.state = RUNNING
+        if resume:
+            tok = req.generated[-1]
+        else:
+            tok = first
+            self._emit(req, tok)
+            if len(req.generated) >= req.max_new_tokens:
+                self._finish(req, None, events)
+                return
+        self._slots[slot] = req
+        self._pos[slot] = len(tokens)
+        self._last_tok[slot] = tok
 
     # ------------------------------------------------------------ preempt
     def _preempt_victim(self) -> bool:
@@ -282,8 +296,8 @@ class Scheduler:
         self.pool.free(req.rid)
         req.state = QUEUED
         req.n_preemptions += 1
+        req.t_queued = self.obs.clock()
         if self.obs.enabled:
-            req.t_queued = self.obs.clock()
             self.obs.event("preempt", tid=req.trace_tid, rid=req.rid,
                            priority=req.priority)
             self.obs.metrics.counter(
@@ -330,6 +344,10 @@ class Scheduler:
         capped at each request's remaining token budget — any cache rows
         the engine wrote past the cap die with the request's pages.
         """
+        with self.obs.tracer.span("step"):
+            return self._step()
+
+    def _step(self) -> list[Completion]:
         events: list[Completion] = []
         self._admit(events)
         self._ensure_pages()
@@ -347,15 +365,34 @@ class Scheduler:
                          - len(self._slots[i].generated))
         pos = np.where([r is not None for r in self._slots], self._pos, 0)
         # the engine-lane decode span; a speculative engine opens its
-        # draft/verify child spans inside it (noop tracer: a shared null
-        # context, no recording)
-        with self.obs.tracer.span("decode", step=self._decode_steps,
-                                  n_slots=len(active)):
+        # draft/verify child spans inside it.  live_tokens: the context
+        # every advanced slot attends over, its token included; like
+        # emit's tokens, counted only while a trace keeps it
+        tracer = self.obs.tracer
+        recording = tracer.recording
+        live = ({"live_tokens": int(sum(pos[i] + 1 for i in active))}
+                if recording else {})
+        with tracer.span("decode", step=self._decode_steps,
+                         n_slots=len(active), **live):
             emitted, rejected = self.engine.advance_slots(
                 self.pool, self._last_tok, table, pos.astype(np.int32),
                 self._fold_key(), budget=budget)
         self._decode_steps += 1
+        out = ({"tokens": sum(min(len(emitted[i]), budget[i])
+                              for i in active)} if recording else {})
+        with tracer.span("emit", **out):
+            self._emit_step(active, emitted, rejected, events)
+        if self.quality is not None:
+            self.quality.on_step(self)
+        if self.profiler is not None:
+            self.profiler.on_step(self)
+        return events
 
+    def _emit_step(self, active: list[int], emitted, rejected,
+                   events: list[Completion]):
+        """Emit each slot's accepted tokens, finish the requests that
+        reached their budget, and roll back a speculative slot's cache
+        rows past what it accepted."""
         look = getattr(self.engine, "lookahead_tokens", 1)
         for i in active:
             req = self._slots[i]
@@ -373,11 +410,6 @@ class Scheduler:
                 # accepted prefix and release surplus lookahead pages —
                 # the slot keeps running (NOT a preemption)
                 self.pool.truncate(req.rid, int(self._pos[i]))
-        if self.quality is not None:
-            self.quality.on_step(self)
-        if self.profiler is not None:
-            self.profiler.on_step(self)
-        return events
 
     def drain(self, max_steps: int | None = None) -> dict[int, list[int]]:
         """Run until every submitted request completes."""

@@ -30,7 +30,6 @@ import numpy as np
 from repro.models import transformer
 from repro.models.config import ModelConfig
 from repro.obs import NOOP, Stopwatch
-from repro.obs.profile import annotate
 from repro.serve.engine import EngineConfig, PagedConfig, PagedEngine
 from repro.serve.pool import PagedKVPool
 from repro.spec.draft import draft_proposals
@@ -224,7 +223,7 @@ class SpeculativeEngine:
         k = self.spec_k
         obs = self._obs
         sw = Stopwatch(obs.clock) if obs.enabled else None
-        with obs.tracer.span("draft", k=k), annotate("draft"):
+        with obs.tracer.span("draft", k=k):
             props = draft_proposals(self.draft, pool.draft, tokens,
                                     page_table, pos, k, key)
             if sw is not None:
@@ -234,7 +233,7 @@ class SpeculativeEngine:
             sw.reset()
         run = np.concatenate(
             [np.asarray(tokens, np.int32)[:, None], props], axis=1)
-        with obs.tracer.span("verify", k=k), annotate("verify"):
+        with obs.tracer.span("verify", k=k):
             greedy = self.verifier.decode_multi_batch(pool, run, page_table,
                                                       pos)
             if sw is not None:

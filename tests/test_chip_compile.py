@@ -153,3 +153,79 @@ def test_decode_attention_no_upcast_copy_on_v5e(one_chip):
     temp = c.memory_analysis().temp_size_in_bytes
     assert temp < 0.5 * one_upcast_copy, \
         f"temps {temp}B vs one f32 cache copy {one_upcast_copy}B"
+
+
+# ---------------------------------------------------------------------------
+# the decode program's device scopes, as the chip's compiler leaves them
+# ---------------------------------------------------------------------------
+
+def _bench():
+    """The benchmark's HLO reading (bench/hlo.py, bench/scopes.py), from
+    the repository root."""
+    import sys
+    from pathlib import Path
+    root = str(Path(__file__).resolve().parents[1])
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench import hlo, scopes
+    return hlo, scopes
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["xla", "fused"])
+def test_decode_copies_carry_a_scope(one_chip, fused):
+    """In the decode program compiled for a v5e (granite widths, two
+    layers, 4-bit weights and KV pages), every scatter and
+    dynamic-update-slice, and every copy that has an op_name, lies under a
+    layer-kind scope, and every copy has a consumer: a device trace splits
+    the copies into the scatter's, the kernel's and the scan's.  Copies of
+    a scalar (the scan's loop counter) are the loop's bookkeeping."""
+    import dataclasses as dc
+    from repro import configs
+    from repro.core import schemes
+    from repro.serve import pool as pool_mod
+    from repro.serve.engine import EngineConfig, PagedConfig, PagedEngine
+    from repro.models import transformer
+    cfg = dc.replace(configs.get("granite-3-2b"), n_layers=2)
+    params = jax.eval_shape(lambda: transformer.init_params(
+        cfg, jax.random.key(0), qcfg=schemes.get("lq4w")))
+    ecfg = EngineConfig(max_len=256, kv_bits=4, kv_group=16,
+                        weight_scheme="lq4w", backend="pallas",
+                        fused_attention=fused)
+    pcfg = PagedConfig(max_slots=SLOTS, page_size=PAGE, n_pages=65,
+                       max_context=256)
+    eng = PagedEngine(cfg, params, ecfg, pcfg)
+    eng.fused_mode = "pallas" if fused else None   # compiled, not interpreted
+    pages = jax.eval_shape(lambda: pool_mod.make_pool_pages(
+        cfg, n_pages=pcfg.n_pages, page_size=PAGE, kv_bits=4, kv_group=16))
+    put = lambda t: jax.tree.map(                  # noqa: E731
+        lambda a: _shape(one_chip, a.shape, a.dtype), t)
+    args = put((eng.params, pages, jax.ShapeDtypeStruct((SLOTS,), jnp.int32),
+                jax.ShapeDtypeStruct((SLOTS, pcfg.pages_per_slot), jnp.int32),
+                jax.ShapeDtypeStruct((SLOTS,), jnp.int32)))
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    c = jax.jit(eng._step_paged_impl).lower(*args, key).compile()
+    assert any(k.startswith("quant_matmul_b4") for k in _kernels(c))
+    hlo, scopes = _bench()
+    text = c.as_text()
+    insts, to = hlo.parse(text), hlo.copy_consumers(text)
+    moves = {n: i for n, i in insts.items()
+             if i.opcode in ("copy", "scatter", "dynamic-update-slice")
+             and not i.shape.split("{")[0].endswith("[]")}
+    assert moves
+    # a scatter or dynamic-update-slice, and a copy with an op_name, lies
+    # under a scope; every copy the program runs has a consumer
+    assert not [n for n, i in moves.items()
+                if (i.path or i.opcode != "copy")
+                and not scopes.scope_of(i.path)]
+    assert not [n for n, i in moves.items() if n in to and not to[n]]
+    assert {"kv_write", "layer_scan"} <= {scopes.scope_of(i.path)
+                                          for i in moves.values()}
+    # the page arrays go through three relayouts a layer: the scan's slice
+    # for the scatter, the scatter's result for the kernel (fused path),
+    # and the layer's pages for the scan's stacking
+    goes = {to[n] for n, i in moves.items()
+            if n in to and i.shape.count(",") >= 2}
+    want = {"scatter@kv_write", "dynamic-update-slice@layer_scan"}
+    if fused:
+        want.add("paged_attention_lut_b4@attention")
+    assert want <= goes, goes

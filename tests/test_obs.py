@@ -332,8 +332,11 @@ class TestSpecWiring:
         server.drain()
         check_trace(obs.tracer.to_chrome(), spec=True)
         check_metrics(obs.metrics.snapshot(), spec=True)
-        decodes = [n for n in obs.tracer.span_tree(tid=0)
-                   if n["name"] == "decode"]
+        # the engine lane: one step span per scheduler step, the decode
+        # span inside it
+        decodes = [c for n in obs.tracer.span_tree(tid=0)
+                   if n["name"] == "step" for c in n["children"]
+                   if c["name"] == "decode"]
         assert decodes, "no decode spans on the engine lane"
         kids = [c["name"] for c in decodes[0]["children"]]
         assert kids == ["draft", "verify"]

@@ -5,6 +5,7 @@ tokens, one compiled decode step), every phase lands in the
 the artifacts pass ``check_profile``, named scopes reach the lowered
 HLO, and the spec engine profiles through its verifier."""
 import json
+import re
 
 import jax
 import jax.numpy as jnp
@@ -15,7 +16,7 @@ from repro.models import transformer
 from repro.models.config import ModelConfig
 from repro.obs import Observability
 from repro.obs.check import check_profile
-from repro.obs.profile import (PHASES, PhaseProfiler, annotate,
+from repro.obs.profile import (PHASES, PhaseProfiler,
                                record_utilization, xprof_capture)
 from repro.plan import QuantPlan
 from repro.plan.plan import candidates_for
@@ -255,23 +256,61 @@ def test_spec_engine_profiles_via_verifier(params):
 # annotations + capture
 # ---------------------------------------------------------------------------
 
-def test_annotate_is_a_context_manager():
-    with annotate("unit-test-span"):
-        x = jnp.ones((2, 2)) + 1
+def _host_events(trace_dir) -> dict:
+    """{name: [stats dict, ...]} of the host-plane events of a capture."""
+    from jax.profiler import ProfileData
+    (path,) = trace_dir.rglob("*.xplane.pb")
+    out: dict = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    out.setdefault(ev.name, []).append(dict(ev.stats))
+    return out
+
+
+@pytest.mark.parametrize("enabled", [False, True])
+def test_annotate_is_a_context_manager(tmp_path, enabled):
+    # every repro.obs span, recording or not, is a profiler TraceMe: its
+    # name and args land on the host plane under a profiler session
+    obs = Observability(enabled=enabled)
+    with xprof_capture(str(tmp_path)):
+        with obs.span("unit-test-span", rid=3, queued_ms=1.5):
+            x = jnp.ones((2, 2)) + 1
     assert float(x.sum()) == 8.0
+    (stats,) = _host_events(tmp_path)["unit-test-span"]
+    assert stats == {"rid": 3, "queued_ms": 1.5}
+    assert len(obs.tracer.events) == (1 if enabled else 0)
 
 
-def test_named_scopes_reach_lowered_hlo(params):
-    pages = _server(params).pool.pages
-    table = jnp.zeros((2, 8), jnp.int32)
-    lowered = jax.jit(
-        lambda p, t, pg, tb, pos: transformer.paged_decode_step(
-            p, TINY, t, pg, tb, pos)
-    ).lower(params, jnp.zeros((2, 1), jnp.int32), pages, table,
-            jnp.zeros((2,), jnp.int32))
-    # named scopes land in the HLO location metadata, not the op text
-    text = lowered.compiler_ir().operation.get_asm(enable_debug_info=True)
-    assert "lm_head" in text and "paged_decode_step" in text
+# the layer-kind scopes of the decode program (models/transformer.py,
+# models/attention.py, serve/engine.py) and the layer scan's own ops
+SCOPES = ("norm", "qkv", "kv_write", "attention", "attn_out", "ffn",
+          "lm_head", "sample", "layer_scan")
+
+
+@pytest.fixture(scope="module", params=[False, True], ids=["xla", "fused"])
+def decode_ir(request, params):
+    """The engine's decode program lowered with debug locations, on the
+    XLA gather path and through the fused kernel (interpret mode)."""
+    ecfg = EngineConfig(max_len=32, kv_bits=8, kv_group=16, backend="ref",
+                        fused_attention=request.param)
+    pcfg = PagedConfig(max_slots=2, page_size=4, n_pages=24, max_context=32)
+    server = Server(TINY, params, ecfg, pcfg, seed=0)
+    eng = server.engine
+    lowered = eng._step_paged.lower(
+        eng.params, server.pool.pages, jnp.zeros((2,), jnp.int32),
+        jnp.zeros((2, 8), jnp.int32), jnp.zeros((2,), jnp.int32),
+        jax.random.key(0))
+    return lowered.compiler_ir().operation.get_asm(enable_debug_info=True)
+
+
+@pytest.mark.parametrize("scope", SCOPES)
+def test_named_scopes_reach_lowered_hlo(decode_ir, scope):
+    # named scopes land in the HLO location metadata, not the op text;
+    # inside the scan body the path restarts at the body's own scopes
+    assert re.search(rf'["/]{scope}/', decode_ir)
+    assert "paged_decode_step" in decode_ir
 
 
 def test_xprof_capture_writes_or_degrades(tmp_path):
