@@ -38,10 +38,20 @@ multi-query run (Lq = k+1, the speculative verify) flatten onto one
 (KV*Lq*G, D) row block, scored against every page row at once, with a
 head mask keeping each query row on its own kv head.  Masking matches
 ``decode_attention`` over the gathered view: key position
-``p*page_size + t`` is visible to query row i iff it is ``<= pos[b] + i``,
-which also hides scratch-padded table entries (their positions lie past
-the slot's live prefix) — an all-masked page contributes nothing because
-masked probabilities are forced to zero *after* the running-max update.
+``p*page_size + t`` is visible to query row i iff it is ``<= pos[b] + i``
+— an all-masked page contributes nothing because masked probabilities
+are forced to zero *after* the running-max update.
+
+The grid stays static, but each slot stops at its last live table entry
+``last[b] = (pos[b] + Lq - 1) // page_size``: the wrapper repeats that
+entry over the rest of the slot's table row, so the page ``index_map``
+repeats its block (the pipeline fetches nothing), and the per-page body
+runs under ``p <= last[b]`` (a third scalar-prefetch operand).  Those
+entries are masked for every query row, so skipping them changes no bit
+of the output, and whatever the table holds there (scratch padding or a
+stale page) is never read.  The clamp lives in the wrapper, not the
+``index_map``: six index maps recomputing it each grid step cost ~0.2 us
+a live step on a v5e.
 
 Quantized pages unpack into ``cpb`` code planes by shift and mask (plane i
 holds feature ``cpb*j + i`` at lane j).  The wrapper hands the kernel its
@@ -172,6 +182,13 @@ def _allowed(nq: int, rows: int, *, lqg: int, gq: int, kvh: int,
     return (row // lqg == col % kvh) & (kpos <= qpos)
 
 
+def _last_page(pos_b, *, lq: int, page_size: int, n_tbl: int):
+    """Slot's last live table entry: query row i sees keys <= pos_b + i,
+    so every entry past ``(pos_b + lq - 1) // page_size`` is masked for
+    all rows (clamped to the table for a run tailing past it)."""
+    return jnp.minimum((pos_b + lq - 1) // page_size, n_tbl - 1)
+
+
 def _online_step(s, allowed, m_ref, l_ref):
     """One flash-decode page update of the running max / denominator;
     returns (probabilities, accumulator correction).
@@ -204,28 +221,34 @@ def _flush(p, p_steps, o_ref, acc_ref, l_ref):
         o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
 
 
-def _kernel_fp(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
+def _kernel_fp(tbl_ref, pos_ref, last_ref, q_ref, k_ref, v_ref, o_ref,
                acc_ref, m_ref, l_ref, *, page_size: int, gq: int, lqg: int,
                kvh: int, p_steps: int, sm_scale: float):
     p = pl.program_id(1)
+    pos_b = pos_ref[pl.program_id(0)]
     _init(p, acc_ref, m_ref, l_ref)
-    q = q_ref[0, 0]                                            # (nq, D)
-    k = k_ref[0].astype(jnp.float32)                           # (R, D)
-    v = v_ref[0].astype(jnp.float32)
-    s = _dot_t(q, k) * sm_scale
-    allowed = _allowed(q.shape[0], k.shape[0], lqg=lqg, gq=gq, kvh=kvh,
-                       page_size=page_size, pos_b=pos_ref[pl.program_id(0)],
-                       p=p)
-    pmat, corr = _online_step(s, allowed, m_ref, l_ref)
-    acc_ref[0] = acc_ref[0] * corr + _dot(pmat, v)
+
+    @pl.when(p <= last_ref[pl.program_id(0)])
+    def _():
+        q = q_ref[0, 0]                                        # (nq, D)
+        k = k_ref[0].astype(jnp.float32)                       # (R, D)
+        v = v_ref[0].astype(jnp.float32)
+        s = _dot_t(q, k) * sm_scale
+        allowed = _allowed(q.shape[0], k.shape[0], lqg=lqg, gq=gq, kvh=kvh,
+                           page_size=page_size, pos_b=pos_b, p=p)
+        pmat, corr = _online_step(s, allowed, m_ref, l_ref)
+        acc_ref[0] = acc_ref[0] * corr + _dot(pmat, v)
+
     _flush(p, p_steps, o_ref, acc_ref, l_ref)
 
 
-def _kernel_quant(tbl_ref, pos_ref, q_ref, qsum_ref, kp_ref, ks_ref, kz_ref,
-                  vp_ref, vs_ref, vz_ref, o_ref, acc_ref, m_ref, l_ref, *,
+def _kernel_quant(tbl_ref, pos_ref, last_ref, q_ref, qsum_ref, kp_ref,
+                  ks_ref, kz_ref, vp_ref, vs_ref, vz_ref, o_ref, acc_ref,
+                  m_ref, l_ref, *,
                   bits: int, gr: int, lut: bool, page_size: int, gq: int,
                   lqg: int, kvh: int, p_steps: int, sm_scale: float):
     p = pl.program_id(1)
+    pos_b = pos_ref[pl.program_id(0)]
     _init(p, acc_ref, m_ref, l_ref)
     nq = m_ref.shape[0]
     codes_v = range(1, 1 << bits)                              # v=0 adds 0
@@ -242,43 +265,46 @@ def _kernel_quant(tbl_ref, pos_ref, q_ref, qsum_ref, kp_ref, ks_ref, kz_ref,
             out = t if out is None else out + t
         return out
 
-    # scores: per region r, scale_r * (q_r . codes) + zmin_r * sum(q_r).
-    # q_ref stacks the region-masked query rows (row r*nq + i holds query
-    # row i restricted to region r), so one matmul per plane gives every
-    # region's code dot at once.
-    k_sc_t = ks_ref[0].T                                       # (Gr, R)
-    cd = None
-    for i, codes in enumerate(_unpack_planes(kp_ref[0], bits)):
-        t = code_matmul(_dot_t, q_ref[0, i], codes)            # (Gr*nq, R)
-        cd = t if cd is None else cd + t
-    s = _dot_t(qsum_ref[0], kz_ref[0])                         # zmin terms
-    for r in range(gr):
-        s = s + cd[r * nq:(r + 1) * nq] * k_sc_t[r:r + 1]
-    s = s * sm_scale
-    allowed = _allowed(nq, s.shape[1], lqg=lqg, gq=gq, kvh=kvh,
-                       page_size=page_size, pos_b=pos_ref[pl.program_id(0)],
-                       p=p)
-    pmat, corr = _online_step(s, allowed, m_ref, l_ref)
-
-    # values: out[:, d in r] = sum_t p_t * (scale_tr * code_td + zmin_tr);
-    # the scale folds into p (one stacked row block per region), the zmin
-    # term is a per-region row sum, and a lane mask keeps region r's block
-    # on region r's lanes.
-    v_sc_t = vs_ref[0].T
-    v_zm_t = vz_ref[0].T
-    p_sc = jnp.concatenate([pmat * v_sc_t[r:r + 1] for r in range(gr)],
-                           axis=0)                             # (Gr*nq, R)
-    zsum = jnp.concatenate(
-        [(pmat * v_zm_t[r:r + 1]).sum(axis=-1, keepdims=True)
-         for r in range(gr)], axis=0)                          # (Gr*nq, 1)
-    w = acc_ref.shape[-1]
-    region = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1) // (w // gr)
-    for i, codes in enumerate(_unpack_planes(vp_ref[0], bits)):
-        pv = code_matmul(_dot, p_sc, codes) + zsum             # (Gr*nq, W)
-        out = jnp.zeros((nq, w), jnp.float32)
+    @pl.when(p <= last_ref[pl.program_id(0)])
+    def _():
+        # scores: per region r, scale_r * (q_r . codes) + zmin_r * sum(q_r).
+        # q_ref stacks the region-masked query rows (row r*nq + i holds query
+        # row i restricted to region r), so one matmul per plane gives every
+        # region's code dot at once.
+        k_sc_t = ks_ref[0].T                                   # (Gr, R)
+        cd = None
+        for i, codes in enumerate(_unpack_planes(kp_ref[0], bits)):
+            t = code_matmul(_dot_t, q_ref[0, i], codes)        # (Gr*nq, R)
+            cd = t if cd is None else cd + t
+        s = _dot_t(qsum_ref[0], kz_ref[0])                     # zmin terms
         for r in range(gr):
-            out = out + jnp.where(region == r, pv[r * nq:(r + 1) * nq], 0.0)
-        acc_ref[i] = acc_ref[i] * corr + out
+            s = s + cd[r * nq:(r + 1) * nq] * k_sc_t[r:r + 1]
+        s = s * sm_scale
+        allowed = _allowed(nq, s.shape[1], lqg=lqg, gq=gq, kvh=kvh,
+                           page_size=page_size, pos_b=pos_b, p=p)
+        pmat, corr = _online_step(s, allowed, m_ref, l_ref)
+
+        # values: out[:, d in r] = sum_t p_t * (scale_tr * code_td + zmin_tr);
+        # the scale folds into p (one stacked row block per region), the zmin
+        # term is a per-region row sum, and a lane mask keeps region r's block
+        # on region r's lanes.
+        v_sc_t = vs_ref[0].T
+        v_zm_t = vz_ref[0].T
+        p_sc = jnp.concatenate([pmat * v_sc_t[r:r + 1] for r in range(gr)],
+                               axis=0)                         # (Gr*nq, R)
+        zsum = jnp.concatenate(
+            [(pmat * v_zm_t[r:r + 1]).sum(axis=-1, keepdims=True)
+             for r in range(gr)], axis=0)                      # (Gr*nq, 1)
+        w = acc_ref.shape[-1]
+        region = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1) // (w // gr)
+        for i, codes in enumerate(_unpack_planes(vp_ref[0], bits)):
+            pv = code_matmul(_dot, p_sc, codes) + zsum         # (Gr*nq, W)
+            out = jnp.zeros((nq, w), jnp.float32)
+            for r in range(gr):
+                out = out + jnp.where(region == r,
+                                      pv[r * nq:(r + 1) * nq], 0.0)
+            acc_ref[i] = acc_ref[i] * corr + out
+
     _flush(p, p_steps, o_ref, acc_ref, l_ref)
 
 
@@ -306,17 +332,22 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *,
     nq = kvh * lqg
     n_tbl = page_table.shape[1]
     quant = isinstance(k_pages, dict)
+    page_size = (k_pages["packed"] if quant else k_pages).shape[1]
     sm_scale = d ** -0.5
 
     # query rows (h, l, g) = h*lqg + l*gq + g; page rows (t, h') = t*KV + h'
     qm = q.transpose(0, 2, 1, 3, 4).reshape(b, nq, d).astype(jnp.float32)
-    tbl = page_table.astype(jnp.int32)
     posb = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
+    # each slot's row repeats its last live entry from there on
+    last = _last_page(posb, lq=lq, page_size=page_size, n_tbl=n_tbl)
+    tbl = jnp.take_along_axis(
+        page_table.astype(jnp.int32),
+        jnp.minimum(jnp.arange(n_tbl)[None], last[:, None]), axis=1)
 
-    def fixed(bi, p, tbl_ref, pos_ref):
+    def fixed(bi, p, tbl_ref, pos_ref, last_ref):
         return (bi, 0, 0, 0)
 
-    def page_map(bi, p, tbl_ref, pos_ref):
+    def page_map(bi, p, tbl_ref, pos_ref, last_ref):
         return (tbl_ref[bi, p], 0, 0)
 
     def rows(a):                       # (n_pages, ps, KV, X) -> (n, ps*KV, X)
@@ -325,17 +356,16 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *,
     def page_spec(a):
         return pl.BlockSpec((1,) + a.shape[1:], page_map)
 
-    statics = dict(gq=gq, lqg=lqg, kvh=kvh, p_steps=n_tbl,
-                   sm_scale=sm_scale)
+    statics = dict(page_size=page_size, gq=gq, lqg=lqg, kvh=kvh,
+                   p_steps=n_tbl, sm_scale=sm_scale)
     if quant:
         packed_d = k_pages["packed"].shape[-1]
         gr = k_pages["scale"].shape[-1]
         bits = _infer_bits(packed_d, d)
         cpb, w = d // packed_d, packed_d
-        page_size = k_pages["packed"].shape[1]
         lut = dequant_path(bits, dequant) == "lut"
         kernel = functools.partial(_kernel_quant, bits=bits, gr=gr, lut=lut,
-                                   page_size=page_size, **statics)
+                                   **statics)
         # query planes (feature cpb*j + i -> plane i, lane j), stacked per
         # region: row r*nq + n holds query row n masked to region r's lanes
         qp = qm.reshape(b, nq, w, cpb).transpose(0, 3, 1, 2)
@@ -346,16 +376,15 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *,
         leaves = [rows(t[f]) for t in (k_pages, v_pages)
                   for f in ("packed", "scale", "zmin")]
         in_specs = [pl.BlockSpec((1, cpb, gr * nq, w), fixed),
-                    pl.BlockSpec((1, nq, gr), lambda bi, p, t, s: (bi, 0, 0))
+                    pl.BlockSpec((1, nq, gr),
+                                 lambda bi, p, *_: (bi, 0, 0))
                     ] + [page_spec(a) for a in leaves]
         operands = (qs, qsum, *leaves)
         name = f"paged_attention_{'lut' if lut else 'affine'}_b{bits}"
     else:
         dequant_path(None, dequant)            # still validates the mode
         cpb, w = 1, d
-        page_size = k_pages.shape[1]
-        kernel = functools.partial(_kernel_fp, page_size=page_size,
-                                   **statics)
+        kernel = functools.partial(_kernel_fp, **statics)
         leaves = [rows(k_pages), rows(v_pages)]
         in_specs = [pl.BlockSpec((1, 1, nq, d), fixed)] \
             + [page_spec(a) for a in leaves]
@@ -365,7 +394,7 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *,
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(b, n_tbl),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, cpb, nq, w), fixed),
@@ -378,6 +407,6 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *,
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name=name,
-    )(tbl, posb, *operands)
+    )(tbl, posb, last, *operands)
     out = out.transpose(0, 2, 3, 1).reshape(b, kvh, lq, gq, d)
     return out.transpose(0, 2, 1, 3, 4).astype(q.dtype)

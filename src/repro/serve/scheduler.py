@@ -366,12 +366,19 @@ class Scheduler:
         pos = np.where([r is not None for r in self._slots], self._pos, 0)
         # the engine-lane decode span; a speculative engine opens its
         # draft/verify child spans inside it.  live_tokens: the context
-        # every advanced slot attends over, its token included; like
-        # emit's tokens, counted only while a trace keeps it
+        # every advanced slot attends over, its token included;
+        # live_pages: the table entries the fused kernel computes a layer,
+        # every slot up to its last live page (an idle slot computes one).
+        # Like emit's tokens, counted only while a trace keeps them
         tracer = self.obs.tracer
         recording = tracer.recording
-        live = ({"live_tokens": int(sum(pos[i] + 1 for i in active))}
-                if recording else {})
+        live = {}
+        if recording:
+            look = getattr(self.engine, "lookahead_tokens", 1)
+            last = np.minimum((pos + look - 1) // self.pcfg.page_size,
+                              self.pcfg.pages_per_slot - 1)
+            live = {"live_tokens": int(sum(pos[i] + 1 for i in active)),
+                    "live_pages": int((last + 1).sum())}
         with tracer.span("decode", step=self._decode_steps,
                          n_slots=len(active), **live):
             emitted, rejected = self.engine.advance_slots(

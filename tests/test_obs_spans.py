@@ -28,14 +28,14 @@ def params():
     return transformer.init_params(TINY, jax.random.key(0))
 
 
-def _drive(params, obs=None):
+def _drive(params, obs=None, prompts=PROMPTS):
     ecfg = EngineConfig(max_len=32, kv_bits=8, kv_group=16, backend="ref")
     pcfg = PagedConfig(max_slots=2, page_size=4, n_pages=24, max_context=32)
     server = Server(TINY, params, ecfg, pcfg, seed=0, obs=obs)
     rng = np.random.default_rng(5)
     rids = [server.submit(list(map(int, rng.integers(0, 256, size=n))),
                           RequestParams(max_new_tokens=MAX_NEW))
-            for n in PROMPTS]
+            for n in prompts]
     server.drain()
     return server, [server.output(r) for r in rids]
 
@@ -84,7 +84,7 @@ def test_serving_spans_on_host_plane(captured):
         assert set(a) == {"rid", "prompt_len", "queued_ms"}
         assert a["queued_ms"] >= 0
     for _, _, _, a in _named(captured, "decode"):
-        assert set(a) == {"step", "n_slots", "live_tokens"}
+        assert set(a) == {"step", "n_slots", "live_tokens", "live_pages"}
     for _, _, _, a in _named(captured, "emit"):
         assert set(a) == {"tokens"}
     assert all("n_tokens" in a for *_, a in _named(captured, "prefill"))
@@ -153,6 +153,39 @@ def test_no_args_and_no_split_unless_recording(params, monkeypatch):
         assert ("fetch.to_host" in spans) is on
         assert all(bool(a) is on for a in spans["admit"] + spans["emit"])
         assert all(("live_tokens" in a) is on for a in spans["decode"])
+        assert all(("live_pages" in a) is on for a in spans["decode"])
+
+
+def test_live_pages_counts_each_slot_to_its_last_live_page(params,
+                                                          monkeypatch):
+    """``decode.live_pages`` is, step by step, the fused kernel's table
+    entries a layer: every slot (idle ones at pos 0 too) up to its last
+    live page, ``pos // page_size + 1``.  Three requests behind two
+    slots leave one slot idle at the end."""
+    from repro.obs import trace as trace_mod
+    from repro.serve.engine import PagedEngine
+    from contextlib import nullcontext
+    spans, seen_pos = [], []
+
+    def trace_me(name, args):
+        if name == "decode":
+            spans.append(dict(args))
+        return nullcontext()
+
+    advance = PagedEngine.advance_slots
+
+    def spy(self, pool, tokens, table, pos, *a, **k):
+        seen_pos.append(np.asarray(pos).copy())
+        return advance(self, pool, tokens, table, pos, *a, **k)
+
+    monkeypatch.setattr(trace_mod, "_trace_me", trace_me)
+    monkeypatch.setattr(trace_mod, "profiling", lambda: True)
+    monkeypatch.setattr(PagedEngine, "advance_slots", spy)
+    server, _ = _drive(params, prompts=PROMPTS[:3])
+    page_size = server.engine.pcfg.page_size
+    want = [int(sum(p // page_size + 1 for p in pos)) for pos in seen_pos]
+    assert [a["live_pages"] for a in spans] == want
+    assert len(set(want)) > 1 and 0 in np.concatenate(seen_pos)
 
 
 def test_enabled_chrome_json_passes_check(params):

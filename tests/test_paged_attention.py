@@ -93,6 +93,59 @@ def test_kernel_matches_xla_baseline(bits, lq):
                                rtol=2e-5, atol=2e-5)
 
 
+def _poison(leaf, pages):
+    """``leaf`` with ``pages`` overwritten by NaN: any read of them that
+    reaches the accumulator (even times a zero probability) shows."""
+    if isinstance(leaf, dict):
+        return {**leaf, "scale": leaf["scale"].at[pages].set(jnp.nan),
+                "zmin": leaf["zmin"].at[pages].set(jnp.nan)}
+    return leaf.at[pages].set(jnp.nan)
+
+
+@pytest.mark.parametrize("lq", [1, 3])
+@pytest.mark.parametrize("bits,dequant", [(None, "auto"), (8, "auto"),
+                                          (4, "lut"), (4, "affine"),
+                                          (2, "auto")])
+def test_entries_past_the_last_live_page_are_never_read(bits, dequant, lq):
+    """Each slot stops at table entry ``(pos + lq - 1) // page_size``.
+    Pointing every entry past it at real pages full of NaN instead of
+    the scratch page changes no bit of the output.  Slots: the last key
+    of page 0 (pos 15) and the first of page 1 (16), an idle slot
+    (pos 0), a run that crosses a boundary at lq 3 (30 -> 32), a context
+    that fills the whole table, and a run tailing past it."""
+    b, page_size, pps, kvh, d = 6, 16, 4, 2, 32
+    full = pps * page_size
+    pos = jnp.asarray([15, 16, 0, 30, full - lq, full - 1], jnp.int32)
+    n_live = pps * b
+    kf = jax.random.normal(KEY, (2 * n_live + 1, page_size, kvh, d),
+                           jnp.float32)
+    vf = jax.random.normal(jax.random.fold_in(KEY, 1), kf.shape,
+                           jnp.float32)
+    q = jax.random.normal(jax.random.fold_in(KEY, 2), (b, lq, kvh, 2, d),
+                          jnp.float32)
+    live = (1 + jnp.arange(n_live, dtype=jnp.int32)).reshape(b, pps)
+    last = np.minimum((np.asarray(pos) + lq - 1) // page_size, pps - 1)
+    dead = np.arange(pps)[None] > last[:, None]
+    assert dead.any() and not dead.all(axis=1).any()
+    scratch = jnp.where(dead, 0, live)
+    stale = jnp.where(dead, live + n_live, live)    # other real pages
+    if bits is not None:
+        kf, vf = kvwire.quantize_kv(kf, bits, 16), kvwire.quantize_kv(
+            vf, bits, 16)
+    poisoned = np.asarray(stale)[dead]
+    k_pg, v_pg = _poison(kf, poisoned), _poison(vf, poisoned)
+
+    def run(table):
+        return np.asarray(paged_attn.paged_attention(
+            q, k_pg, v_pg, table, pos, dequant=dequant, interpret=True))
+
+    got = run(stale)
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, run(scratch))
+    want = _baseline(q, kf, vf, scratch, pos, d)
+    np.testing.assert_allclose(got, np.asarray(want), rtol=2e-5, atol=2e-5)
+
+
 @pytest.mark.parametrize("bits", [4, 2])
 def test_lut_and_affine_dequant_agree(bits):
     """The LUT masked-matmul dataflow is an exact reformulation of the
