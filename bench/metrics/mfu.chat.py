@@ -1,13 +1,13 @@
 """Model FLOPs the window's tokens needed (decode steps over their real
 slots and live contexts, prefills over their real prompt rows) over the
-window times the chip's bf16 peak."""
-from bench import work
+window times the chip's bf16 peak; the FLOPs are the family's counts."""
 
 
 def read(ctx):
-    flops = sum(work.decode_flops(ctx.md, s.contexts)
+    plain = ctx.family.plain
+    flops = sum(plain.decode_flops(ctx.md, s.contexts)
                 for s in ctx.steps if s.contexts)
-    flops += sum(work.prefill_flops(ctx.md, n)
+    flops += sum(plain.prefill_flops(ctx.md, n)
                  for s in ctx.steps for n in s.prefills)
     if flops <= 0:
         return None

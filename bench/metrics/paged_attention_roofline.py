@@ -1,6 +1,7 @@
 """The fused paged-attention kernel's share of its roofline at decode:
 the least time its needed work takes (live pages and live context of the
-slots that hold a request) over its device time."""
+slots that hold a request, in every layer that calls the kernel, as the
+family counts them) over its device time."""
 from bench import work
 from bench.names import DECODE, PAGED_ATTENTION
 
@@ -9,7 +10,8 @@ def read(ctx):
     t = ctx.trace.op_s(PAGED_ATTENTION, DECODE)
     if t <= 0:
         return None
-    least = sum(work.least_time(*work.attention(ctx.md, s.contexts,
-                                                ctx.serving), ctx.peak)
-                for s in ctx.steps if s.contexts)
-    return 100.0 * least * ctx.md["layers"] / t
+    least = sum(work.least_time(f, b, ctx.peak)
+                for s in ctx.steps if s.contexts
+                for f, b in ctx.family.plain.attention_calls(
+                    ctx.md, s.contexts, ctx.serving))
+    return 100.0 * least / t

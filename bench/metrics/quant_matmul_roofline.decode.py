@@ -1,6 +1,7 @@
 """``quant_matmul``'s share of its roofline inside the decode program:
 every call's least time over its real rows (the slots that hold a
-request) and wire bytes, over the kernel's device time."""
+request) and wire bytes, as the family counts the calls, over the
+kernel's device time."""
 from bench import work
 from bench.names import DECODE, QUANT_MATMUL
 
@@ -11,6 +12,6 @@ def read(ctx):
         return None
     least = sum(work.least_time(f, b, ctx.peak)
                 for s in ctx.steps if s.contexts
-                for f, b in work.quant_matmul_calls(
+                for f, b in ctx.family.plain.quant_matmul_calls(
                     ctx.md, len(s.contexts), len(s.contexts)))
     return 100.0 * least / t

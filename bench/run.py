@@ -6,16 +6,21 @@
 
 The cell (``BENCHMARK.json``'s ``workloads`` entry) names a configuration
 (``bench/configs/<config>.json``) and a traffic mix
-(``bench/traffic/<mix>.json``).  One process builds the model through the
+(``bench/traffic/<mix>.json``).  The configuration names its family
+(``"family"``), the directory ``bench/families/<family>/`` that holds
+what depends on the architecture: ``plain.py`` (its sizes, weights,
+reference forward and work counts) and ``served.py`` (its program
+config and parameter tree).  One process builds the model through the
 program's public API with weights drawn from ``--seed`` on the device,
 warms up the cell's own programs from the persistent compilation cache,
 puts the traffic in place, and drives ``Server.submit``/``Server.step``
 for ``--seconds``.  Then it compares what the window served with the plain
-reference (``bench/reference.py``) and prints, as the last line of
-standard output, one JSON object: ``correct``, ``attempted``, ``failed``,
-``metrics`` (the cell's end-to-end metrics with ``--trace 0``, its
-per-layer metrics with ``--trace 1``), ``device``, with ``--trace 1`` a
-``breakdown``, and last ``checks``: each compared number beside its limit.
+reference (``bench/reference.py`` over the family's forward) and prints,
+as the last line of standard output, one JSON object: ``correct``,
+``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics with
+``--trace 0``, its per-layer metrics with ``--trace 1``), ``device``, with
+``--trace 1`` a ``breakdown``, and last ``checks``: each compared number
+beside its limit.
 
 It exits non-zero and prints no result where JAX finds no TPU, fewer chips
 than the cell asks for, a device kind missing from ``bench/peaks.json``,
@@ -29,14 +34,17 @@ T0 = time.perf_counter()
 
 import argparse  # noqa: E402
 import dataclasses  # noqa: E402
+import functools  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
 import threading  # noqa: E402
+import types  # noqa: E402
 from pathlib import Path  # noqa: E402
 
 HERE = Path(__file__).resolve().parent
@@ -44,11 +52,57 @@ ROOT = HERE.parent
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
-from bench import stats, traffic, weights  # noqa: E402
+from bench import stats, traffic  # noqa: E402
+
+FAMILIES = HERE / "families"
 
 
 class BenchError(Exception):
     """A run that must not print a result."""
+
+
+@dataclasses.dataclass(frozen=True)
+class Family:
+    """A configuration's architecture: the two modules of
+    ``bench/families/<name>/``."""
+    name: str
+    plain: types.ModuleType     # dims, draw, forward, work counts, tiny
+    served: types.ModuleType    # model_config, params (the program's types)
+
+
+def _module(path: Path, name: str) -> types.ModuleType:
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@functools.lru_cache(maxsize=None)
+def _family_at(where: Path) -> Family:
+    tag = re.sub(r"\W", "_", where.name)
+    return Family(where.name,
+                  _module(where / "plain.py", f"bench_family_{tag}_plain"),
+                  _module(where / "served.py", f"bench_family_{tag}_served"))
+
+
+def load_family(cfg: dict, root: Path = FAMILIES) -> Family:
+    """The family a configuration file names, from ``root/<family>/``:
+    loaded once a process, so its functions (and what is compiled for
+    them) are the same on every call."""
+    name = cfg.get("family")
+    if not name:
+        raise BenchError(f"configuration {cfg.get('name')!r} names no "
+                         f"family: give it a \"family\" key, a directory "
+                         f"of {root}")
+    if not re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}", str(name)):
+        raise BenchError(f"family {name!r} is not a directory name")
+    where = Path(root).resolve() / name
+    missing = [f for f in ("plain.py", "served.py")
+               if not (where / f).is_file()]
+    if missing:
+        raise BenchError(f"configuration {cfg.get('name')!r} names family "
+                         f"{name!r}, but {where} has no {', '.join(missing)}")
+    return _family_at(where)
 
 
 @dataclasses.dataclass
@@ -56,6 +110,7 @@ class Cell:
     name: str
     chips: int
     config: dict            # bench/configs/<config>.json
+    family: Family          # bench/families/<config's family>/
     mix: dict               # bench/traffic/<mix>.json
     limits: dict            # bench/limits/<cell>.json
     end_to_end: list        # BENCHMARK.json metric entries for this cell
@@ -81,9 +136,10 @@ def load_cell(name: str) -> Cell:
     w = cells[name]
     cfg_entry = {c["name"]: c for c in spec["configs"]}[w["config"]]
     e2e = _for_cell(spec["end_to_end"], name)
+    config = json.loads((ROOT / cfg_entry["file"]).read_text())
     return Cell(
-        name=name, chips=int(w["chips"]),
-        config=json.loads((ROOT / cfg_entry["file"]).read_text()),
+        name=name, chips=int(w["chips"]), config=config,
+        family=load_family(config),
         mix=json.loads((HERE / "traffic" / f"{w['traffic']}.json")
                        .read_text()),
         limits=json.loads((HERE / "limits" / f"{name}.json").read_text()),
@@ -364,11 +420,11 @@ def check(cell: Cell, md: dict, seed: int, chosen: list, *,
     tokens, and ``correct`` is judged on its gap.  Also returns the
     program's own widest gap."""
     from bench import reference
-    serving = cell.config["serving"]
-    w = weights.make(md, seed)
+    serving, plain = cell.config["serving"], cell.family.plain
+    w = plain.draw(md, seed)
     widest, ctrl = 0.0, 0.0
     for prompt, served in chosen:
-        g, c = reference.gaps(w, md, prompt, served,
+        g, c = reference.gaps(plain.forward, w, md, prompt, served,
                               bucket=serving["max_context"],
                               kv_bits=serving["kv_bits"],
                               kv_group=serving["kv_group"], control=control)
@@ -387,11 +443,8 @@ def check(cell: Cell, md: dict, seed: int, chosen: list, *,
 def per_layer(cell: Cell, ctx) -> dict:
     out = {}
     for m in cell.per_layer:
-        path = HERE / "metrics" / f"{m['name']}.py"
-        spec = importlib.util.spec_from_file_location(
-            f"bench_metric_{m['name'].replace('.', '_')}", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
+        mod = _module(HERE / "metrics" / f"{m['name']}.py",
+                      f"bench_metric_{m['name'].replace('.', '_')}")
         v = mod.read(ctx)
         if v is not None:
             out[m["name"]] = {"value": float(v), "unit": m["unit"]}
@@ -403,22 +456,23 @@ class Context:
     """What a per-layer reader reads."""
     trace: object            # bench.trace.Reduced
     steps: list              # Step records of the window
-    md: dict                 # model sizes (bench.weights.dims)
+    md: dict                 # model sizes (the family's plain.dims)
     serving: dict            # the configuration's serving geometry
     peak: dict               # bench/peaks.json entry
+    family: Family | None    # the work counts: family.plain
 
 
 def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
         device: dict, peak: dict, control: bool = False) -> dict:
     import jax
     from bench import program
-    cfg, serving = cell.config, cell.config["serving"]
-    md = weights.dims(cfg)
-    mc = program.model_config(cfg)
+    cfg, serving, fam = cell.config, cell.config["serving"], cell.family
+    md = fam.plain.dims(cfg)
+    mc = program.model_config(cfg, fam)
     book = Book()
     marks = [("start", time.perf_counter() - T0)]
-    w = weights.make(md, seed)
-    srv = program.server(mc, program.params(w, mc), serving,
+    w = fam.plain.draw(md, seed)
+    srv = program.server(mc, program.params(w, mc, fam), serving,
                          on_token=book.on_token)
     del w
     _sync(srv)
@@ -487,7 +541,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
             red = tr.load(tdir)
         finally:
             shutil.rmtree(tdir, ignore_errors=True)
-        ctx = Context(red, book.steps, md, serving, peak)
+        ctx = Context(red, book.steps, md, serving, peak, fam)
         metrics = per_layer(cell, ctx)
         device["busy_s"] = red.busy_s()
         device["window_s"] = red.window_s

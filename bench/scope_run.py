@@ -28,7 +28,6 @@ Like ``bench/run.py`` it needs a TPU.
 from __future__ import annotations
 
 import argparse
-import importlib.util
 import json
 import sys
 from pathlib import Path
@@ -42,12 +41,8 @@ from bench.names import DECODE, PREFILL  # noqa: E402
 
 
 def _reader(name: str):
-    path = HERE / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return R._module(HERE / "metrics" / f"{name}.py",
+                     f"bench_metric_{name.replace('.', '_')}").read
 
 
 def _stat_dump(meta: dict, n: int) -> list:
@@ -95,7 +90,7 @@ def fetches(red: scopes.Scoped, long_ms: float) -> dict:
 
 
 def analyse(red: scopes.Scoped, long_ms: float) -> dict:
-    ctx = R.Context(red, [], {}, {}, {})
+    ctx = R.Context(red, [], {}, {}, {}, None)
     return {"kv_write_share.decode": _reader("kv_write_share.decode")(ctx),
             "decode": program_table(red, DECODE),
             "prefill": program_table(red, PREFILL),

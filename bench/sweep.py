@@ -23,7 +23,7 @@ from pathlib import Path
 ROOT = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT))
 
-from bench import program, stats, traffic, weights  # noqa: E402
+from bench import program, stats, traffic  # noqa: E402
 from bench import run as R  # noqa: E402
 
 
@@ -40,10 +40,10 @@ def main(argv=None) -> int:
     cell = R.load_cell(args.workload)
     R.check_device(cell.chips)
     R.enable_compile_cache()
-    cfg, sv = cell.config, cell.config["serving"]
-    md, mc = weights.dims(cfg), program.model_config(cfg)
-    srv = program.server(mc, program.params(weights.make(md, args.seed), mc),
-                         sv)
+    cfg, sv, fam = cell.config, cell.config["serving"], cell.family
+    md, mc = fam.plain.dims(cfg), program.model_config(cfg, fam)
+    srv = program.server(
+        mc, program.params(fam.plain.draw(md, args.seed), mc, fam), sv)
     R._warm(srv, program, 1)
     burst = traffic.make(dict(cell.mix, rate_per_s=1.0), args.seed,
                          slots=sv["max_slots"], vocab=md["vocab"],

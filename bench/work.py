@@ -1,4 +1,10 @@
-"""Operations and bytes each kernel and each step needs, from shapes.
+"""Operations and bytes of one kernel call, from shapes, and its least time.
+
+A configuration's family (``bench/families/<family>/plain.py``) counts,
+with these, the calls one step of its architecture makes
+(``quant_matmul_calls``, ``attention_calls``) and the model FLOPs of a
+decode step and of a prefill; the per-layer readers in ``bench/metrics/``
+take those counts.
 
 Only needed work counts: real rows (the slots that hold a request, the
 prompt's own tokens, never the padding of the 2048 bucket), the live pages
@@ -14,10 +20,6 @@ page are ``page_size * kv_heads * (head_dim * bits / 8 + 8 * head_dim /
 kv_group)`` per key or value leaf, equal to the pool's own page bytes.
 """
 from __future__ import annotations
-
-import math
-
-from bench import weights
 
 ACT_BYTES = 2          # bfloat16 activations in and out of a kernel
 
@@ -35,56 +37,14 @@ def matmul(m: int, k: int, n: int, *, bits: int = 4,
 
 
 def page_bytes(md: dict, serving: dict) -> int:
-    """Bytes of one page of one layer's key and value leaves together."""
+    """Bytes of one page of one layer's key and value leaves together
+    (``md``: a family's sizes with the head size ``hd`` and the number
+    of key/value heads ``kv``)."""
     hd, g = md["hd"], serving["kv_group"]
     per_head = hd * serving["kv_bits"] // 8 + 8 * (hd // g)
     return 2 * serving["page_size"] * md["kv"] * per_head
 
 
-def projections(md: dict) -> list[tuple[int, int]]:
-    """(K, N) of the packed projections of one decoder layer."""
-    return list(weights.projections(md).values())
-
-
-def quant_matmul_calls(md: dict, rows: int,
-                       head_rows: int) -> list[tuple[float, float]]:
-    """(FLOPs, bytes) of every ``quant_matmul`` call of one forward pass
-    over ``rows`` real rows: each layer's projections, then an untied,
-    packed output head over ``head_rows`` (a prefill reads one position;
-    a tied head is an XLA matmul with the embedding, not this kernel)."""
-    calls = [matmul(rows, k, n) for k, n in projections(md)] * md["layers"]
-    if not md["tied"]:
-        calls.append(matmul(head_rows, md["d"], md["vocab"]))
-    return calls
-
-
-def attention(md: dict, contexts: list[int],
-              serving: dict) -> tuple[float, float]:
-    """(FLOPs, bytes) of one layer's paged attention at decode: each slot's
-    query against its live context (QK and PV), over its live pages."""
-    hq, hd = md["heads"], md["hd"]
-    flops = sum(4.0 * hq * hd * c for c in contexts)
-    pages = sum(math.ceil(c / serving["page_size"]) for c in contexts)
-    io = ACT_BYTES * 2 * len(contexts) * hq * hd
-    return flops, pages * page_bytes(md, serving) + io
-
-
 def least_time(flops: float, nbytes: float, peak: dict) -> float:
     """The roofline bound of one call, in seconds."""
     return max(flops / peak["flops_per_s"], nbytes / peak["bytes_per_s"])
-
-
-def decode_flops(md: dict, contexts: list[int]) -> float:
-    """Model FLOPs one decode step needs for its real slots."""
-    rows = len(contexts)
-    proj = sum(2.0 * rows * k * n for k, n in projections(md))
-    attn = sum(4.0 * md["heads"] * md["hd"] * c for c in contexts)
-    return md["layers"] * (proj + attn) + 2.0 * rows * md["d"] * md["vocab"]
-
-
-def prefill_flops(md: dict, length: int) -> float:
-    """Model FLOPs a prefill of ``length`` real tokens needs: every
-    projection over the prompt, causal attention, one row of the head."""
-    proj = sum(2.0 * length * k * n for k, n in projections(md))
-    attn = 2.0 * md["heads"] * md["hd"] * length * (length + 1)
-    return md["layers"] * (proj + attn) + 2.0 * md["d"] * md["vocab"]

@@ -121,7 +121,7 @@ def red():
 
 
 def ctx_of(red):
-    return R.Context(red, [], {}, {}, {})
+    return R.Context(red, [], {}, {}, {}, None)
 
 
 def test_existing_numbers_unchanged():

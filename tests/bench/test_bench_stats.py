@@ -36,7 +36,8 @@ def test_spread_is_quartile_distance_over_median():
 
 
 def _cell(names):
-    return R.Cell(name="x", chips=1, config={}, mix={}, limits={},
+    return R.Cell(name="x", chips=1, config={}, family=None, mix={},
+                  limits={},
                   end_to_end=[{"name": n, "unit": "u"} for n in names],
                   per_layer=[])
 
