@@ -25,6 +25,7 @@ ARCHS = (
     "internvl2-1b",
     "mamba2-130m",
     "recurrentgemma-2b",
+    "mellum2-12b-a2.5b",
 )
 
 

@@ -26,6 +26,7 @@ from repro.core import packing
 from . import ref as _ref
 from . import quant_matmul as _qm
 from . import act_quant as _aq
+from . import expert_matmul as _em
 from . import lut_matmul as _lm
 
 
@@ -91,6 +92,23 @@ def quant_matmul(x, qw: QWeight, *, backend: str = "auto", **block_kw):
                                bits=qw.bits, group_size=qw.group_size,
                                interpret=(b == "interpret"), **block_kw)
     return out.reshape(*lead, qw.n)
+
+
+expert_row_tile = _em.row_tile
+
+
+def expert_matmul(x, qw: QWeight, tile_expert, n_live, *, tm: int,
+                  backend: str = "auto"):
+    """Grouped x (R, K) @ dequant(qw[e]) over experts: row tile i (``tm``
+    rows) uses expert ``tile_expert[i]`` of the stacked ``qw`` (E, K, N);
+    tiles from ``n_live`` on are not computed.  -> (R, N)."""
+    b = resolve_backend(backend)
+    kw = dict(bits=qw.bits, group_size=qw.group_size, tm=tm)
+    if b == "ref":
+        return _ref.expert_matmul(x, qw.packed, qw.scale, qw.zmin,
+                                  tile_expert, n_live, **kw)
+    return _em.expert_matmul(x, qw.packed, qw.scale, qw.zmin, tile_expert,
+                             n_live, interpret=(b == "interpret"), **kw)
 
 
 def act_quant(x, *, bits: int, group_size: int, backend: str = "auto",

@@ -53,6 +53,15 @@ stale page) is never read.  The clamp lives in the wrapper, not the
 ``index_map``: six index maps recomputing it each grid step cost ~0.2 us
 a live step on a v5e.
 
+A sliding-window layer (static ``window``) also starts late: query row i
+sees only keys in ``(pos[b] + i - window, pos[b] + i]``, so every entry
+before ``first[b] = max(pos[b] - window + 1, 0) // page_size`` is masked
+for all rows.  The wrapper repeats entry ``first[b]`` over the entries
+before it (no fetch), the body runs under ``first[b] <= p <= last[b]``
+(``first`` a fourth scalar-prefetch operand), and the in-page mask drops
+keys at or below ``pos[b] + i - window``.  ``window=None`` traces to the
+kernel above, unchanged.
+
 Quantized pages unpack into ``cpb`` code planes by shift and mask (plane i
 holds feature ``cpb*j + i`` at lane j).  The wrapper hands the kernel its
 queries in the same plane order, stacked once per local region with the
@@ -172,14 +181,18 @@ def _dot(a, b):
 
 
 def _allowed(nq: int, rows: int, *, lqg: int, gq: int, kvh: int,
-             page_size: int, pos_b, p):
+             page_size: int, pos_b, p, window: int | None = None):
     """(nq, rows) mask: query row ``h*lqg + l*gq + g`` may see page row
-    ``t*kvh + h'`` iff it is the same kv head and key pos <= query pos."""
+    ``t*kvh + h'`` iff it is the same kv head and key pos <= query pos
+    (and, with a ``window``, key pos > query pos - window)."""
     row = jax.lax.broadcasted_iota(jnp.int32, (nq, rows), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (nq, rows), 1)
     qpos = pos_b + (row % lqg) // gq
     kpos = p * page_size + col // kvh
-    return (row // lqg == col % kvh) & (kpos <= qpos)
+    ok = (row // lqg == col % kvh) & (kpos <= qpos)
+    if window is not None:
+        ok &= kpos > qpos - window
+    return ok
 
 
 def _last_page(pos_b, *, lq: int, page_size: int, n_tbl: int):
@@ -187,6 +200,24 @@ def _last_page(pos_b, *, lq: int, page_size: int, n_tbl: int):
     so every entry past ``(pos_b + lq - 1) // page_size`` is masked for
     all rows (clamped to the table for a run tailing past it)."""
     return jnp.minimum((pos_b + lq - 1) // page_size, n_tbl - 1)
+
+
+def _first_page(pos_b, *, window: int, page_size: int):
+    """Slot's first live table entry under a sliding window: the earliest
+    query row (row 0) sees keys > pos_b - window, so every entry before
+    ``(pos_b - window + 1) // page_size`` is masked for all rows."""
+    return jnp.maximum(pos_b - window + 1, 0) // page_size
+
+
+def _live(p, bounds):
+    """Whether grid step ``p`` of slot ``program_id(0)`` holds a live page:
+    ``bounds`` is ``(last_ref,)`` or, under a window, ``(last_ref,
+    first_ref)``."""
+    b = pl.program_id(0)
+    live = p <= bounds[0][b]
+    if len(bounds) > 1:
+        live &= p >= bounds[1][b]
+    return live
 
 
 def _online_step(s, allowed, m_ref, l_ref):
@@ -221,32 +252,39 @@ def _flush(p, p_steps, o_ref, acc_ref, l_ref):
         o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
 
 
-def _kernel_fp(tbl_ref, pos_ref, last_ref, q_ref, k_ref, v_ref, o_ref,
-               acc_ref, m_ref, l_ref, *, page_size: int, gq: int, lqg: int,
-               kvh: int, p_steps: int, sm_scale: float):
+def _kernel_fp(tbl_ref, pos_ref, *refs, page_size: int, gq: int, lqg: int,
+               kvh: int, p_steps: int, sm_scale: float,
+               window: int | None = None):
+    n_bounds = 1 if window is None else 2
+    bounds = refs[:n_bounds]
+    q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs[n_bounds:]
     p = pl.program_id(1)
     pos_b = pos_ref[pl.program_id(0)]
     _init(p, acc_ref, m_ref, l_ref)
 
-    @pl.when(p <= last_ref[pl.program_id(0)])
+    @pl.when(_live(p, bounds))
     def _():
         q = q_ref[0, 0]                                        # (nq, D)
         k = k_ref[0].astype(jnp.float32)                       # (R, D)
         v = v_ref[0].astype(jnp.float32)
         s = _dot_t(q, k) * sm_scale
         allowed = _allowed(q.shape[0], k.shape[0], lqg=lqg, gq=gq, kvh=kvh,
-                           page_size=page_size, pos_b=pos_b, p=p)
+                           page_size=page_size, pos_b=pos_b, p=p,
+                           window=window)
         pmat, corr = _online_step(s, allowed, m_ref, l_ref)
         acc_ref[0] = acc_ref[0] * corr + _dot(pmat, v)
 
     _flush(p, p_steps, o_ref, acc_ref, l_ref)
 
 
-def _kernel_quant(tbl_ref, pos_ref, last_ref, q_ref, qsum_ref, kp_ref,
-                  ks_ref, kz_ref, vp_ref, vs_ref, vz_ref, o_ref, acc_ref,
-                  m_ref, l_ref, *,
+def _kernel_quant(tbl_ref, pos_ref, *refs,
                   bits: int, gr: int, lut: bool, page_size: int, gq: int,
-                  lqg: int, kvh: int, p_steps: int, sm_scale: float):
+                  lqg: int, kvh: int, p_steps: int, sm_scale: float,
+                  window: int | None = None):
+    n_bounds = 1 if window is None else 2
+    bounds = refs[:n_bounds]
+    (q_ref, qsum_ref, kp_ref, ks_ref, kz_ref, vp_ref, vs_ref, vz_ref, o_ref,
+     acc_ref, m_ref, l_ref) = refs[n_bounds:]
     p = pl.program_id(1)
     pos_b = pos_ref[pl.program_id(0)]
     _init(p, acc_ref, m_ref, l_ref)
@@ -265,7 +303,7 @@ def _kernel_quant(tbl_ref, pos_ref, last_ref, q_ref, qsum_ref, kp_ref,
             out = t if out is None else out + t
         return out
 
-    @pl.when(p <= last_ref[pl.program_id(0)])
+    @pl.when(_live(p, bounds))
     def _():
         # scores: per region r, scale_r * (q_r . codes) + zmin_r * sum(q_r).
         # q_ref stacks the region-masked query rows (row r*nq + i holds query
@@ -281,7 +319,8 @@ def _kernel_quant(tbl_ref, pos_ref, last_ref, q_ref, qsum_ref, kp_ref,
             s = s + cd[r * nq:(r + 1) * nq] * k_sc_t[r:r + 1]
         s = s * sm_scale
         allowed = _allowed(nq, s.shape[1], lqg=lqg, gq=gq, kvh=kvh,
-                           page_size=page_size, pos_b=pos_b, p=p)
+                           page_size=page_size, pos_b=pos_b, p=p,
+                           window=window)
         pmat, corr = _online_step(s, allowed, m_ref, l_ref)
 
         # values: out[:, d in r] = sum_t p_t * (scale_tr * code_td + zmin_tr);
@@ -308,9 +347,11 @@ def _kernel_quant(tbl_ref, pos_ref, last_ref, q_ref, qsum_ref, kp_ref,
     _flush(p, p_steps, o_ref, acc_ref, l_ref)
 
 
-@functools.partial(jax.jit, static_argnames=("dequant", "interpret"))
+@functools.partial(jax.jit, static_argnames=("window", "dequant",
+                                             "interpret"))
 def paged_attention(q, k_pages, v_pages, page_table, pos, *,
-                    dequant: str = "auto", interpret: bool = False):
+                    window: int | None = None, dequant: str = "auto",
+                    interpret: bool = False):
     """Fused flash-decode over a paged pool, wire format and all.
 
     q (B, Lq, KV, G, D); ``k_pages``/``v_pages`` are one pool leaf — fp
@@ -318,7 +359,9 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *,
     (n_pages, page_size, KV, D/cpb) packed codes (``core/kvwire.py``);
     page_table (B, P) int32 physical page ids, in table order (position t
     lives at table entry t // page_size); pos (B,) int32 — the absolute
-    position of each slot's FIRST query row (query i attends ``<= pos+i``).
+    position of each slot's FIRST query row (query i attends ``<= pos+i``);
+    ``window`` (static) a sliding window: query i attends only keys in
+    ``(pos+i-window, pos+i]``.
     Returns (B, Lq, KV, G, D) in q's dtype.  Token parity with
     ``gather_pages -> dequantize_kv -> decode_attention`` is the contract
     (tests/test_paged_attention.py); bit-identity is not, since the online
@@ -338,16 +381,21 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *,
     # query rows (h, l, g) = h*lqg + l*gq + g; page rows (t, h') = t*KV + h'
     qm = q.transpose(0, 2, 1, 3, 4).reshape(b, nq, d).astype(jnp.float32)
     posb = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
-    # each slot's row repeats its last live entry from there on
+    # each slot's row repeats its last live entry from there on (and,
+    # under a window, its first live entry before it)
     last = _last_page(posb, lq=lq, page_size=page_size, n_tbl=n_tbl)
-    tbl = jnp.take_along_axis(
-        page_table.astype(jnp.int32),
-        jnp.minimum(jnp.arange(n_tbl)[None], last[:, None]), axis=1)
+    entry = jnp.minimum(jnp.arange(n_tbl)[None], last[:, None])
+    bounds = (last,)
+    if window is not None:
+        first = _first_page(posb, window=window, page_size=page_size)
+        entry = jnp.maximum(entry, first[:, None])
+        bounds = (last, first)
+    tbl = jnp.take_along_axis(page_table.astype(jnp.int32), entry, axis=1)
 
-    def fixed(bi, p, tbl_ref, pos_ref, last_ref):
+    def fixed(bi, p, *_):
         return (bi, 0, 0, 0)
 
-    def page_map(bi, p, tbl_ref, pos_ref, last_ref):
+    def page_map(bi, p, tbl_ref, *_):
         return (tbl_ref[bi, p], 0, 0)
 
     def rows(a):                       # (n_pages, ps, KV, X) -> (n, ps*KV, X)
@@ -358,6 +406,8 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *,
 
     statics = dict(page_size=page_size, gq=gq, lqg=lqg, kvh=kvh,
                    p_steps=n_tbl, sm_scale=sm_scale)
+    if window is not None:
+        statics["window"] = window
     if quant:
         packed_d = k_pages["packed"].shape[-1]
         gr = k_pages["scale"].shape[-1]
@@ -394,7 +444,7 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *,
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=2 + len(bounds),
             grid=(b, n_tbl),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, cpb, nq, w), fixed),
@@ -407,6 +457,6 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *,
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name=name,
-    )(tbl, posb, last, *operands)
+    )(tbl, posb, *bounds, *operands)
     out = out.transpose(0, 2, 3, 1).reshape(b, kvh, lq, gq, d)
     return out.transpose(0, 2, 1, 3, 4).astype(q.dtype)

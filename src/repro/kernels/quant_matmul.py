@@ -44,9 +44,16 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import packing
 
 
-def _kernel(x_ref, p_ref, s_ref, z_ref, o_ref, acc_ref, *,
-            bits: int, group_size: int, bk: int, k_steps: int):
-    @pl.when(pl.program_id(2) == 0)
+def _kernel(x_ref, p_ref, s_ref, z_ref, o_ref, acc_ref, **kw):
+    _tile(x_ref, p_ref, s_ref, z_ref, o_ref, acc_ref, pl.program_id(2), **kw)
+
+
+def _tile(x_ref, p_ref, s_ref, z_ref, o_ref, acc_ref, kk, *,
+          bits: int, group_size: int, bk: int, k_steps: int):
+    """One (bm, bn) output tile's K step ``kk``: zero the accumulator at
+    the first, add ``x @ dequant(w)`` over the block's code planes, write
+    the tile at the last (``expert_matmul`` runs it per expert tile)."""
+    @pl.when(kk == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
@@ -66,7 +73,7 @@ def _kernel(x_ref, p_ref, s_ref, z_ref, o_ref, acc_ref, *,
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(pl.program_id(2) == k_steps - 1)
+    @pl.when(kk == k_steps - 1)
     def _flush():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 

@@ -17,6 +17,7 @@ paper's Fig. 4 picture with the weight rows split into local regions.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import packing
@@ -68,6 +69,21 @@ def quant_matmul(x, packed, scale, zmin, *, bits: int, group_size: int):
     """Oracle for kernels.quant_matmul: x @ dequant(w)."""
     w = dequantize_weight(packed, scale, zmin, bits, group_size)
     return (x.astype(jnp.float32) @ w).astype(x.dtype)
+
+
+def expert_matmul(x, packed, scale, zmin, tile_expert, n_live, *,
+                  bits: int, group_size: int, tm: int):
+    """Oracle for kernels.expert_matmul: each ``tm``-row tile of x times
+    its expert's dequantized weights; tiles past ``n_live`` give zeros."""
+    r, k = x.shape
+    w = jax.vmap(lambda p, s, z: dequantize_weight(p, s, z, bits,
+                                                   group_size))(
+        packed, scale, zmin)                                   # (E, K, N)
+    xt = x.astype(jnp.float32).reshape(r // tm, tm, k)
+    out = jnp.einsum("tmk,tkn->tmn", xt, w[tile_expert])
+    live = jnp.arange(r // tm) < n_live
+    out = jnp.where(live[:, None, None], out, 0.0)
+    return out.reshape(r, -1).astype(x.dtype)
 
 
 def act_quant(x, *, bits: int, group_size: int):

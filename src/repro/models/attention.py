@@ -334,7 +334,7 @@ def attn_init(key, *, d_model: int, n_heads: int, n_kv: int, head_dim: int,
 
 
 def _project_qkv(p, x, kv_src, *, n_heads, n_kv, head_dim, qk_norm, rope,
-                 positions, rope_theta, policy: QuantPolicy):
+                 positions, rope_theta, policy: QuantPolicy, yarn=()):
     b, l = x.shape[:2]
     g = n_heads // n_kv
     q = layers.dense_apply(p["wq"], x, policy).reshape(b, l, n_kv, g, head_dim)
@@ -348,15 +348,15 @@ def _project_qkv(p, x, kv_src, *, n_heads, n_kv, head_dim, qk_norm, rope,
         k = layers.rmsnorm_apply(p["k_norm"], k)
     if rope:
         q = layers.apply_rope(q.reshape(b, l, n_kv * g, head_dim),
-                              positions, rope_theta).reshape(q.shape)
-        k = layers.apply_rope(k, positions, rope_theta)
+                              positions, rope_theta, yarn).reshape(q.shape)
+        k = layers.apply_rope(k, positions, rope_theta, yarn)
     return q, k, v
 
 
 def attn_apply(p, x, *, n_heads: int, n_kv: int, head_dim: int,
                kind: str = "full", causal: bool = True,
                window: int | None = None, qk_norm: bool = False,
-               rope: bool = True, rope_theta: float = 1e4,
+               rope: bool = True, rope_theta: float = 1e4, yarn: tuple = (),
                positions=None, kv_src=None, cache=None, cache_pos=None,
                page_table=None, fused: str | None = None,
                policy: QuantPolicy = NO_QUANT):
@@ -371,11 +371,15 @@ def attn_apply(p, x, *, n_heads: int, n_kv: int, head_dim: int,
       then carry a shared (n_pages, page_size, KV, ...) pool instead of a
       per-request (B, S, KV, ...) buffer, cache_pos is a (B,) per-slot
       position vector, and the step writes this token's K/V into its page
-      before attending over the gathered page views (kind 'full' only).
+      before attending over the gathered page views (kinds 'full' and
+      'local'; a local layer's pages hold its whole context, and its
+      attention reads only the last ``window`` positions of it).
     fused: None (XLA gather+dequant path) or 'pallas'/'interpret' — run the
       paged branch through the fused flash-decode kernel
       (``kernels/paged_attention.py``), which streams wire pages through
       VMEM and dequantizes in-register instead of materializing the pool.
+    yarn: YaRN's (factor, original positions, beta_fast, beta_slow,
+      attention_factor) for this layer's rotary embedding; () = plain.
     Returns (out, new_cache).
     """
     b, l, _ = x.shape
@@ -391,7 +395,7 @@ def attn_apply(p, x, *, n_heads: int, n_kv: int, head_dim: int,
                                head_dim=head_dim, qk_norm=qk_norm,
                                rope=rope and kind != "cross",
                                positions=positions, rope_theta=rope_theta,
-                               policy=policy)
+                               policy=policy, yarn=yarn)
         if cache is None and kind != "cross" and \
                 getattr(policy, "kv_fq", None) is not None:
             # cache-free forward under a kv-quantized policy: round K/V
@@ -406,11 +410,12 @@ def attn_apply(p, x, *, n_heads: int, n_kv: int, head_dim: int,
 
     new_cache = cache
     if cache is not None and kind != "cross" and page_table is not None:
-        if kind != "full":
-            raise ValueError("paged cache supports decode of 'full' "
-                             "attention only")
+        if kind not in ("full", "local"):
+            raise ValueError("paged cache supports decode of 'full' and "
+                             "'local' attention only")
         out, new_cache = _paged_attend(q, k, v, cache, page_table,
-                                       cache_pos, fused)
+                                       cache_pos, fused,
+                                       window if kind == "local" else None)
     elif cache is not None and kind != "cross":
         out, new_cache = _cached_attend(q, k, v, cache, cache_pos, kind,
                                         causal, window)
@@ -431,9 +436,11 @@ def _quant_spec(cache, head_dim):
                           cache["k"]["scale"].shape[-1])
 
 
-def _paged_attend(q, k, v, cache, page_table, cache_pos, fused):
+def _paged_attend(q, k, v, cache, page_table, cache_pos, fused,
+                  window=None):
     """Paged decode: write this step's K/V into the slots' pages, then
-    attend over them (fused kernel, or gather + dequant + attend)."""
+    attend over them (fused kernel, or gather + dequant + attend); a
+    ``window`` keeps query i to keys in ``(pos + i - window, pos + i]``."""
     l, head_dim = q.shape[1], q.shape[-1]
     spec = _quant_spec(cache, head_dim)
     with jax.named_scope("kv_write"):
@@ -454,7 +461,7 @@ def _paged_attend(q, k, v, cache, page_table, cache_pos, fused):
     with jax.named_scope("attention"):
         if fused is not None:
             out = paged_attn.paged_attention(
-                q, qk, qv, page_table, cache_pos,
+                q, qk, qv, page_table, cache_pos, window=window,
                 interpret=fused == "interpret")
         else:
             k_cache = kvcache.gather_pages(qk, page_table)
@@ -462,7 +469,8 @@ def _paged_attend(q, k, v, cache, page_table, cache_pos, fused):
             if spec:
                 k_cache = kvcache.dequantize_kv(k_cache, head_dim, q.dtype)
                 v_cache = kvcache.dequantize_kv(v_cache, head_dim, q.dtype)
-            out = decode_attention(q, k_cache, v_cache, cache_pos)
+            out = decode_attention(q, k_cache, v_cache, cache_pos,
+                                   window=window)
     return out, {"k": qk, "v": qv}
 
 

@@ -29,6 +29,9 @@ class ModelConfig:
     qk_norm: bool = False
     rope: bool = True
     rope_theta: float = 1e4
+    # YaRN on the full-attention layers: (factor, original positions,
+    # beta_fast, beta_slow, attention_factor); () = plain RoPE
+    yarn: tuple = ()
     pos_embed: str = "none"           # none (rope) | learned
     attn_bias: bool = False
     vocab_pad: int = 256              # embedding table padded to multiple

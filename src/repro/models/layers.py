@@ -18,6 +18,7 @@ threading extra arguments everywhere.
 from __future__ import annotations
 
 import dataclasses
+import math
 
 import jax
 import jax.numpy as jnp
@@ -207,17 +208,43 @@ def posembed_apply(p, x, offset=0):
 # Rotary position embedding
 # ---------------------------------------------------------------------------
 
-def rope_freqs(head_dim: int, theta: float = 1e4):
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, jnp.float32)
+def rope_freqs(head_dim: int, theta: float = 1e4, yarn: tuple = ()):
+    """Rotary inverse frequencies (D/2,); with ``yarn`` = (factor,
+    original positions, beta_fast, beta_slow, attention_factor), YaRN's
+    blend of them (Hugging Face ``_compute_yarn_parameters``): pair i
+    keeps ``1/theta^(2i/D)`` below ``low``, takes it over ``factor`` above
+    ``high``, and ramps linearly between, where ``low``/``high`` are the
+    pairs that turn ``beta_fast``/``beta_slow`` times over the original
+    context, floored/ceiled and clamped to [0, D-1]."""
+    base = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, jnp.float32)
                             / head_dim))
+    if not yarn:
+        return base
+    factor, orig, beta_fast, beta_slow, _ = yarn
+
+    def pair(rot):      # the pair index whose wavelength is orig / rot
+        return (head_dim * math.log(orig / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(pair(beta_fast)), 0)
+    high = min(math.ceil(pair(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return base / factor * ramp + base * (1.0 - ramp)
 
 
-def apply_rope(x, positions, theta: float = 1e4):
-    """x (..., L, H, D), positions (..., L) int32 -> same shape."""
-    freqs = rope_freqs(x.shape[-1], theta)                     # (D/2,)
+def apply_rope(x, positions, theta: float = 1e4, yarn: tuple = ()):
+    """x (..., L, H, D), positions (..., L) int32 -> same shape.  With
+    ``yarn`` the frequencies are YaRN's and cos/sin are scaled by its
+    attention factor (the fifth entry)."""
+    freqs = rope_freqs(x.shape[-1], theta, yarn)               # (D/2,)
     ang = positions[..., None].astype(jnp.float32) * freqs     # (..., L, D/2)
     cos = jnp.cos(ang)[..., None, :]                           # (..., L, 1, D/2)
     sin = jnp.sin(ang)[..., None, :]
+    if yarn:
+        cos, sin = cos * yarn[4], sin * yarn[4]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin,
                            x2 * cos + x1 * sin], axis=-1)

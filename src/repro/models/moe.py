@@ -1,6 +1,17 @@
-"""Mixture-of-Experts FFN with capacity-based gather/scatter dispatch.
+"""Mixture-of-Experts FFN: drop-free grouped experts for serving, and a
+capacity-based gather/scatter dispatch for training.
 
-Design (DESIGN.md section 6):
+Serving (packed ``QWeight`` experts, ``_moe_dropfree``): no assignment is
+ever dropped, so a token's output depends on that token alone, whoever
+shares the batch and however much padding a prefill bucket holds.  The
+(token, k) assignments are counted and sorted by expert, each expert's
+group padded to the row tile, and each projection is one grouped
+``expert_matmul`` over the sorted rows (``kernels/expert_matmul.py``):
+every hit expert's packed weights stream once per row tile, and no
+FLOP is spent on an expert's empty capacity.  The combine gathers each
+token's k rows and sums them in top-k order.
+
+Training (float experts), design (DESIGN.md section 6):
 
   * top-k routing with a dense router (kept fp32 -- tiny, numerically
     sensitive; DESIGN.md section 4);
@@ -45,45 +56,65 @@ def moe_init(key, *, d_model: int, d_ff: int, n_experts: int,
     return p
 
 
-def _expert_ffn(p, x, policy: QuantPolicy):
-    """x (E, C, d) -> (E, C, d) through per-expert SwiGLU."""
+def _expert_ffn(p, x):
+    """x (E, C, d) -> (E, C, d) through per-expert SwiGLU (float experts;
+    packed ones route drop-free)."""
     wg, wu, wo = p["wi_gate"], p["wi_up"], p["wo"]
-    if isinstance(wg, layers.kops.QWeight):
-        # batched packed experts: vmap the quant matmul over the expert axis
-        qmm = jax.vmap(lambda xx, qq: layers.kops.quant_matmul(
-            xx, qq, backend=policy.backend), in_axes=(0, 0))
-        gate = qmm(x, wg)
-        up = qmm(x, wu)
-        return qmm(jax.nn.silu(gate) * up, wo)
     dt = x.dtype
     gate = jnp.einsum("ecd,edf->ecf", x, wg.astype(dt))
     up = jnp.einsum("ecd,edf->ecf", x, wu.astype(dt))
     return jnp.einsum("ecf,efd->ecd", jax.nn.silu(gate) * up, wo.astype(dt))
 
 
+def _route(router_w, xt, top_k: int):
+    """f32 router: softmax over the experts, top-k, renormalised.
+    Returns (probs (T, E), gate (T, k), expert ids (T, k))."""
+    logits = xt.astype(jnp.float32) @ router_w.astype(jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate_vals, expert_ids = jax.lax.top_k(probs, top_k)
+    gate_vals = gate_vals / jnp.maximum(
+        gate_vals.sum(-1, keepdims=True), 1e-9)
+    return probs, gate_vals, expert_ids
+
+
+def _balance_loss(probs, expert_ids, n_experts: int):
+    """Switch-style load-balancing loss."""
+    me = probs.mean(axis=0)                                     # (E,)
+    ce = jax.nn.one_hot(expert_ids[:, 0], n_experts).mean(axis=0)
+    return n_experts * jnp.sum(me * ce)
+
+
+def _aux(loss, counts) -> dict:
+    """The layer's auxiliary outputs: the balance loss and, from the rows
+    each expert was given, the experts hit and the largest group."""
+    return {"loss": loss, "experts_hit": jnp.sum(counts > 0),
+            "expert_rows_max": jnp.max(counts)}
+
+
 def moe_apply(p, x, *, n_experts: int, top_k: int,
               capacity_factor: float = 1.25,
               policy: QuantPolicy = NO_QUANT):
-    """x (B, L, d) -> (out (B, L, d), aux_loss scalar)."""
+    """x (B, L, d) -> (out (B, L, d), aux): ``aux`` holds the balance
+    ``loss`` and the routing counts ``experts_hit`` and
+    ``expert_rows_max`` (zeros on the expert-parallel path).  Packed
+    experts route drop-free; float experts through capacity buffers."""
+    if isinstance(p["wi_gate"], layers.kops.QWeight):
+        return _moe_dropfree(p, x, n_experts=n_experts, top_k=top_k,
+                             policy=policy)
     from repro.distributed import actshard
     rules = actshard.current_rules()
-    if rules and rules.get("moe_shard_map") and not isinstance(
-            p["wi_gate"], layers.kops.QWeight):
-        return _moe_apply_ep(p, x, n_experts=n_experts, top_k=top_k,
-                             capacity_factor=capacity_factor,
-                             mesh=rules["__mesh__"],
-                             dp_axes=tuple(rules["batch"]),
-                             ep_axis=rules.get("moe_ep_axis", "model"))
+    if rules and rules.get("moe_shard_map"):
+        out, loss = _moe_apply_ep(p, x, n_experts=n_experts, top_k=top_k,
+                                  capacity_factor=capacity_factor,
+                                  mesh=rules["__mesh__"],
+                                  dp_axes=tuple(rules["batch"]),
+                                  ep_axis=rules.get("moe_ep_axis", "model"))
+        return out, _aux(loss, jnp.zeros((n_experts,), jnp.int32))
     b, l, d = x.shape
     t = b * l
     xt = constrain(x.reshape(t, d), "flat_tokens", None)
 
-    logits = (xt.astype(jnp.float32)
-              @ p["router"]["w"].astype(jnp.float32))          # (T, E) fp32
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, expert_ids = jax.lax.top_k(probs, top_k)        # (T, K)
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9)
+    probs, gate_vals, expert_ids = _route(p["router"]["w"], xt, top_k)
 
     capacity = max(int(capacity_factor * t * top_k / n_experts), 1)
 
@@ -118,7 +149,7 @@ def moe_apply(p, x, *, n_experts: int, top_k: int,
     # flops on the scout train cell; §Perf)
     xe = constrain(xe, "experts", "batch", None)
 
-    ye = _expert_ffn(p, xe, policy)                             # (E, C, d)
+    ye = _expert_ffn(p, xe)                                     # (E, C, d)
     ye = constrain(ye, "experts", "batch", None)
 
     # combine: weighted scatter-add back to tokens
@@ -136,11 +167,61 @@ def moe_apply(p, x, *, n_experts: int, top_k: int,
         from . import mlp
         out = out + mlp.swiglu_apply(p["shared"], x, policy)
 
-    # Switch-style load-balancing aux loss
-    me = probs.mean(axis=0)                                     # (E,)
-    ce = jax.nn.one_hot(expert_ids[:, 0], n_experts).mean(axis=0)
-    aux = n_experts * jnp.sum(me * ce)
-    return out, aux
+    return out, _aux(_balance_loss(probs, expert_ids, n_experts),
+                     onehot.sum(axis=0))
+
+
+def _moe_dropfree(p, x, *, n_experts: int, top_k: int, policy: QuantPolicy):
+    """Drop-free routed experts over packed weights (the serving path).
+
+    The T*k assignments are sorted by expert (a stable argsort) and laid
+    out in expert groups, each padded to the row tile ``tm``: at most
+    ``T*k + E*(tm-1)`` rows, a static bound.  Gate, up and down are one
+    grouped ``expert_matmul`` each; the combine gathers each token's k
+    output rows and sums them, weighted, in top-k order, so a token's
+    result is the same alone, in any batch, behind any padding."""
+    b, l, d = x.shape
+    t, e, k = b * l, n_experts, top_k
+    a = t * k
+    tm = layers.kops.expert_row_tile(a, e)
+    rows = -(-(a + e * (tm - 1)) // tm) * tm
+    xt = x.reshape(t, d)
+    with jax.named_scope("router"):
+        probs, gate_vals, expert_ids = _route(p["router"]["w"], xt, k)
+    with jax.named_scope("expert_dispatch"):
+        flat = expert_ids.reshape(-1)                           # (A,)
+        counts = jnp.zeros((e,), jnp.int32).at[flat].add(1)
+        padded = -(-counts // tm) * tm
+        pend = jnp.cumsum(padded)                               # group ends
+        start = jnp.cumsum(counts) - counts
+        order = jnp.argsort(flat, stable=True)                  # by expert
+        e_sorted = flat[order]
+        dest = jnp.zeros((a,), jnp.int32).at[order].set(
+            pend[e_sorted] - padded[e_sorted]
+            + jnp.arange(a, dtype=jnp.int32) - start[e_sorted])
+        # each padded row's token; rows no assignment fills read a zero row
+        src = jnp.full((rows,), t, jnp.int32).at[dest].set(
+            jnp.arange(a, dtype=jnp.int32) // k)
+        xs = jnp.concatenate([xt, jnp.zeros((1, d), xt.dtype)])[src]
+        tiles = jnp.arange(rows // tm, dtype=jnp.int32) * tm
+        tile_expert = jnp.minimum(
+            jnp.sum(pend[None, :] <= tiles[:, None], axis=1), e - 1)
+        n_live = pend[-1] // tm
+    with jax.named_scope("experts"):
+        def mm(h, w):
+            return layers.kops.expert_matmul(h, w, tile_expert, n_live,
+                                             tm=tm, backend=policy.backend)
+        h = (jax.nn.silu(mm(xs, p["wi_gate"]).astype(jnp.float32))
+             * mm(xs, p["wi_up"]).astype(jnp.float32)).astype(x.dtype)
+        ys = mm(h, p["wo"])                                     # (rows, d)
+    with jax.named_scope("expert_combine"):
+        y = ys[dest].reshape(t, k, d).astype(jnp.float32)
+        out = jnp.sum(y * gate_vals[..., None], axis=1)
+        out = out.astype(x.dtype).reshape(b, l, d)
+    if "shared" in p:
+        from . import mlp
+        out = out + mlp.swiglu_apply(p["shared"], x, policy)
+    return out, _aux(_balance_loss(probs, expert_ids, n_experts), counts)
 
 
 # ---------------------------------------------------------------------------

@@ -109,12 +109,28 @@ def _attn_kind(mixer: str):
             "attn_chunked": ("chunked", True, "chunk")}[mixer]
 
 
+def _aux_zero(cfg: ModelConfig) -> dict:
+    """A block's auxiliary outputs, summed over the stack: the MoE
+    load-balancing ``loss``, and in a model with MoE layers the routing
+    counts ``experts_hit`` (experts given a row) and ``expert_rows_max``
+    (the most rows one expert was given)."""
+    aux = {"loss": jnp.zeros((), jnp.float32)}
+    if any(f == "moe" for _, f in cfg.pattern):
+        aux["experts_hit"] = jnp.zeros((), jnp.int32)
+        aux["expert_rows_max"] = jnp.zeros((), jnp.int32)
+    return aux
+
+
+def _aux_add(a: dict, b: dict) -> dict:
+    return jax.tree.map(jnp.add, a, b)
+
+
 def block_apply(p, x, spec, cfg: ModelConfig, *, policy: QuantPolicy,
                 cache=None, cache_pos=None, enc_out=None, positions=None,
                 page_table=None, fused=None):
-    """Returns (x, new_cache, aux)."""
+    """Returns (x, new_cache, aux) with ``aux`` as :func:`_aux_zero`."""
     mixer, ffn = spec
-    aux = jnp.zeros((), jnp.float32)
+    aux = _aux_zero(cfg)
     new_cache = dict(cache) if cache is not None else None
 
     # layer-kind scopes (norm / ffn here; qkv / kv_write / attention /
@@ -130,6 +146,7 @@ def block_apply(p, x, spec, cfg: ModelConfig, *, policy: QuantPolicy,
             p["mixer"], h, n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
             head_dim=cfg.head_dim, kind=kind, causal=causal, window=window,
             qk_norm=cfg.qk_norm, rope=cfg.rope, rope_theta=cfg.rope_theta,
+            yarn=cfg.yarn if mixer == "attn" else (),
             positions=positions, cache=self_cache, cache_pos=cache_pos,
             page_table=page_table, fused=fused, policy=policy)
         if cache is not None:
@@ -202,11 +219,11 @@ def block_apply(p, x, spec, cfg: ModelConfig, *, policy: QuantPolicy,
 # ---------------------------------------------------------------------------
 
 def _block_cache(cfg: ModelConfig, spec, batch: int, max_len: int,
-                 cross: bool, dtype, kv_quant=None):
+                 cross: bool, dtype, kv_quant=None, whole: bool = False):
     mixer, _ = spec
     c = {}
     if mixer.startswith("attn"):
-        if mixer == "attn_local":
+        if mixer == "attn_local" and not whole:
             s = min(max_len, cfg.window)
         elif mixer == "attn_chunked":
             s = min(max_len, cfg.chunk)
@@ -300,7 +317,7 @@ def _stack_apply(params, x, cfg: ModelConfig, pattern, *,
             params, x, cfg, pattern, policy=policy, caches=caches,
             cache_pos=cache_pos, enc_out=enc_out, positions=positions,
             page_table=page_table, fused=fused, training=training)
-    aux_total = jnp.zeros((), jnp.float32)
+    aux_total = _aux_zero(cfg)
 
     def body(carry, xs):
         xx, aux_acc = carry
@@ -314,9 +331,10 @@ def _stack_apply(params, x, cfg: ModelConfig, pattern, *,
                                       positions=positions,
                                       page_table=page_table, fused=fused)
             xx = constrain(xx, "batch", "seq", "embed")
+            aux_acc = _aux_add(aux_acc, aux)
             new_caches.append(nc)
         out_caches = tuple(new_caches) if blk_caches is not None else None
-        return (xx, aux_acc + aux), out_caches
+        return (xx, aux_acc), out_caches
 
     body = _maybe_remat(body, cfg, training)
     sup_caches = caches["super"] if caches is not None else None
@@ -338,7 +356,7 @@ def _stack_apply(params, x, cfg: ModelConfig, pattern, *,
                                  cache_pos=cache_pos, enc_out=enc_out,
                                  positions=positions, page_table=page_table,
                                  fused=fused)
-        aux_total = aux_total + aux
+        aux_total = _aux_add(aux_total, aux)
         new_tail.append(nc)
 
     new_caches = None
@@ -405,7 +423,7 @@ def _stack_apply_planned(params, x, cfg: ModelConfig, pattern, *, policy,
             f"policy implies {len(segs)} segments but params carry "
             f"{len(seg_param_list)} — plan/params mismatch")
 
-    aux_total = jnp.zeros((), jnp.float32)
+    aux_total = _aux_zero(cfg)
     sup_caches = cache_runs = None
     if caches is not None:
         if "super_segments" in caches:
@@ -462,9 +480,10 @@ def _stack_apply_planned(params, x, cfg: ModelConfig, pattern, *, policy,
                                           page_table=page_table,
                                           fused=fused)
                 xx = constrain(xx, "batch", "seq", "embed")
+                aux_acc = _aux_add(aux_acc, aux)
                 new_caches.append(nc)
             out = tuple(new_caches) if blk_caches is not None else None
-            return (xx, aux_acc + aux), out
+            return (xx, aux_acc), out
 
         body = _maybe_remat(body, cfg, training)
         # one named scope per walker segment: xprof attributes device time
@@ -506,7 +525,7 @@ def _stack_apply_planned(params, x, cfg: ModelConfig, pattern, *, policy,
                                      cache=ct, cache_pos=cache_pos,
                                      enc_out=enc_out, positions=positions,
                                      page_table=page_table, fused=fused)
-        aux_total = aux_total + aux
+        aux_total = _aux_add(aux_total, aux)
         new_tail.append(nc)
 
     new_caches = None
@@ -605,7 +624,7 @@ def forward(params, cfg: ModelConfig, batch, *, policy: QuantPolicy = NO_QUANT,
                              training=training)
     x = _norm_apply(cfg, params["final_norm"], x)
     logits = _logits(params, cfg, x, policy)
-    return logits, aux
+    return logits, aux["loss"]
 
 
 def _logits(params, cfg: ModelConfig, x, policy):
@@ -649,13 +668,16 @@ def normalize_kv_quant(cfg: ModelConfig, kv_quant):
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int,
-               dtype=None, kv_quant=None) -> dict:
+               dtype=None, kv_quant=None, whole: bool = False) -> dict:
     """Decode cache.  ``kv_quant=(bits, group_size)`` stores attention K/V
     in the LQ wire format (bits in {8,4,2,1}; group_size divides head_dim);
     ``bits`` may be a per-layer sequence (see :func:`normalize_kv_quant`),
     in which case superblocks are stacked per run of identical kv bits
     under a ``"super_segments"`` key — packed wire shapes differ across
     bitwidths, so heterogeneous layers cannot share one stacked array.
+    ``whole`` gives sliding-window layers every one of ``max_len``
+    positions instead of a ``window``-long ring (the paged prefill's
+    bucket, scattered page for page into the pool).
     """
     dtype = dtype or cfg.activation_dtype
     cross = cfg.n_enc_layers > 0
@@ -670,13 +692,14 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
         return None if b is None else (b, kv_quant[1])
 
     def stacked(stack: int, spec, kvq):
-        one = _block_cache(cfg, spec, batch, max_len, cross, dtype, kvq)
+        one = _block_cache(cfg, spec, batch, max_len, cross, dtype, kvq,
+                           whole)
         return jax.tree.map(
             lambda a: jnp.zeros((stack,) + a.shape, a.dtype), one)
 
     tail = [_block_cache(cfg, cfg.pattern[(cfg.n_super * p_len + t) % p_len],
                          batch, max_len, cross, dtype,
-                         layer_kvq(cfg.n_super * p_len + t))
+                         layer_kvq(cfg.n_super * p_len + t), whole)
             for t in range(cfg.n_tail)]
     out = {"tail": tail, "pos": jnp.zeros((), jnp.int32)}
     if per_layer:
@@ -764,7 +787,8 @@ def paged_decode_step(params, cfg: ModelConfig, tokens, pages, page_table,
     Inactive slots point at the scratch page and are masked by the caller.
     ``fused`` ('pallas' | 'interpret' | None) routes every layer's
     attention through the fused paged kernel instead of gather+dequant.
-    Returns (logits (B, 1, V), new pages).
+    Returns (logits (B, 1, V), new pages, aux): ``aux`` as
+    :func:`_aux_zero`, summed over the layers.
     """
     if cfg.pos_embed == "learned":
         raise ValueError("paged decode needs per-slot positions; learned "
@@ -772,7 +796,7 @@ def paged_decode_step(params, cfg: ModelConfig, tokens, pages, page_table,
     x = layers.embed_apply(params["embed"], tokens)
     x = x.astype(cfg.activation_dtype)
     with jax.named_scope("paged_decode_step"):
-        x, new_pages, _ = _stack_apply(
+        x, new_pages, aux = _stack_apply(
             params["decoder"], x, cfg, cfg.pattern, policy=policy,
             caches=_layer_caches(pages),
             cache_pos=pos, enc_out=None, positions=pos[:, None],
@@ -780,7 +804,7 @@ def paged_decode_step(params, cfg: ModelConfig, tokens, pages, page_table,
     with jax.named_scope("lm_head"):
         x = _norm_apply(cfg, params["final_norm"], x)
         logits = _logits(params, cfg, x, policy)
-    return logits, new_pages
+    return logits, new_pages, aux
 
 
 def paged_decode_multi(params, cfg: ModelConfig, tokens, pages, page_table,

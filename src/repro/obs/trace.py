@@ -108,6 +108,12 @@ class _Span:
         self._tm.__enter__()
         return self
 
+    def set_metadata(self, **args):
+        """Add args once the body knows them (a TraceMe's own
+        ``set_metadata``, which the disabled tracer's span is)."""
+        self._tm.set_metadata(**args)
+        self._ev.setdefault("args", {}).update(args)
+
     def __exit__(self, *exc):
         self._tm.__exit__(*exc)
         ev = self._ev

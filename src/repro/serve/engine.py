@@ -216,6 +216,11 @@ class PagedEngine(Engine):
                                and self.fused_mode is None)
         self._fused_fallback_reported = False
         self.report_attention_mode()
+        # a model with MoE layers returns its routing counts (experts hit,
+        # largest group; summed over layers) beside the decode step's
+        # tokens, fetched only while a trace records (``step_counts``)
+        self.counts_experts = any(f == "moe" for _, f in cfg.pattern)
+        self.step_counts: dict = {}
         self._prefill_paged = jax.jit(self._prefill_paged_impl)
         self._step_paged = jax.jit(self._step_paged_impl)
         self._multi_paged = jax.jit(self._multi_paged_impl)
@@ -267,7 +272,7 @@ class PagedEngine(Engine):
     def _prefill_paged_impl(self, params, tokens, pages, page_ids,
                             logits_pos, key):
         cache = transformer.init_cache(self.cfg, 1, self.pcfg.max_context,
-                                       kv_quant=self._kvq)
+                                       kv_quant=self._kvq, whole=True)
         logits, cache = transformer.prefill(
             params, self.cfg, {"tokens": tokens}, cache, policy=self.policy,
             logits_pos=logits_pos)
@@ -276,11 +281,15 @@ class PagedEngine(Engine):
             return self._sample(logits[:, -1], key), pages
 
     def _step_paged_impl(self, params, pages, tokens, page_table, pos, key):
-        logits, pages = transformer.paged_decode_step(
+        logits, pages, aux = transformer.paged_decode_step(
             params, self.cfg, tokens[:, None], pages, page_table, pos,
             policy=self.policy, fused=self.fused_mode)
         with jax.named_scope("sample"):
-            return self._sample(logits[:, -1], key), pages
+            toks = self._sample(logits[:, -1], key)
+        if self.counts_experts:
+            return (toks, jnp.stack([aux["experts_hit"],
+                                     aux["expert_rows_max"]])), pages
+        return toks, pages
 
     def _multi_paged_impl(self, params, pages, tokens, page_table, pos):
         logits, pages = transformer.paged_decode_multi(
@@ -322,20 +331,27 @@ class PagedEngine(Engine):
             jnp.asarray(ids), jnp.asarray(len(tokens) - 1, jnp.int32), key)
         return int(self._fetch(tok)[0])
 
-    def _fetch(self, toks) -> np.ndarray:
+    def _fetch(self, toks, counts=None) -> np.ndarray:
         """A program's sampled tokens on the host, called right after its
         dispatch.  While a trace records, the wait splits in two:
         ``fetch.ready`` waits for the program's end, ``fetch.to_host``
         for the copy, queued behind the program before the wait as one
-        ``np.asarray`` queues it."""
+        ``np.asarray`` queues it; ``counts`` (the decode step's routing
+        counts) come with the tokens then, into ``step_counts``."""
         tracer = self.obs.tracer
         with tracer.span("fetch"):
             if not tracer.recording:
                 return np.asarray(toks)
             toks.copy_to_host_async()
+            if counts is not None:
+                counts.copy_to_host_async()
             with tracer.span("fetch.ready"):
                 toks.block_until_ready()
             with tracer.span("fetch.to_host"):
+                if counts is not None:
+                    hit, rows = np.asarray(counts).tolist()
+                    self.step_counts = {"experts_hit": hit,
+                                        "expert_rows_max": rows}
                 return np.asarray(toks)
 
     def decode_step_batch(self, pool: PagedKVPool, tokens, page_table, pos,
@@ -349,7 +365,10 @@ class PagedEngine(Engine):
                 self.params, pool.pages, jnp.asarray(tokens, jnp.int32),
                 jnp.asarray(page_table, jnp.int32),
                 jnp.asarray(pos, jnp.int32), key)
-        out = self._fetch(toks)
+        counts = None
+        if self.counts_experts:
+            toks, counts = toks
+        out = self._fetch(toks, counts)
         if sw is not None:
             jax.block_until_ready(pool.pages)
             obs.metrics.histogram("serve_decode_step_ms",

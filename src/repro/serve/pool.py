@@ -8,6 +8,11 @@ otherwise.  Requests own ordered page lists (page tables); the device-side
 gather/scatter lives in core/kvwire.py and models/attention.py; this class
 is the host-side allocator: alloc/free/defrag plus accounting.
 
+Full and sliding-window (``attn_local``) layers share one page geometry:
+a windowed layer's pages hold its whole context, though its attention
+reads only the last ``window`` positions (a known cost: ring or
+per-layer page counts would free the rest).
+
 Page 0 is reserved as a scratch page.  Padded page-table entries and
 inactive decode slots read and write it; decode masking guarantees its
 garbage never reaches a real output.
@@ -25,10 +30,11 @@ from repro.obs import NOOP
 
 def _check_paged_support(cfg: ModelConfig):
     for mixer, _ in cfg.pattern:
-        if mixer != "attn":
+        if mixer not in ("attn", "attn_local"):
             raise ValueError(
-                f"paged KV pool supports full-attention decoders only; "
-                f"mixer {mixer!r} needs the contiguous Engine path")
+                f"paged KV pool supports full and sliding-window attention "
+                f"decoders only; mixer {mixer!r} needs the contiguous "
+                f"Engine path")
     if cfg.n_enc_layers:
         raise ValueError("paged serving does not support encoder-decoder")
     if cfg.frontend != "none":

@@ -368,7 +368,11 @@ class Scheduler:
         # draft/verify child spans inside it.  live_tokens: the context
         # every advanced slot attends over, its token included;
         # live_pages: the table entries the fused kernel computes a layer,
-        # every slot up to its last live page (an idle slot computes one).
+        # every slot up to its last live page (an idle slot computes one);
+        # window_pages: the entries before each slot's first live page
+        # that the sliding-window layers skip, summed over those layers;
+        # experts_hit / expert_rows_max: the engine's routing counts of
+        # the step (``step_counts``), summed over the MoE layers.
         # Like emit's tokens, counted only while a trace keeps them
         tracer = self.obs.tracer
         recording = tracer.recording
@@ -379,11 +383,21 @@ class Scheduler:
                               self.pcfg.pages_per_slot - 1)
             live = {"live_tokens": int(sum(pos[i] + 1 for i in active)),
                     "live_pages": int((last + 1).sum())}
+            cfg = self.engine.cfg
+            n_local = sum(1 for i in range(cfg.n_layers)
+                          if cfg.layer_spec(i)[0] == "attn_local")
+            if n_local:
+                first = np.maximum(pos - cfg.window + 1, 0) \
+                    // self.pcfg.page_size
+                live["window_pages"] = int(n_local * first.sum())
         with tracer.span("decode", step=self._decode_steps,
-                         n_slots=len(active), **live):
+                         n_slots=len(active), **live) as span:
             emitted, rejected = self.engine.advance_slots(
                 self.pool, self._last_tok, table, pos.astype(np.int32),
                 self._fold_key(), budget=budget)
+            counts = getattr(self.engine, "step_counts", None)
+            if recording and counts:
+                span.set_metadata(**counts)
         self._decode_steps += 1
         out = ({"tokens": sum(min(len(emitted[i]), budget[i])
                               for i in active)} if recording else {})

@@ -28,6 +28,7 @@ qm = importlib.import_module("repro.kernels.quant_matmul")
 aq = importlib.import_module("repro.kernels.act_quant")
 lm = importlib.import_module("repro.kernels.lut_matmul")
 pa = importlib.import_module("repro.kernels.paged_attention")
+em = importlib.import_module("repro.kernels.expert_matmul")
 
 D_MODEL, D_FF, VOCAB = 2048, 8192, 49155
 KV, G, HEAD_DIM, PAGE = 8, 4, 64, 16
@@ -114,6 +115,78 @@ def test_paged_attention_compiles(one_chip, bits, dequant, lq):
         leaf, leaf, _shape(one_chip, (SLOTS, pps), jnp.int32),
         _shape(one_chip, (SLOTS,), jnp.int32))
     assert any("paged_attention_" in name for name in _kernels(c))
+
+
+# mellum2-12b-a2.5b: 64 experts of width 896 over d_model 2304, top-8;
+# 4 kv heads of 128, GQA group 8, a 1024-token window
+M_D, M_FF, M_E, M_TOP = 2304, 896, 64, 8
+M_KV, M_G, M_HD, M_WINDOW = 4, 8, 128, 1024
+
+
+@pytest.mark.parametrize("tokens", [32, 2048], ids=["decode", "prefill"])
+@pytest.mark.parametrize("k,n", [(M_D, M_FF), (M_FF, M_D)],
+                         ids=["gate_up", "down"])
+def test_expert_matmul_compiles(one_chip, tokens, k, n):
+    tm = em.row_tile(tokens * M_TOP, M_E)
+    rows = -(-(tokens * M_TOP + M_E * (tm - 1)) // tm) * tm
+    c = _compile(lambda x, p, s, z, te, nl: em.expert_matmul(
+        x, p, s, z, te, nl, bits=4, group_size=GS, tm=tm),
+        _shape(one_chip, (rows, k), jnp.bfloat16),
+        _shape(one_chip, (M_E, k // 2, n), jnp.uint8),
+        _shape(one_chip, (M_E, k // GS, n), jnp.float32),
+        _shape(one_chip, (M_E, k // GS, n), jnp.float32),
+        _shape(one_chip, (rows // tm,), jnp.int32),
+        _shape(one_chip, (), jnp.int32))
+    names = _kernels(c)
+    assert any("expert_matmul_b4g128" in name for name in names)
+    assert not any("quant_matmul_b" in name for name in names)
+
+
+def _mellum_pages(one_chip, n_pages=256):
+    gr = M_HD // 16
+    return {"packed": _shape(one_chip, (n_pages, PAGE, M_KV, M_HD // 2),
+                             jnp.uint8),
+            "scale": _shape(one_chip, (n_pages, PAGE, M_KV, gr), jnp.float32),
+            "zmin": _shape(one_chip, (n_pages, PAGE, M_KV, gr), jnp.float32)}
+
+
+@pytest.mark.parametrize("lq", [1, 5])
+def test_windowed_paged_attention_compiles(one_chip, lq):
+    leaf = _mellum_pages(one_chip)
+    c = _compile(lambda q, k, v, t, p: pa.paged_attention(
+        q, k, v, t, p, window=M_WINDOW),
+        _shape(one_chip, (32, lq, M_KV, M_G, M_HD), jnp.bfloat16),
+        leaf, leaf, _shape(one_chip, (32, 128), jnp.int32),
+        _shape(one_chip, (32,), jnp.int32))
+    assert any("paged_attention_lut_b4" in name for name in _kernels(c))
+
+
+def test_no_window_lowers_to_the_unwindowed_kernel(one_chip):
+    """``window=None`` is the kernel without a window, HLO text and all:
+    full-attention layers compile to the same program as before.  The
+    serialized kernel body carries the tracing call stack's source
+    locations, which differ between the two calls, so they are left
+    out."""
+    leaf = _mellum_pages(one_chip)
+    args = (_shape(one_chip, (32, 1, M_KV, M_G, M_HD), jnp.bfloat16),
+            leaf, leaf, _shape(one_chip, (32, 128), jnp.int32),
+            _shape(one_chip, (32,), jnp.int32))
+
+    def lower(**kw):
+        return jax.jit(
+            lambda q, k, v, t, p: pa.paged_attention(q, k, v, t, p, **kw)
+        ).lower(*args).as_text(debug_info=False)
+
+    was = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    jax.clear_caches()
+    try:
+        plain = lower()
+        assert lower(window=None) == plain
+        assert lower(window=M_WINDOW) != plain
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", was)
+        jax.clear_caches()
 
 
 @pytest.mark.parametrize("bits", [2, 4, 8])

@@ -72,6 +72,7 @@ def test_full_config_exact_spec(arch):
         "internvl2-1b": (24, 896, 14, 2, 4864, 151655),
         "mamba2-130m": (24, 768, 0, 0, 0, 50280),
         "recurrentgemma-2b": (26, 2560, 10, 1, 7680, 256000),
+        "mellum2-12b-a2.5b": (28, 2304, 32, 4, 896, 98304),
     }[arch]
     n_layers, d_model, n_heads, n_kv, d_ff, vocab = spec
     assert cfg.n_layers == n_layers
