@@ -54,11 +54,12 @@ def run_fused(verbose: bool = True) -> dict:
             else:
                 k_pg = kvwire.quantize_kv(kf, bits, gs)
                 v_pg = kvwire.quantize_kv(vf, bits, gs)
+            k_pg, v_pg = kvwire.stored_pages(k_pg), kvwire.stored_pages(v_pg)
             label = "fp" if bits is None else f"kv{bits}"
 
             def baseline(k_pg=k_pg, v_pg=v_pg, bits=bits):
-                kk = kvwire.gather_pages(k_pg, table)
-                vv = kvwire.gather_pages(v_pg, table)
+                kk = kvwire.gather_pages(k_pg, table, page_size, kvh)
+                vv = kvwire.gather_pages(v_pg, table, page_size, kvh)
                 if bits is not None:
                     kk = kvwire.dequantize_kv(kk, d)
                     vv = kvwire.dequantize_kv(vv, d)
@@ -71,7 +72,7 @@ def run_fused(verbose: bool = True) -> dict:
 
             def fused(k_pg=k_pg, v_pg=v_pg):
                 return paged_attn.paged_attention(
-                    q, k_pg, v_pg, table, pos,
+                    q, k_pg, v_pg, table, pos, page_size=page_size,
                     interpret=mode == "interpret")
 
             t = _time(fused, reps=2)

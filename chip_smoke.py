@@ -121,15 +121,17 @@ def check_live_pool_attention(server, args):
                           cfg.n_heads // cfg.n_kv_heads, cfg.head_dim),
                           jnp.float32)
     stack = server.pool.pages["super"][0]["self"]
+    ps, kvh = pcfg.page_size, cfg.n_kv_heads
     for layer in (0, cfg.n_layers - 1):
-        k, v = (jax.tree.map(lambda a: a[layer], stack[n]) for n in "kv")
-        got = paged_attn.paged_attention(q, k, v, table, pos)
+        k, v = stack["k"], stack["v"]
+        got = paged_attn.paged_attention(q, k, v, table, pos, page_size=ps,
+                                         layer=layer)
         with jax.default_matmul_precision("highest"):
             want = attention.decode_attention(
-                q, kvwire.dequantize_kv(kvwire.gather_pages(k, table),
-                                        cfg.head_dim),
-                kvwire.dequantize_kv(kvwire.gather_pages(v, table),
-                                     cfg.head_dim), pos)
+                q, kvwire.dequantize_kv(kvwire.gather_pages(
+                    k, table, ps, kvh, layer), cfg.head_dim),
+                kvwire.dequantize_kv(kvwire.gather_pages(
+                    v, table, ps, kvh, layer), cfg.head_dim), pos)
         err = rel_err(got, want)
         print(f"info: fused paged_attention vs XLA reference, layer "
               f"{layer}: rel err {err:.3e}")

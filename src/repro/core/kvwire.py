@@ -80,7 +80,7 @@ def make_quant_kv(shape: tuple, bits: int, group_size: int) -> dict:
     is one uniform fill.  Scan-stacked cache/pool layouts build their
     leaves with ``jnp.zeros`` over this structure
     (``transformer.init_cache``, ``serve/pool.py``), and cache rewind
-    (:func:`reset_page_rows`) restores exactly this state.
+    (:func:`reset_table_rows`) restores exactly this state.
     """
     *lead, d = shape
     cpb = packing.codes_per_byte(bits)
@@ -106,77 +106,176 @@ def update_quant_kv(q: dict, new: jnp.ndarray, slot, *, axis: int,
 # paged layout: pool pages of the same wire format
 # ---------------------------------------------------------------------------
 #
-# A paged pool stores a leaf as (n_pages, page_size, KV, D) — or its wire
-# dict with (n_pages, page_size, KV, D/cpb) packed codes — instead of one
-# contiguous (B, T, KV, D) buffer.  A request owns an ordered list of pages
-# (its page table); page p of the table holds absolute token positions
+# A paged pool stores its tokens in pages instead of one contiguous
+# (B, T, KV, D) buffer.  A request owns an ordered list of pages (its page
+# table); page p of the table holds absolute token positions
 # [p*page_size, (p+1)*page_size).  Page 0 is reserved as a scratch page:
 # padded table entries and inactive batch slots read/write it, and the
 # masking in decode_attention guarantees scratch garbage never reaches a
 # real output.  Packing is along the head dim, so page_size is independent
 # of kv_bits; every page is page_size * KV * (D*bits/8 + 8*D/group) bytes.
+#
+# Logically a page leaf is (n_pages, page_size, KV, X): X = D/cpb packed
+# codes, G regions of scale/zmin, or D fp features.  It is STORED feature-
+# major within a page: the page's R = page_size*KV (token, head) rows lie
+# on the minor dim, lane ``t*KV + h`` for token row t of kv head h, and
+# its features on the dim before.  Where R is under the chip's 128 lanes,
+# the features fold into them: with fold f, feature ``j*(X/f) + i`` of
+# row r sits at ``[i, j*R + r]``, so a stored page is (X/f, f*R).  f is the
+# largest power of two with f*R <= 128 that divides X, chosen per leaf
+# from its shape (1 where R already fills the lanes).  The two minor dims
+# are then what the fused kernel reads for one page, dense in the chip's
+# (sublane, 128-lane) tiles, and a scan-stacked leaf (n_super, n_pages,
+# X/f, f*R) is written in place and read as stored, whole, by every layer
+# of the stack.  Same elements, same bytes: a permutation only.
+# :func:`logical_pages` gives the logical view, :func:`stored_pages` the
+# stored one.
+
+LANES = 128        # the chip's vector lanes, which a stored page fills
+
+
+def page_fold(width: int, rows: int) -> int:
+    """How many feature blocks of a page share the lanes: the largest
+    power of two f with ``f * rows <= LANES`` that divides ``width``."""
+    f = 1
+    while 2 * f * rows <= LANES and width % (2 * f) == 0:
+        f *= 2
+    return f
+
+
+def fold_pages(a, rows: int):
+    """(..., X, R) feature-major pages -> the stored (..., X/f, f*R)."""
+    *lead, w, _ = a.shape
+    f = page_fold(w, rows)
+    if f == 1:
+        return a
+    a = a.reshape(*lead, f, w // f, rows)
+    return jnp.swapaxes(a, -2, -3).reshape(*lead, w // f, f * rows)
+
+
+def unfold_pages(a, rows: int):
+    """The stored (..., X/f, f*R) -> (..., X, R) feature-major pages."""
+    *lead, wf, lanes = a.shape
+    f = lanes // rows
+    if f == 1:
+        return a
+    a = a.reshape(*lead, wf, f, rows)
+    return jnp.swapaxes(a, -2, -3).reshape(*lead, f * wf, rows)
+
+
+def lane_rows(width: int, rows: int, kv_heads: int) -> jnp.ndarray:
+    """(f*R,) in-page token row of each lane of a stored page of logical
+    width ``width`` and R = ``rows``."""
+    t = jnp.broadcast_to(jnp.arange(rows) // kv_heads, (width, rows))
+    return fold_pages(t, rows)[0]
+
+
+def stored_pages(x):
+    """Logical (..., n_pages, page_size, KV, X) pages -> the stored layout;
+    leaves of a wire dict alike."""
+    def f(a):
+        *lead, ps, kvh, w = a.shape
+        a = jnp.swapaxes(a.reshape(*lead, ps * kvh, w), -1, -2)
+        return fold_pages(a, ps * kvh)
+    return jax.tree.map(f, x)
+
+
+def logical_pages(x, page_size: int, kv_heads: int):
+    """Stored pages -> the logical (..., n_pages, page_size, KV, X) view;
+    leaves of a wire dict alike."""
+    def f(a):
+        a = unfold_pages(a, page_size * kv_heads)
+        *lead, w, _ = a.shape
+        return jnp.swapaxes(a, -1, -2).reshape(*lead, page_size, kv_heads, w)
+    return jax.tree.map(f, x)
+
+
+def page_width(a, rows: int) -> int:
+    """Logical feature width X of a stored page array."""
+    return a.shape[-2] * a.shape[-1] // rows
+
+
+def page_spec(leaf, head_dim: int, rows: int):
+    """(bits, group) of a stored wire leaf with R = ``rows``, else None."""
+    if not is_quant_kv(leaf):
+        return None
+    return _infer(page_width(leaf["packed"], rows), head_dim,
+                  page_width(leaf["scale"], rows))
+
 
 def make_paged_kv(n_pages: int, page_size: int, kv_heads: int, head_dim: int,
                   bits: int | None = None, group_size: int = 64,
                   dtype=jnp.float32):
-    """One pool leaf: fp array or wire dict with (n_pages, page_size) lead."""
+    """One zero pool leaf in the stored layout: fp array or wire dict."""
     shape = (n_pages, page_size, kv_heads, head_dim)
-    if bits is None:
-        return jnp.zeros(shape, dtype)
-    return make_quant_kv(shape, bits, group_size)
+    one = jax.eval_shape(
+        lambda: stored_pages(jnp.zeros(shape, dtype) if bits is None
+                             else make_quant_kv(shape, bits, group_size)))
+    return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), one)
 
 
-def gather_pages(leaf, page_table: jnp.ndarray):
-    """Gather a (B, P) page table into logical (B, P*page_size, ...) views.
+def gather_pages(leaf, page_table: jnp.ndarray, page_size: int,
+                 kv_heads: int, layer=None):
+    """Gather a (B, P) page table into logical (B, P*page_size, KV, X)
+    views of a stored leaf (layer ``layer`` of a stacked one).
 
-    Works on fp leaves and wire dicts alike (a wire dict is a pytree of
-    arrays whose page dims match).  Row order in the gathered view is the
-    page-table order, so with in-order tables position t of request b lives
-    at gathered index t.
+    Works on fp leaves and wire dicts alike.  Row order in the gathered
+    view is the page-table order, so with in-order tables position t of
+    request b lives at gathered index t.
     """
     def g(a):
-        out = a[page_table]
-        return out.reshape(page_table.shape[0], -1, *a.shape[2:])
+        out = a[page_table] if layer is None else a[layer, page_table]
+        out = logical_pages(out, page_size, kv_heads)   # (B, P, ps, KV, X)
+        return out.reshape(page_table.shape[0], -1, *out.shape[3:])
     return jax.tree.map(g, leaf)
 
 
-def scatter_token(leaf, new: jnp.ndarray, page_idx, row, *,
-                  bits: int | None = None, group_size: int | None = None):
-    """Write one token per batch row into its page.
-
-    ``new`` is fp (B, 1, KV, D); ``page_idx``/``row`` are (B,) physical page
-    ids and in-page rows.  Rows of inactive slots should point at the
-    scratch page (duplicate scratch writes are unordered, which is fine —
-    the scratch page is never read unmasked).
-    """
-    if is_quant_kv(leaf):
-        wire = quantize_kv(new, bits, group_size)
-        return jax.tree.map(
-            lambda a, w: a.at[page_idx, row].set(w[:, 0].astype(a.dtype)),
-            leaf, wire)
-    return leaf.at[page_idx, row].set(new[:, 0].astype(leaf.dtype))
-
-
-def scatter_tokens(leaf, new: jnp.ndarray, page_idx, row, *,
-                   bits: int | None = None, group_size: int | None = None):
-    """Write a length-L run of tokens per batch row into its pages.
+def scatter_tokens(leaf, new: jnp.ndarray, page_idx, row, *, page_size: int,
+                   layer=None, bits: int | None = None,
+                   group_size: int | None = None):
+    """Write a length-L run of tokens per batch row into its pages, in
+    place in the stored layout (layer ``layer`` of a stacked leaf).
 
     ``new`` is fp (B, L, KV, D); ``page_idx``/``row`` are (B, L) physical
     page ids and in-page rows — the speculative verify path writes all L
-    candidate positions of every slot in one scatter.  Rows of inactive
-    (or overflowing) slots should point at the scratch page; duplicate
-    scratch writes are unordered, which is fine — the scratch page is
-    never read unmasked.
+    candidate positions of every slot in one scatter.  Each token
+    rewrites its slot's whole stored page with its own rows set, so the
+    scatter's window is the page's two minor dims and the pool keeps its
+    layout.  Rows of inactive (or overflowing) slots should point at the
+    scratch page; duplicate scratch writes are unordered, which is fine —
+    the scratch page is never read unmasked.  (Live slots never share a
+    page, so no other duplicates arise.)
     """
-    if is_quant_kv(leaf):
-        wire = quantize_kv(new, bits, group_size)
-        return jax.tree.map(
-            lambda a, w: a.at[page_idx, row].set(w.astype(a.dtype)),
-            leaf, wire)
-    return leaf.at[page_idx, row].set(new.astype(leaf.dtype))
+    kvh, l = new.shape[2], new.shape[1]
+    rows = page_size * kvh
+    wire = quantize_kv(new, bits, group_size) if is_quant_kv(leaf) else new
+
+    def put(a, w):                                          # w (B, L, KV, X)
+        token_row = lane_rows(w.shape[-1], rows, kvh)
+        for i in range(l):
+            at = ((page_idx[:, i],) if layer is None
+                  else (layer, page_idx[:, i]))
+            old = a[at]                                     # (B, X/f, f*R)
+            tok = jnp.swapaxes(w[:, i], 1, 2).astype(a.dtype)   # (B, X, KV)
+            tok = fold_pages(jnp.tile(tok, (1, 1, page_size)), rows)
+            hit = token_row == row[:, i, None, None]        # (B, 1, f*R)
+            a = a.at[at].set(jnp.where(hit, tok, old))
+        return a
+    return jax.tree.map(put, leaf, wire)
 
 
-def reset_table_rows(tree, table, keep_tokens, *, stacked: bool = False):
+def scatter_token(leaf, new: jnp.ndarray, page_idx, row, *, page_size: int,
+                  layer=None, bits: int | None = None,
+                  group_size: int | None = None):
+    """:func:`scatter_tokens` for one token per batch row: ``new`` is fp
+    (B, 1, KV, D), ``page_idx``/``row`` (B,)."""
+    return scatter_tokens(leaf, new, page_idx[:, None], row[:, None],
+                          page_size=page_size, layer=layer, bits=bits,
+                          group_size=group_size)
+
+
+def reset_table_rows(tree, table, keep_tokens, *, page_size: int,
+                     kv_heads: int, stacked: bool = False):
     """Un-write every row past ``keep_tokens`` tokens of one request's
     page table, in ONE fused update per leaf.
 
@@ -194,17 +293,14 @@ def reset_table_rows(tree, table, keep_tokens, *, stacked: bool = False):
     — one dispatch per rewind, however many pages it spans.
     """
     n_tbl = table.shape[0]
+    rows = page_size * kv_heads
 
     def reset(a):
-        pages = a[:, table] if stacked else a[table]   # (.., n_tbl, ps, ..)
-        lead = 2 if stacked else 1
-        ps = pages.shape[lead]
-        pos = (jnp.arange(n_tbl)[:, None] * ps
-               + jnp.arange(ps)[None])                  # (n_tbl, ps)
+        pages = a[:, table] if stacked else a[table]   # (.., n_tbl, X/f, L)
+        t = lane_rows(page_width(pages, rows), rows, kv_heads)
+        pos = jnp.arange(n_tbl)[:, None] * page_size + t[None]
         mask = (pos >= keep_tokens) & (table > 0)[:, None]
-        mask = mask.reshape((1,) * (lead - 1) + (n_tbl, ps)
-                            + (1,) * (pages.ndim - lead - 1))
-        new = jnp.where(mask, jnp.zeros((), a.dtype), pages)
+        new = jnp.where(mask[:, None], jnp.zeros((), a.dtype), pages)
         # duplicate scratch entries all scatter their own UNCHANGED rows
         # (mask is False there), so the unordered dupes are harmless
         return (a.at[:, table].set(new) if stacked
@@ -217,17 +313,18 @@ def scatter_prefill(leaf, contig, page_ids: jnp.ndarray, *,
                     stacked: bool = False):
     """Copy a B=1 contiguous prefill cache into pool pages.
 
-    ``contig`` is the (S, 1, T, ...) (stacked=True) or (1, T, ...) leaf from
-    a contiguous prefill; T must equal len(page_ids) * page_size.  Pages the
-    request does not own map to the scratch page in ``page_ids``.
+    ``contig`` is the (S, 1, T, KV, X) (stacked=True) or (1, T, KV, X) leaf
+    from a contiguous prefill; T must equal len(page_ids) * page_size.
+    Pages the request does not own map to the scratch page in
+    ``page_ids``.  The small bucket is put in the stored layout before
+    the set, so the pool is written in place as it is stored.
     """
     def s(pl, cl):
+        n = page_ids.shape[0]
+        lead = cl.shape[:1] if stacked else ()
+        c = stored_pages(cl.reshape(*lead, n, -1, *cl.shape[-2:]))
         if stacked:
-            ps = pl.shape[2]
-            c = cl.reshape(cl.shape[0], -1, ps, *cl.shape[3:])
             return pl.at[:, page_ids].set(c.astype(pl.dtype))
-        ps = pl.shape[1]
-        c = cl.reshape(-1, ps, *cl.shape[2:])
         return pl.at[page_ids].set(c.astype(pl.dtype))
     return jax.tree.map(s, leaf, contig)
 

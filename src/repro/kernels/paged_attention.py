@@ -31,12 +31,18 @@ Dequant paths per page (``dequant=``):
 
 Grid ``(B, P)`` — batch parallel, the page axis sequential ("arbitrary")
 so the m/l/acc VMEM scratch carries the running softmax state across
-pages.  Each step reads one page as ``(page_size*KV, X)`` rows (a free
-reshape of the pool leaf), so every block is full-width in its last two
-dims.  Queries arrive as (B, Lq, KV, G, D); kv heads, GQA groups and the
-multi-query run (Lq = k+1, the speculative verify) flatten onto one
-(KV*Lq*G, D) row block, scored against every page row at once, with a
-head mask keeping each query row on its own kv head.  Masking matches
+pages.  Each step reads one page as the pool stores it
+(``core/kvwire.py``): features on sublanes and the page's R =
+page_size*KV (token, head) rows on lanes, an ``(X/f, f*R)`` block
+full-width in its last two dims, whose ``f`` feature blocks (where R
+alone does not fill the lanes) the body stacks back into ``(X, R)``.  A
+scan-stacked leaf is passed whole with the layer's index as a
+scalar-prefetch operand, so no layer's pages are sliced out of the
+stack or relaid for the kernel.  Queries arrive as (B, Lq, KV, G, D);
+kv heads, GQA groups and the multi-query run (Lq = k+1, the speculative
+verify) flatten onto one (KV*Lq*G, D) row block, scored against every
+page row at once, with a head mask keeping each query row on its own kv
+head.  Masking matches
 ``decode_attention`` over the gathered view: key position
 ``p*page_size + t`` is visible to query row i iff it is ``<= pos[b] + i``
 — an all-masked page contributes nothing because masked probabilities
@@ -77,6 +83,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from repro.core import kvwire
 
 try:  # pallas is optional at import time: gate, don't crash (ROADMAP env)
     from jax.experimental import pallas as pl
@@ -168,6 +176,19 @@ def _unpack_planes(pk, bits: int) -> list:
     return [(p >> (i * bits)) & mask for i in range(8 // bits)]
 
 
+def _page(ref, rows: int):
+    """One stored page block, ``(X/f, f*R)``, as ``(X, R)``: the ``f``
+    feature blocks that share the lanes (``core/kvwire.py``) stacked back
+    along sublanes.  Codes come out int32, values f32."""
+    v = ref[0, 0]
+    v = v.astype(jnp.int32 if v.dtype == jnp.uint8 else jnp.float32)
+    f = v.shape[-1] // rows
+    if f == 1:
+        return v
+    return jnp.concatenate([v[:, j * rows:(j + 1) * rows] for j in range(f)],
+                           axis=0)
+
+
 def _dot_t(a, b):
     """a (M, K) @ b (N, K)^T, f32 accumulation.  At the default precision
     Mosaic feeds f32 operands to the MXU as one bf16 pass, as XLA does."""
@@ -252,8 +273,8 @@ def _flush(p, p_steps, o_ref, acc_ref, l_ref):
         o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
 
 
-def _kernel_fp(tbl_ref, pos_ref, *refs, page_size: int, gq: int, lqg: int,
-               kvh: int, p_steps: int, sm_scale: float,
+def _kernel_fp(tbl_ref, pos_ref, layer_ref, *refs, page_size: int, gq: int,
+               lqg: int, kvh: int, p_steps: int, sm_scale: float,
                window: int | None = None):
     n_bounds = 1 if window is None else 2
     bounds = refs[:n_bounds]
@@ -265,19 +286,19 @@ def _kernel_fp(tbl_ref, pos_ref, *refs, page_size: int, gq: int, lqg: int,
     @pl.when(_live(p, bounds))
     def _():
         q = q_ref[0, 0]                                        # (nq, D)
-        k = k_ref[0].astype(jnp.float32)                       # (R, D)
-        v = v_ref[0].astype(jnp.float32)
-        s = _dot_t(q, k) * sm_scale
-        allowed = _allowed(q.shape[0], k.shape[0], lqg=lqg, gq=gq, kvh=kvh,
+        k = _page(k_ref, page_size * kvh)                      # (D, R)
+        v = _page(v_ref, page_size * kvh)
+        s = _dot(q, k) * sm_scale
+        allowed = _allowed(q.shape[0], k.shape[1], lqg=lqg, gq=gq, kvh=kvh,
                            page_size=page_size, pos_b=pos_b, p=p,
                            window=window)
         pmat, corr = _online_step(s, allowed, m_ref, l_ref)
-        acc_ref[0] = acc_ref[0] * corr + _dot(pmat, v)
+        acc_ref[0] = acc_ref[0] * corr + _dot_t(pmat, v)
 
     _flush(p, p_steps, o_ref, acc_ref, l_ref)
 
 
-def _kernel_quant(tbl_ref, pos_ref, *refs,
+def _kernel_quant(tbl_ref, pos_ref, layer_ref, *refs,
                   bits: int, gr: int, lut: bool, page_size: int, gq: int,
                   lqg: int, kvh: int, p_steps: int, sm_scale: float,
                   window: int | None = None):
@@ -309,14 +330,15 @@ def _kernel_quant(tbl_ref, pos_ref, *refs,
         # q_ref stacks the region-masked query rows (row r*nq + i holds query
         # row i restricted to region r), so one matmul per plane gives every
         # region's code dot at once.
-        k_sc_t = ks_ref[0].T                                   # (Gr, R)
+        rows = page_size * kvh
+        k_sc = _page(ks_ref, rows)                             # (Gr, R)
         cd = None
-        for i, codes in enumerate(_unpack_planes(kp_ref[0], bits)):
-            t = code_matmul(_dot_t, q_ref[0, i], codes)        # (Gr*nq, R)
+        for i, codes in enumerate(_unpack_planes(_page(kp_ref, rows), bits)):
+            t = code_matmul(_dot, q_ref[0, i], codes)          # (Gr*nq, R)
             cd = t if cd is None else cd + t
-        s = _dot_t(qsum_ref[0], kz_ref[0])                     # zmin terms
+        s = _dot(qsum_ref[0], _page(kz_ref, rows))             # zmin terms
         for r in range(gr):
-            s = s + cd[r * nq:(r + 1) * nq] * k_sc_t[r:r + 1]
+            s = s + cd[r * nq:(r + 1) * nq] * k_sc[r:r + 1]
         s = s * sm_scale
         allowed = _allowed(nq, s.shape[1], lqg=lqg, gq=gq, kvh=kvh,
                            page_size=page_size, pos_b=pos_b, p=p,
@@ -327,17 +349,17 @@ def _kernel_quant(tbl_ref, pos_ref, *refs,
         # the scale folds into p (one stacked row block per region), the zmin
         # term is a per-region row sum, and a lane mask keeps region r's block
         # on region r's lanes.
-        v_sc_t = vs_ref[0].T
-        v_zm_t = vz_ref[0].T
-        p_sc = jnp.concatenate([pmat * v_sc_t[r:r + 1] for r in range(gr)],
+        v_sc = _page(vs_ref, rows)                             # (Gr, R)
+        v_zm = _page(vz_ref, rows)
+        p_sc = jnp.concatenate([pmat * v_sc[r:r + 1] for r in range(gr)],
                                axis=0)                         # (Gr*nq, R)
         zsum = jnp.concatenate(
-            [(pmat * v_zm_t[r:r + 1]).sum(axis=-1, keepdims=True)
+            [(pmat * v_zm[r:r + 1]).sum(axis=-1, keepdims=True)
              for r in range(gr)], axis=0)                      # (Gr*nq, 1)
         w = acc_ref.shape[-1]
         region = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1) // (w // gr)
-        for i, codes in enumerate(_unpack_planes(vp_ref[0], bits)):
-            pv = code_matmul(_dot, p_sc, codes) + zsum         # (Gr*nq, W)
+        for i, codes in enumerate(_unpack_planes(_page(vp_ref, rows), bits)):
+            pv = code_matmul(_dot_t, p_sc, codes) + zsum       # (Gr*nq, W)
             out = jnp.zeros((nq, w), jnp.float32)
             for r in range(gr):
                 out = out + jnp.where(region == r,
@@ -347,16 +369,18 @@ def _kernel_quant(tbl_ref, pos_ref, *refs,
     _flush(p, p_steps, o_ref, acc_ref, l_ref)
 
 
-@functools.partial(jax.jit, static_argnames=("window", "dequant",
-                                             "interpret"))
-def paged_attention(q, k_pages, v_pages, page_table, pos, *,
-                    window: int | None = None, dequant: str = "auto",
-                    interpret: bool = False):
+@functools.partial(jax.jit, static_argnames=("page_size", "window",
+                                             "dequant", "interpret"))
+def paged_attention(q, k_pages, v_pages, page_table, pos, *, page_size: int,
+                    layer=None, window: int | None = None,
+                    dequant: str = "auto", interpret: bool = False):
     """Fused flash-decode over a paged pool, wire format and all.
 
-    q (B, Lq, KV, G, D); ``k_pages``/``v_pages`` are one pool leaf — fp
-    (n_pages, page_size, KV, D) arrays or LQ wire dicts with
-    (n_pages, page_size, KV, D/cpb) packed codes (``core/kvwire.py``);
+    q (B, Lq, KV, G, D); ``k_pages``/``v_pages`` are one pool leaf in the
+    stored layout (``core/kvwire.py``) — fp arrays or LQ wire dicts of
+    (n_pages, X/f, f*page_size*KV) arrays — or, with ``layer`` (a traced
+    int), a scan-stacked leaf with an (n_super, ...) lead, read at that
+    layer in place; ``page_size`` (static) the pool's tokens per page;
     page_table (B, P) int32 physical page ids, in table order (position t
     lives at table entry t // page_size); pos (B,) int32 — the absolute
     position of each slot's FIRST query row (query i attends ``<= pos+i``);
@@ -375,7 +399,11 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *,
     nq = kvh * lqg
     n_tbl = page_table.shape[1]
     quant = isinstance(k_pages, dict)
-    page_size = (k_pages["packed"] if quant else k_pages).shape[1]
+    if layer is None:                  # one layer's leaf: a stack of one
+        k_pages, v_pages = jax.tree.map(lambda a: a[None], (k_pages, v_pages))
+        layer = 0
+    layer = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
+    rows = page_size * kvh
     sm_scale = d ** -0.5
 
     # query rows (h, l, g) = h*lqg + l*gq + g; page rows (t, h') = t*KV + h'
@@ -395,22 +423,19 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *,
     def fixed(bi, p, *_):
         return (bi, 0, 0, 0)
 
-    def page_map(bi, p, tbl_ref, *_):
-        return (tbl_ref[bi, p], 0, 0)
+    def page_map(bi, p, tbl_ref, pos_ref, layer_ref, *_):
+        return (layer_ref[0], tbl_ref[bi, p], 0, 0)
 
-    def rows(a):                       # (n_pages, ps, KV, X) -> (n, ps*KV, X)
-        return a.reshape(a.shape[0], a.shape[1] * a.shape[2], a.shape[3])
-
-    def page_spec(a):
-        return pl.BlockSpec((1,) + a.shape[1:], page_map)
+    def page_spec(a):                  # one stored page: (X, page_size*KV)
+        return pl.BlockSpec((1, 1) + a.shape[2:], page_map)
 
     statics = dict(page_size=page_size, gq=gq, lqg=lqg, kvh=kvh,
                    p_steps=n_tbl, sm_scale=sm_scale)
     if window is not None:
         statics["window"] = window
     if quant:
-        packed_d = k_pages["packed"].shape[-1]
-        gr = k_pages["scale"].shape[-1]
+        packed_d = kvwire.page_width(k_pages["packed"], rows)
+        gr = kvwire.page_width(k_pages["scale"], rows)
         bits = _infer_bits(packed_d, d)
         cpb, w = d // packed_d, packed_d
         lut = dequant_path(bits, dequant) == "lut"
@@ -423,7 +448,7 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *,
         qs = jnp.where(onehot[None, None, :, None, :], qp[:, :, None], 0.0)
         qs = qs.reshape(b, cpb, gr * nq, w)
         qsum = qm.reshape(b, nq, gr, d // gr).sum(-1)          # (b, nq, Gr)
-        leaves = [rows(t[f]) for t in (k_pages, v_pages)
+        leaves = [t[f] for t in (k_pages, v_pages)
                   for f in ("packed", "scale", "zmin")]
         in_specs = [pl.BlockSpec((1, cpb, gr * nq, w), fixed),
                     pl.BlockSpec((1, nq, gr),
@@ -435,7 +460,7 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *,
         dequant_path(None, dequant)            # still validates the mode
         cpb, w = 1, d
         kernel = functools.partial(_kernel_fp, **statics)
-        leaves = [rows(k_pages), rows(v_pages)]
+        leaves = [k_pages, v_pages]
         in_specs = [pl.BlockSpec((1, 1, nq, d), fixed)] \
             + [page_spec(a) for a in leaves]
         operands = (qm[:, None], *leaves)
@@ -444,7 +469,7 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *,
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2 + len(bounds),
+            num_scalar_prefetch=3 + len(bounds),
             grid=(b, n_tbl),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, cpb, nq, w), fixed),
@@ -457,6 +482,6 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *,
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name=name,
-    )(tbl, posb, *bounds, *operands)
+    )(tbl, posb, layer, *bounds, *operands)
     out = out.transpose(0, 2, 3, 1).reshape(b, kvh, lq, gq, d)
     return out.transpose(0, 2, 1, 3, 4).astype(q.dtype)

@@ -358,7 +358,8 @@ def attn_apply(p, x, *, n_heads: int, n_kv: int, head_dim: int,
                window: int | None = None, qk_norm: bool = False,
                rope: bool = True, rope_theta: float = 1e4, yarn: tuple = (),
                positions=None, kv_src=None, cache=None, cache_pos=None,
-               page_table=None, fused: str | None = None,
+               page_table=None, page_size: int | None = None, layer=None,
+               fused: str | None = None,
                policy: QuantPolicy = NO_QUANT):
     """One attention block.
 
@@ -368,12 +369,17 @@ def attn_apply(p, x, *, n_heads: int, n_kv: int, head_dim: int,
       * decode: x has L==1, cache_pos is this token's position scalar;
       * prefill-into-cache: L>1 writes [0:L) and attends within x.
     page_table: (B, P) int32 physical page ids — paged decode.  cache leaves
-      then carry a shared (n_pages, page_size, KV, ...) pool instead of a
-      per-request (B, S, KV, ...) buffer, cache_pos is a (B,) per-slot
-      position vector, and the step writes this token's K/V into its page
-      before attending over the gathered page views (kinds 'full' and
-      'local'; a local layer's pages hold its whole context, and its
-      attention reads only the last ``window`` positions of it).
+      then carry a shared pool in the stored page layout
+      (``core/kvwire.py``) instead of a per-request (B, S, KV, ...) buffer,
+      cache_pos is a (B,) per-slot position vector, and the step writes
+      this token's K/V into its page before attending over the slot's
+      pages (kinds 'full' and 'local'; a local layer's pages hold its whole
+      context, and its attention reads only the last ``window`` positions
+      of it).
+    page_size: with page_table, the pool's tokens per page.
+    layer: with page_table, the traced index of this layer in a
+      scan-stacked pool leaf the cache carries whole; None for a leaf of
+      one layer.
     fused: None (XLA gather+dequant path) or 'pallas'/'interpret' — run the
       paged branch through the fused flash-decode kernel
       (``kernels/paged_attention.py``), which streams wire pages through
@@ -414,8 +420,9 @@ def attn_apply(p, x, *, n_heads: int, n_kv: int, head_dim: int,
             raise ValueError("paged cache supports decode of 'full' and "
                              "'local' attention only")
         out, new_cache = _paged_attend(q, k, v, cache, page_table,
-                                       cache_pos, fused,
-                                       window if kind == "local" else None)
+                                       page_size, cache_pos, fused,
+                                       window if kind == "local" else None,
+                                       layer)
     elif cache is not None and kind != "cross":
         out, new_cache = _cached_attend(q, k, v, cache, cache_pos, kind,
                                         causal, window)
@@ -436,15 +443,16 @@ def _quant_spec(cache, head_dim):
                           cache["k"]["scale"].shape[-1])
 
 
-def _paged_attend(q, k, v, cache, page_table, cache_pos, fused,
-                  window=None):
-    """Paged decode: write this step's K/V into the slots' pages, then
-    attend over them (fused kernel, or gather + dequant + attend); a
-    ``window`` keeps query i to keys in ``(pos + i - window, pos + i]``."""
-    l, head_dim = q.shape[1], q.shape[-1]
-    spec = _quant_spec(cache, head_dim)
+def _paged_attend(q, k, v, cache, page_table, page_size, cache_pos, fused,
+                  window=None, layer=None):
+    """Paged decode: write this step's K/V into the slots' pages, in
+    place (layer ``layer`` of a stacked pool leaf), then attend over them
+    (fused kernel reading the pages as stored, or gather + dequant +
+    attend); a ``window`` keeps query i to keys in
+    ``(pos + i - window, pos + i]``."""
+    l, kvh, head_dim = q.shape[1], q.shape[2], q.shape[-1]
+    spec = kvcache.page_spec(cache["k"], head_dim, page_size * kvh)
     with jax.named_scope("kv_write"):
-        page_size = (cache["k"]["packed"] if spec else cache["k"]).shape[1]
         wpos = cache_pos[:, None] + jnp.arange(l)           # (B, L) absolute
         # positions beyond the slot's table (a speculative run tailing past
         # max_context) write the scratch page instead of clamping onto the
@@ -456,16 +464,20 @@ def _paged_attend(q, k, v, cache, page_table, cache_pos, fused,
         page_idx = jnp.where(wpos < limit, page_idx, 0)
         row = wpos % page_size
         kw = dict(bits=spec[0], group_size=spec[1]) if spec else {}
-        qk = kvcache.scatter_tokens(cache["k"], k, page_idx, row, **kw)
-        qv = kvcache.scatter_tokens(cache["v"], v, page_idx, row, **kw)
+        qk = kvcache.scatter_tokens(cache["k"], k, page_idx, row,
+                                    page_size=page_size, layer=layer, **kw)
+        qv = kvcache.scatter_tokens(cache["v"], v, page_idx, row,
+                                    page_size=page_size, layer=layer, **kw)
     with jax.named_scope("attention"):
         if fused is not None:
             out = paged_attn.paged_attention(
-                q, qk, qv, page_table, cache_pos, window=window,
-                interpret=fused == "interpret")
+                q, qk, qv, page_table, cache_pos, page_size=page_size,
+                layer=layer, window=window, interpret=fused == "interpret")
         else:
-            k_cache = kvcache.gather_pages(qk, page_table)
-            v_cache = kvcache.gather_pages(qv, page_table)
+            k_cache = kvcache.gather_pages(qk, page_table, page_size, kvh,
+                                           layer)
+            v_cache = kvcache.gather_pages(qv, page_table, page_size, kvh,
+                                           layer)
             if spec:
                 k_cache = kvcache.dequantize_kv(k_cache, head_dim, q.dtype)
                 v_cache = kvcache.dequantize_kv(v_cache, head_dim, q.dtype)

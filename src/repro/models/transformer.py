@@ -127,8 +127,10 @@ def _aux_add(a: dict, b: dict) -> dict:
 
 def block_apply(p, x, spec, cfg: ModelConfig, *, policy: QuantPolicy,
                 cache=None, cache_pos=None, enc_out=None, positions=None,
-                page_table=None, fused=None):
-    """Returns (x, new_cache, aux) with ``aux`` as :func:`_aux_zero`."""
+                page_table=None, page_size=None, layer=None, fused=None):
+    """Returns (x, new_cache, aux) with ``aux`` as :func:`_aux_zero`.
+    ``layer``: this block's index in the scan-stacked paged pool leaves
+    ``cache`` carries whole (``attention.attn_apply``)."""
     mixer, ffn = spec
     aux = _aux_zero(cfg)
     new_cache = dict(cache) if cache is not None else None
@@ -148,7 +150,8 @@ def block_apply(p, x, spec, cfg: ModelConfig, *, policy: QuantPolicy,
             qk_norm=cfg.qk_norm, rope=cfg.rope, rope_theta=cfg.rope_theta,
             yarn=cfg.yarn if mixer == "attn" else (),
             positions=positions, cache=self_cache, cache_pos=cache_pos,
-            page_table=page_table, fused=fused, policy=policy)
+            page_table=page_table, page_size=page_size, layer=layer,
+            fused=fused, policy=policy)
         if cache is not None:
             new_cache["self"] = sc
     elif mixer == "mamba2":
@@ -299,10 +302,51 @@ def _maybe_remat(fn, cfg: ModelConfig, training: bool):
     return jax.checkpoint(fn)
 
 
+def _scan_run(blk_params, x, aux, cfg: ModelConfig, pattern, policies, *,
+              caches=None, pool=None, layer0: int = 0, training=False,
+              **kw):
+    """One ``lax.scan`` over a run of scan-stacked superblocks: pattern
+    position j runs ``blk_params[j]`` under ``policies[j]``.
+
+    ``caches`` (stacked like the params) are sliced by the scan and
+    stacked back as its outputs.  A paged ``pool`` rides in the carry
+    whole instead: superblock i writes and reads layer ``layer0 + i`` of
+    each stacked page leaf in place, so no layer's pages are sliced out
+    of the stack, relaid, or stacked back.  Returns (x, aux, the new
+    caches or pool).
+    """
+    def body(carry, xs):
+        xx, aux_acc, carried = carry
+        blk_p, blk_c, layer = xs
+        cs = carried if carried is not None else blk_c
+        new = []
+        for j, spec in enumerate(pattern):
+            xx, nc, a = block_apply(blk_p[j], xx, spec, cfg,
+                                    policy=policies[j],
+                                    cache=cs[j] if cs is not None else None,
+                                    layer=layer, **kw)
+            xx = constrain(xx, "batch", "seq", "embed")
+            aux_acc = _aux_add(aux_acc, a)
+            new.append(nc)
+        new = tuple(new) if cs is not None else None
+        if carried is not None:
+            return (xx, aux_acc, new), None
+        return (xx, aux_acc, None), new
+
+    body = _maybe_remat(body, cfg, training)
+    layers = None
+    if pool is not None:
+        n = jax.tree.leaves(blk_params)[0].shape[0]
+        layers = layer0 + jnp.arange(n, dtype=jnp.int32)
+    (x, aux, new_pool), ys = jax.lax.scan(body, (x, aux, pool),
+                                          (blk_params, caches, layers))
+    return x, aux, new_pool if pool is not None else ys
+
+
 def _stack_apply(params, x, cfg: ModelConfig, pattern, *,
                  policy: QuantPolicy, caches=None, cache_pos=None,
                  enc_out=None, positions=None, page_table=None,
-                 fused=None, training=False):
+                 page_size=None, fused=None, training=False):
     """Run scan-stacked superblocks + tail.  Returns (x, caches, aux).
 
     With a uniform :class:`QuantPolicy` (and unsegmented params) every
@@ -310,52 +354,39 @@ def _stack_apply(params, x, cfg: ModelConfig, pattern, *,
     :class:`PlanPolicy` — or params pre-segmented by
     ``quantize_params(plan)`` — routes to the segmented walker, which
     scans each run of identically-configured superblocks separately.
+    With a ``page_table`` the caches are the paged pool, carried whole
+    through the scan (:func:`_scan_run`).
     """
     if isinstance(policy, PlanPolicy) or "super_segments" in params \
             or (caches is not None and "super_segments" in caches):
         return _stack_apply_planned(
             params, x, cfg, pattern, policy=policy, caches=caches,
             cache_pos=cache_pos, enc_out=enc_out, positions=positions,
-            page_table=page_table, fused=fused, training=training)
+            page_table=page_table, page_size=page_size, fused=fused,
+            training=training)
     aux_total = _aux_zero(cfg)
-
-    def body(carry, xs):
-        xx, aux_acc = carry
-        blk_params, blk_caches = xs
-        new_caches = []
-        for j, spec in enumerate(pattern):
-            cj = blk_caches[j] if blk_caches is not None else None
-            xx, nc, aux = block_apply(blk_params[j], xx, spec, cfg,
-                                      policy=policy, cache=cj,
-                                      cache_pos=cache_pos, enc_out=enc_out,
-                                      positions=positions,
-                                      page_table=page_table, fused=fused)
-            xx = constrain(xx, "batch", "seq", "embed")
-            aux_acc = _aux_add(aux_acc, aux)
-            new_caches.append(nc)
-        out_caches = tuple(new_caches) if blk_caches is not None else None
-        return (xx, aux_acc), out_caches
-
-    body = _maybe_remat(body, cfg, training)
-    sup_caches = caches["super"] if caches is not None else None
-    xs = (params["super"], sup_caches)
+    kw = dict(cache_pos=cache_pos, enc_out=enc_out, positions=positions,
+              page_table=page_table, page_size=page_size, fused=fused)
+    paged = page_table is not None
+    new_sup = sup_caches = caches["super"] if caches is not None else None
     if params["super"]:
-        # the scan's own ops (slicing each layer's weights and cache out
-        # of the stack, stacking the new cache) sit under layer_scan; the
-        # body's ops under their layer kind's scope
+        # the scan's own ops (slicing each layer's weights, and a
+        # contiguous cache, out of the stack; stacking the new cache) sit
+        # under layer_scan; the body's ops under their layer kind's scope
         with jax.named_scope("layer_scan"):
-            (x, aux_total), new_sup = jax.lax.scan(body, (x, aux_total), xs)
-    else:
-        new_sup = sup_caches
+            x, aux_total, new_sup = _scan_run(
+                params["super"], x, aux_total, cfg, pattern,
+                (policy,) * len(pattern),
+                caches=None if paged else sup_caches,
+                pool=sup_caches if paged else None, training=training,
+                **kw)
 
     new_tail = []
     for t, tp in enumerate(params["tail"]):
         spec = pattern[t % len(pattern)]
         ct = caches["tail"][t] if caches is not None else None
         x, nc, aux = block_apply(tp, x, spec, cfg, policy=policy, cache=ct,
-                                 cache_pos=cache_pos, enc_out=enc_out,
-                                 positions=positions, page_table=page_table,
-                                 fused=fused)
+                                 **kw)
         aux_total = _aux_add(aux_total, aux)
         new_tail.append(nc)
 
@@ -397,13 +428,15 @@ def _combined_segments(per_layer, kv_list, p_len: int, n_super: int) -> list:
 
 def _stack_apply_planned(params, x, cfg: ModelConfig, pattern, *, policy,
                          caches=None, cache_pos=None, enc_out=None,
-                         positions=None, page_table=None, fused=None,
-                         training=False):
+                         positions=None, page_table=None, page_size=None,
+                         fused=None, training=False):
     """Segmented stack walk: one lax.scan per run of identically-configured
     superblocks, per-layer policies for the tail.  Cache layout is
     IDENTICAL to the uniform path — segments slice and re-concatenate the
     (n_super, ...) leading axis inside the jit, so serve pools, wire
-    scatter and checkpoints see the same pytrees either way.
+    scatter and checkpoints see the same pytrees either way.  A paged
+    pool (``page_table`` given) is never sliced: each segment carries the
+    stacked run that holds it whole, from the segment's first layer.
     """
     p_len = len(pattern)
     segmented = "super_segments" in params
@@ -423,6 +456,9 @@ def _stack_apply_planned(params, x, cfg: ModelConfig, pattern, *, policy,
             f"policy implies {len(segs)} segments but params carry "
             f"{len(seg_param_list)} — plan/params mismatch")
 
+    kw = dict(cache_pos=cache_pos, enc_out=enc_out, positions=positions,
+              page_table=page_table, page_size=page_size, fused=fused)
+    paged = page_table is not None
     aux_total = _aux_zero(cfg)
     sup_caches = cache_runs = None
     if caches is not None:
@@ -447,6 +483,11 @@ def _stack_apply_planned(params, x, cfg: ModelConfig, pattern, *, policy,
             f"cache's kv runs {list(zip(run_starts, run_sizes))} — "
             f"plan/cache kv_bits mismatch")
 
+    def _rows(tree, off, size, whole):
+        if size == whole:
+            return tree
+        return jax.tree.map(lambda a: a[off:off + size], tree)
+
     for k, (start, size, _) in enumerate(segs):
         seg_policies = tuple(per_layer[start * p_len + j]
                              for j in range(p_len))
@@ -455,44 +496,31 @@ def _stack_apply_planned(params, x, cfg: ModelConfig, pattern, *, policy,
         else:
             seg_params = jax.tree.map(lambda a: a[start:start + size],
                                       params["super"])
-        seg_caches = run = None
+        stack = off = run = None
         if cache_runs is not None:
             run, off = _cache_run(start, size)
-            seg_caches = cache_runs[run]
-            if size != run_sizes[run]:
-                seg_caches = jax.tree.map(lambda a: a[off:off + size],
-                                          seg_caches)
+            stack, whole = cache_runs[run], run_sizes[run]
         elif sup_caches is not None:
-            seg_caches = jax.tree.map(lambda a: a[start:start + size],
-                                      sup_caches)
+            stack, off, whole = sup_caches, start, n_super
+        seg_caches = pool = None
+        if stack is not None and paged:
+            pool = stack
+        elif stack is not None:
+            seg_caches = _rows(stack, off, size, whole)
 
-        def body(carry, xs, seg_policies=seg_policies):
-            xx, aux_acc = carry
-            blk_params, blk_caches = xs
-            new_caches = []
-            for j, spec in enumerate(pattern):
-                cj = blk_caches[j] if blk_caches is not None else None
-                xx, nc, aux = block_apply(blk_params[j], xx, spec, cfg,
-                                          policy=seg_policies[j], cache=cj,
-                                          cache_pos=cache_pos,
-                                          enc_out=enc_out,
-                                          positions=positions,
-                                          page_table=page_table,
-                                          fused=fused)
-                xx = constrain(xx, "batch", "seq", "embed")
-                aux_acc = _aux_add(aux_acc, aux)
-                new_caches.append(nc)
-            out = tuple(new_caches) if blk_caches is not None else None
-            return (xx, aux_acc), out
-
-        body = _maybe_remat(body, cfg, training)
         # one named scope per walker segment: xprof attributes device time
         # to the same stack runs serve_phase_ms{layer_run=...} reports;
         # the scan's own ops sit under layer_scan (_stack_apply)
         with jax.named_scope(f"segment{k}"), jax.named_scope("layer_scan"):
-            (x, aux_total), new_seg = jax.lax.scan(
-                body, (x, aux_total), (seg_params, seg_caches))
-        if cache_runs is not None:
+            x, aux_total, new_seg = _scan_run(
+                seg_params, x, aux_total, cfg, pattern, seg_policies,
+                caches=seg_caches, pool=pool, layer0=off or 0,
+                training=training, **kw)
+        if pool is not None and cache_runs is not None:
+            cache_runs[run] = new_seg
+        elif pool is not None:
+            sup_caches = new_seg
+        elif cache_runs is not None:
             new_run_parts[run].append(new_seg)
         elif sup_caches is not None:
             new_sup_parts.append(new_seg)
@@ -522,9 +550,7 @@ def _stack_apply_planned(params, x, cfg: ModelConfig, pattern, *, policy,
         with jax.named_scope(f"tail{t}"):
             x, nc, aux = block_apply(tp, x, spec, cfg,
                                      policy=per_layer[n_super * p_len + t],
-                                     cache=ct, cache_pos=cache_pos,
-                                     enc_out=enc_out, positions=positions,
-                                     page_table=page_table, fused=fused)
+                                     cache=ct, **kw)
         aux_total = _aux_add(aux_total, aux)
         new_tail.append(nc)
 
@@ -777,13 +803,17 @@ def decode_step(params, cfg: ModelConfig, tokens, cache, *,
 
 
 def paged_decode_step(params, cfg: ModelConfig, tokens, pages, page_table,
-                      pos, *, policy: QuantPolicy = NO_QUANT, fused=None):
+                      pos, *, page_size: int, policy: QuantPolicy = NO_QUANT,
+                      fused=None):
     """One continuous-batching decode step over a paged KV pool.
 
     tokens (B, 1) int32; pages {'super': ..., 'tail': ...} with shared
-    (n_pages, page_size, KV, ...) leaves per layer; page_table (B, P) int32
+    page leaves in the stored layout (``core/kvwire.py``: (n_pages, ...)
+    per layer, an (n_super, ...) lead for the scan-stacked ones), updated
+    in place; page_table (B, P) int32
     physical page ids per slot (scratch page 0 pads unused entries); pos
-    (B,) int32 — the absolute position each slot's token is written at.
+    (B,) int32 — the absolute position each slot's token is written at;
+    ``page_size`` the pool's tokens per page.
     Inactive slots point at the scratch page and are masked by the caller.
     ``fused`` ('pallas' | 'interpret' | None) routes every layer's
     attention through the fused paged kernel instead of gather+dequant.
@@ -800,7 +830,7 @@ def paged_decode_step(params, cfg: ModelConfig, tokens, pages, page_table,
             params["decoder"], x, cfg, cfg.pattern, policy=policy,
             caches=_layer_caches(pages),
             cache_pos=pos, enc_out=None, positions=pos[:, None],
-            page_table=page_table, fused=fused)
+            page_table=page_table, page_size=page_size, fused=fused)
     with jax.named_scope("lm_head"):
         x = _norm_apply(cfg, params["final_norm"], x)
         logits = _logits(params, cfg, x, policy)
@@ -808,7 +838,8 @@ def paged_decode_step(params, cfg: ModelConfig, tokens, pages, page_table,
 
 
 def paged_decode_multi(params, cfg: ModelConfig, tokens, pages, page_table,
-                       pos, *, policy: QuantPolicy = NO_QUANT, fused=None):
+                       pos, *, page_size: int, policy: QuantPolicy = NO_QUANT,
+                       fused=None):
     """Length-L batched decode over the paged pool — the speculative
     verify step (one compiled forward scores all L candidate tokens).
 
@@ -833,7 +864,7 @@ def paged_decode_multi(params, cfg: ModelConfig, tokens, pages, page_table,
             params["decoder"], x, cfg, cfg.pattern, policy=policy,
             caches=_layer_caches(pages),
             cache_pos=pos, enc_out=None, positions=positions,
-            page_table=page_table, fused=fused)
+            page_table=page_table, page_size=page_size, fused=fused)
     with jax.named_scope("lm_head"):
         x = _norm_apply(cfg, params["final_norm"], x)
         logits = _logits(params, cfg, x, policy)

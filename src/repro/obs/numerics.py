@@ -368,6 +368,7 @@ class QualityMonitor:
         ``0..c-1`` against the fp replay's cache."""
         table = jnp.asarray(
             pool.table_array(rid, self.core.pcfg.pages_per_slot)[None])
+        page_size = pool.page_size
         d = self.cfg.head_dim
         ref = dict(layer_blocks(fp_cache, self.cfg))
         m = self.obs.metrics
@@ -375,7 +376,8 @@ class QualityMonitor:
         for i, block in layer_blocks(pool.pages, self.cfg):
             errs = []
             for key in ("k", "v"):
-                got = kvwire.gather_pages(block["self"][key], table)
+                got = kvwire.gather_pages(block["self"][key], table,
+                                          page_size, self.cfg.n_kv_heads)
                 if kvwire.is_quant_kv(got):
                     got = kvwire.dequantize_kv(got, d)
                 got = np.asarray(got[0, :c], np.float64)
@@ -391,8 +393,8 @@ class QualityMonitor:
             # metrics snapshot alone is enough for launch/plan.py
             # --kv-sensitivity-from to map error -> format (0 = fp wire)
             kq = block["self"]["k"]
-            bits = (kvwire.kv_bits_of(kq, d)
-                    if kvwire.is_quant_kv(kq) else 0)
+            spec = kvwire.page_spec(kq, d, page_size * self.cfg.n_kv_heads)
+            bits = spec[0] if spec else 0
             m.gauge("kv_dequant_bits", layer=label).set(float(bits))
             out[label] = stats
         return out

@@ -47,6 +47,7 @@ metrics.json --profile`` validates the artifacts.
 from __future__ import annotations
 
 import contextlib
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -175,8 +176,10 @@ class PhaseProfiler:
         quant = any(kvwire.is_quant_kv(kv["k"]) for kv in kvs)
 
         def gather(kv_list, table):
-            fn = (jax.vmap(kvwire.gather_pages, in_axes=(0, None))
-                  if stacked else kvwire.gather_pages)
+            one = functools.partial(kvwire.gather_pages,
+                                    page_size=self.core.pcfg.page_size,
+                                    kv_heads=self.cfg.n_kv_heads)
+            fn = jax.vmap(one, in_axes=(0, None)) if stacked else one
             return [{k: fn(leaf, table) for k, leaf in kv.items()}
                     for kv in kv_list]
 
@@ -203,6 +206,7 @@ class PhaseProfiler:
         if key in self._jits:
             return self._jits[key]
         interpret = self.core.fused_mode == "interpret"
+        page_size = self.core.pcfg.page_size
 
         def fused(kv_list, q, table, pos):
             outs = []
@@ -212,13 +216,12 @@ class PhaseProfiler:
                     lead = (k["packed"] if kvwire.is_quant_kv(k)
                             else k).shape[0]
                     outs.extend(paged_attn.paged_attention(
-                        q, jax.tree.map(lambda a, i=i: a[i], k),
-                        jax.tree.map(lambda a, i=i: a[i], v),
-                        table, pos, interpret=interpret)
-                        for i in range(lead))
+                        q, k, v, table, pos, page_size=page_size, layer=i,
+                        interpret=interpret) for i in range(lead))
                 else:
                     outs.append(paged_attn.paged_attention(
-                        q, k, v, table, pos, interpret=interpret))
+                        q, k, v, table, pos, page_size=page_size,
+                        interpret=interpret))
             return outs
 
         jit = jax.jit(fused)
@@ -303,17 +306,20 @@ class PhaseProfiler:
             # full-step replay through the engine's own compiled jit: same
             # shapes as the serving calls, so no new trace is cut
             # (decode_compilations stays 1) and the probe's own key never
-            # advances the scheduler's sampling stream
+            # advances the scheduler's sampling stream.  The step takes its
+            # pages donated, so it replays on a copy: the live pool is
+            # left as it was
             look = getattr(self.engine, "lookahead_tokens", 1)
+            pages = jax.block_until_ready(jax.tree.map(jnp.copy, pool.pages))
             with self.obs.tracer.span("phase:step_replay"):
                 if look > 1:      # speculative: the verify step is the step
                     run = np.tile(tokens[:, None], (1, look))
                     _, replay_ms = self._timed(
                         self.core._multi_paged, self.core.params,
-                        pool.pages, jnp.asarray(run), jtable, jpos)
+                        pages, jnp.asarray(run), jtable, jpos)
                 else:
                     _, replay_ms = self._timed(
-                        self.core._step_paged, self.core.params, pool.pages,
+                        self.core._step_paged, self.core.params, pages,
                         jnp.asarray(tokens), jtable, jpos,
                         jax.random.fold_in(jax.random.key(0), self.steps))
             m.histogram("serve_step_replay_ms").record(replay_ms)

@@ -221,9 +221,14 @@ class PagedEngine(Engine):
         # tokens, fetched only while a trace records (``step_counts``)
         self.counts_experts = any(f == "moe" for _, f in cfg.pattern)
         self.step_counts: dict = {}
-        self._prefill_paged = jax.jit(self._prefill_paged_impl)
-        self._step_paged = jax.jit(self._step_paged_impl)
-        self._multi_paged = jax.jit(self._multi_paged_impl)
+        # the pool is donated: each step updates its pages in place, so
+        # HBM never holds a second pool.  A caller passes ``pool.pages``
+        # and keeps only what the step returns.
+        self._prefill_paged = jax.jit(self._prefill_paged_impl,
+                                      donate_argnums=2)
+        self._step_paged = jax.jit(self._step_paged_impl, donate_argnums=1)
+        self._multi_paged = jax.jit(self._multi_paged_impl,
+                                    donate_argnums=1)
 
     def new_pool(self) -> PagedKVPool:
         bits, group = self._kv_layout
@@ -283,7 +288,8 @@ class PagedEngine(Engine):
     def _step_paged_impl(self, params, pages, tokens, page_table, pos, key):
         logits, pages, aux = transformer.paged_decode_step(
             params, self.cfg, tokens[:, None], pages, page_table, pos,
-            policy=self.policy, fused=self.fused_mode)
+            page_size=self.pcfg.page_size, policy=self.policy,
+            fused=self.fused_mode)
         with jax.named_scope("sample"):
             toks = self._sample(logits[:, -1], key)
         if self.counts_experts:
@@ -294,7 +300,8 @@ class PagedEngine(Engine):
     def _multi_paged_impl(self, params, pages, tokens, page_table, pos):
         logits, pages = transformer.paged_decode_multi(
             params, self.cfg, tokens, pages, page_table, pos,
-            policy=self.policy, fused=self.fused_mode)
+            page_size=self.pcfg.page_size, policy=self.policy,
+            fused=self.fused_mode)
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), pages
 
     # --------------------------------------------------------------- host

@@ -1,10 +1,12 @@
 """Paged KV-cache pool: block storage for the quantized wire format.
 
-The pool owns every layer's K/V storage as shared page arrays —
-``(n_super, n_pages, page_size, KV, ...)`` for scan-stacked superblock
-positions, ``(n_pages, page_size, KV, ...)`` for the unscanned tail — in
-the LQ wire format when ``kv_bits`` is set (core/kvwire.py) or fp
-otherwise.  Requests own ordered page lists (page tables); the device-side
+The pool owns every layer's K/V storage as shared page arrays in one
+stored layout (core/kvwire.py: a page's features on sublanes, its
+page_size*KV rows on lanes) — ``(n_super, n_pages, ...)`` for
+scan-stacked superblock positions, ``(n_pages, ...)`` for the unscanned
+tail — in the LQ wire format when ``kv_bits`` is set or fp otherwise.
+The engine's step programs take the pool donated and update it in
+place.  Requests own ordered page lists (page tables); the device-side
 gather/scatter lives in core/kvwire.py and models/attention.py; this class
 is the host-side allocator: alloc/free/defrag plus accounting.
 
@@ -148,8 +150,12 @@ class PagedKVPool:
             "tail": kvwire.permute_pages(pages["tail"], perm)})
         self._reset_table = jax.jit(lambda pages, table, keep: {
             sup_key: kvwire.reset_table_rows(pages[sup_key], table, keep,
+                                             page_size=page_size,
+                                             kv_heads=cfg.n_kv_heads,
                                              stacked=True),
-            "tail": kvwire.reset_table_rows(pages["tail"], table, keep)})
+            "tail": kvwire.reset_table_rows(pages["tail"], table, keep,
+                                            page_size=page_size,
+                                            kv_heads=cfg.n_kv_heads)})
 
         self._free = list(range(n_pages - 1, 0, -1))   # LIFO free list
         self.page_tables: dict[int, list[int]] = {}    # rid -> ordered pages

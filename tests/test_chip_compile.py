@@ -69,6 +69,15 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile()
 
 
+def _pages(sharding, n_pages, kvh, d, bits, dtype=jnp.bfloat16):
+    """Shapes of one pool leaf in the stored layout (``core/kvwire.py``),
+    4-bit-or-wider codes in 16-wide regions."""
+    from repro.core import kvwire
+    leaf = jax.eval_shape(lambda: kvwire.make_paged_kv(
+        n_pages, PAGE, kvh, d, bits, 16, dtype))
+    return jax.tree.map(lambda a: _shape(sharding, a.shape, a.dtype), leaf)
+
+
 def _kernels(compiled) -> list:
     """Names of the Pallas kernels in a compiled program."""
     return [line.split("=")[0].strip().lstrip("%")
@@ -99,18 +108,9 @@ def test_quant_matmul_compiles(one_chip, bits, k, n):
 @pytest.mark.parametrize("lq", [1, 5])
 def test_paged_attention_compiles(one_chip, bits, dequant, lq):
     n_pages, pps = 128, 10
-    if bits is None:
-        leaf = _shape(one_chip, (n_pages, PAGE, KV, HEAD_DIM), jnp.bfloat16)
-    else:
-        gr = HEAD_DIM // 16
-        leaf = {"packed": _shape(one_chip, (n_pages, PAGE, KV,
-                                            HEAD_DIM * bits // 8), jnp.uint8),
-                "scale": _shape(one_chip, (n_pages, PAGE, KV, gr),
-                                jnp.float32),
-                "zmin": _shape(one_chip, (n_pages, PAGE, KV, gr),
-                               jnp.float32)}
+    leaf = _pages(one_chip, n_pages, KV, HEAD_DIM, bits)
     c = _compile(lambda q, k, v, t, p: pa.paged_attention(
-        q, k, v, t, p, dequant=dequant),
+        q, k, v, t, p, page_size=PAGE, dequant=dequant),
         _shape(one_chip, (SLOTS, lq, KV, G, HEAD_DIM), jnp.bfloat16),
         leaf, leaf, _shape(one_chip, (SLOTS, pps), jnp.int32),
         _shape(one_chip, (SLOTS,), jnp.int32))
@@ -143,18 +143,14 @@ def test_expert_matmul_compiles(one_chip, tokens, k, n):
 
 
 def _mellum_pages(one_chip, n_pages=256):
-    gr = M_HD // 16
-    return {"packed": _shape(one_chip, (n_pages, PAGE, M_KV, M_HD // 2),
-                             jnp.uint8),
-            "scale": _shape(one_chip, (n_pages, PAGE, M_KV, gr), jnp.float32),
-            "zmin": _shape(one_chip, (n_pages, PAGE, M_KV, gr), jnp.float32)}
+    return _pages(one_chip, n_pages, M_KV, M_HD, 4)
 
 
 @pytest.mark.parametrize("lq", [1, 5])
 def test_windowed_paged_attention_compiles(one_chip, lq):
     leaf = _mellum_pages(one_chip)
     c = _compile(lambda q, k, v, t, p: pa.paged_attention(
-        q, k, v, t, p, window=M_WINDOW),
+        q, k, v, t, p, page_size=PAGE, window=M_WINDOW),
         _shape(one_chip, (32, lq, M_KV, M_G, M_HD), jnp.bfloat16),
         leaf, leaf, _shape(one_chip, (32, 128), jnp.int32),
         _shape(one_chip, (32,), jnp.int32))
@@ -174,7 +170,8 @@ def test_no_window_lowers_to_the_unwindowed_kernel(one_chip):
 
     def lower(**kw):
         return jax.jit(
-            lambda q, k, v, t, p: pa.paged_attention(q, k, v, t, p, **kw)
+            lambda q, k, v, t, p: pa.paged_attention(q, k, v, t, p,
+                                                     page_size=PAGE, **kw)
         ).lower(*args).as_text(debug_info=False)
 
     was = jax.config.jax_traceback_in_locations_limit
@@ -244,40 +241,93 @@ def _bench():
     return hlo, scopes
 
 
-@pytest.mark.parametrize("fused", [False, True], ids=["xla", "fused"])
-def test_decode_copies_carry_a_scope(one_chip, fused):
-    """In the decode program compiled for a v5e (granite widths, two
-    layers, 4-bit weights and KV pages), every scatter and
-    dynamic-update-slice, and every copy that has an op_name, lies under a
-    layer-kind scope, and every copy has a consumer: a device trace splits
-    the copies into the scatter's, the kernel's and the scan's.  Copies of
-    a scalar (the scan's loop counter) are the loop's bookkeeping."""
+# The serving geometry of the chat cells: 32 slots x 2048 positions in
+# 16-token pages, 4097 pages with the scratch page; 4097 names the page
+# arrays in the compiled text, as no other dim of these programs has it.
+POOL = dict(max_slots=32, page_size=PAGE, n_pages=4097, max_context=2048)
+
+
+def _paged_program(one_chip, arch, n_layers, fused, program="decode",
+                   lq=5):
+    """A ``PagedEngine`` step program compiled for a v5e at ``arch``'s
+    widths, ``n_layers`` deep, lq4w weights and 4-bit KV pages: the
+    decode step, the speculative verify of ``lq`` tokens ("multi") or
+    the B=1 prefill.  Returns (compiled, pages) with ``pages`` the pool's
+    shapes."""
     import dataclasses as dc
     from repro import configs
     from repro.core import schemes
     from repro.serve import pool as pool_mod
     from repro.serve.engine import EngineConfig, PagedConfig, PagedEngine
     from repro.models import transformer
-    cfg = dc.replace(configs.get("granite-3-2b"), n_layers=2)
+    cfg = dc.replace(configs.get(arch), n_layers=n_layers)
     params = jax.eval_shape(lambda: transformer.init_params(
         cfg, jax.random.key(0), qcfg=schemes.get("lq4w")))
-    ecfg = EngineConfig(max_len=256, kv_bits=4, kv_group=16,
+    pcfg = PagedConfig(**POOL)
+    ecfg = EngineConfig(max_len=pcfg.max_context, kv_bits=4, kv_group=16,
                         weight_scheme="lq4w", backend="pallas",
                         fused_attention=fused)
-    pcfg = PagedConfig(max_slots=SLOTS, page_size=PAGE, n_pages=65,
-                       max_context=256)
     eng = PagedEngine(cfg, params, ecfg, pcfg)
     eng.fused_mode = "pallas" if fused else None   # compiled, not interpreted
     pages = jax.eval_shape(lambda: pool_mod.make_pool_pages(
         cfg, n_pages=pcfg.n_pages, page_size=PAGE, kv_bits=4, kv_group=16))
     put = lambda t: jax.tree.map(                  # noqa: E731
         lambda a: _shape(one_chip, a.shape, a.dtype), t)
-    args = put((eng.params, pages, jax.ShapeDtypeStruct((SLOTS,), jnp.int32),
-                jax.ShapeDtypeStruct((SLOTS, pcfg.pages_per_slot), jnp.int32),
-                jax.ShapeDtypeStruct((SLOTS,), jnp.int32)))
+    b, pps = pcfg.max_slots, pcfg.pages_per_slot
+    i32 = jnp.int32
     key = jax.eval_shape(lambda: jax.random.key(0))
-    c = jax.jit(eng._step_paged_impl).lower(*args, key).compile()
+    if program == "prefill":
+        args = put((eng.params, jax.ShapeDtypeStruct((1, pcfg.max_context),
+                                                     i32), pages,
+                    jax.ShapeDtypeStruct((pps,), i32),
+                    jax.ShapeDtypeStruct((), i32)))
+        return eng._prefill_paged.lower(*args, key).compile(), pages
+    table = jax.ShapeDtypeStruct((b, pps), i32)
+    if program == "multi":
+        args = put((eng.params, pages, jax.ShapeDtypeStruct((b, lq), i32),
+                    table, jax.ShapeDtypeStruct((b,), i32)))
+        return eng._multi_paged.lower(*args).compile(), pages
+    args = put((eng.params, pages, jax.ShapeDtypeStruct((b,), i32), table,
+                jax.ShapeDtypeStruct((b,), i32)))
+    return eng._step_paged.lower(*args, key).compile(), pages
+
+
+def _page_copies(insts) -> list:
+    """The copies of a page array: rank >= 3 with the pool's page dim."""
+    import re
+    n = str(POOL["n_pages"])
+    return [name for name, i in insts.items() if i.opcode == "copy"
+            and len(dims := re.match(r"\w+\[([\d,]*)\]", i.shape)
+                    .group(1).split(",")) >= 3 and n in dims]
+
+
+# (arch, layers): granite's widths two layers deep; Mellum2's attention
+# geometry (4 kv heads of 128: 64-row pages whose features fold into the
+# lanes; three sliding-window positions and a full one) over two
+# superblocks, so the layer scan runs a real loop
+STACKS = {"granite": ("granite-3-2b", 2),
+          "mellum2": ("mellum2-12b-a2.5b", 8)}
+
+
+@pytest.mark.parametrize("fused,stack", [
+    pytest.param(False, "granite", id="xla"),
+    pytest.param(True, "granite", id="fused"),
+    pytest.param(False, "mellum2", id="xla-mellum2"),
+    pytest.param(True, "mellum2", id="fused-mellum2")])
+def test_decode_copies_carry_a_scope(one_chip, fused, stack):
+    """In the decode program compiled for a v5e (lq4w weights, 4-bit KV
+    pages at the cells' pool geometry), no page array is copied: the pool
+    rides the layer scan whole in its one stored layout, each layer's
+    token write updates it in place and the fused kernel reads it as
+    stored.  Every scatter and dynamic-update-slice, and every copy that
+    has an op_name, lies under a layer-kind scope, and every copy has a
+    consumer, so a device trace still splits what copies remain.  Copies
+    of a scalar (the scan's loop counter) are the loop's bookkeeping."""
+    c, _ = _paged_program(one_chip, *STACKS[stack], fused)
     assert any(k.startswith("quant_matmul_b4") for k in _kernels(c))
+    if fused:
+        assert any(k.startswith("paged_attention_lut_b4")
+                   for k in _kernels(c))
     hlo, scopes = _bench()
     text = c.as_text()
     insts, to = hlo.parse(text), hlo.copy_consumers(text)
@@ -291,14 +341,37 @@ def test_decode_copies_carry_a_scope(one_chip, fused):
                 if (i.path or i.opcode != "copy")
                 and not scopes.scope_of(i.path)]
     assert not [n for n, i in moves.items() if n in to and not to[n]]
-    assert {"kv_write", "layer_scan"} <= {scopes.scope_of(i.path)
-                                          for i in moves.values()}
-    # the page arrays go through three relayouts a layer: the scan's slice
-    # for the scatter, the scatter's result for the kernel (fused path),
-    # and the layer's pages for the scan's stacking
-    goes = {to[n] for n, i in moves.items()
-            if n in to and i.shape.count(",") >= 2}
-    want = {"scatter@kv_write", "dynamic-update-slice@layer_scan"}
-    if fused:
-        want.add("paged_attention_lut_b4@attention")
-    assert want <= goes, goes
+    assert "kv_write" in {scopes.scope_of(i.path) for i in moves.values()}
+    # the page arrays go through no relayout at all: not the scan's slice
+    # for the scatter, not the kernel's operand, not the scan's stacking
+    assert not _page_copies(insts), [
+        (n, insts[n].shape, to.get(n)) for n in _page_copies(insts)]
+
+
+@pytest.mark.parametrize("stack", ["granite", "mellum2"])
+def test_prefill_writes_the_pool_in_place(one_chip, stack):
+    """The B=1 prefill puts its bucket in the stored layout and sets the
+    request's pages in the donated pool: no whole-pool relayout around
+    the scatter, and the pool goes out where it came in."""
+    c, pages = _paged_program(one_chip, *STACKS[stack], True, "prefill")
+    hlo, _ = _bench()
+    insts = hlo.parse(c.as_text())
+    assert not _page_copies(insts)
+    pool = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(pages))
+    assert c.memory_analysis().alias_size_in_bytes == pool
+
+
+@pytest.mark.parametrize("program", ["decode", "multi"])
+def test_step_programs_donate_the_pool(one_chip, program):
+    """The decode step and the speculative verify take the pool donated:
+    the compiled program aliases it from input to output, so its output
+    holds no second pool (only the tokens beside it), and its
+    temporaries stay under two of one layer's page arrays."""
+    c, pages = _paged_program(one_chip, *STACKS["granite"], True, program)
+    pool = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(pages))
+    layer = pool // STACKS["granite"][1]
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes == pool
+    assert mem.output_size_in_bytes - pool < 2 ** 20
+    assert mem.temp_size_in_bytes < 2 * layer, \
+        f"temps {mem.temp_size_in_bytes}B vs one layer's pages {layer}B"

@@ -45,7 +45,8 @@ def params():
 
 def _case(bits, *, b=2, lq=1, kvh=2, gq=2, d=32, gs=16, page_size=4,
           pps=4, ragged=True):
-    """One synthetic paged-pool decode case + its XLA baseline inputs.
+    """One synthetic paged-pool decode case + its XLA baseline inputs,
+    the pages in the pool's stored layout.
 
     Page 0 (the scratch page) is filled with large garbage so any leak
     past the position mask shows up as a parity failure, and table rows
@@ -66,16 +67,15 @@ def _case(bits, *, b=2, lq=1, kvh=2, gq=2, d=32, gs=16, page_size=4,
     full = pps * page_size
     pos = jnp.asarray([full - page_size - 2, full - lq] if ragged
                       else [full - lq] * b, jnp.int32)[:b]
-    if bits is None:
-        return q, kf, vf, table, pos
-    k_pg = kvwire.quantize_kv(kf, bits, gs)
-    v_pg = kvwire.quantize_kv(vf, bits, gs)
-    return q, k_pg, v_pg, table, pos
+    if bits is not None:
+        kf = kvwire.quantize_kv(kf, bits, gs)
+        vf = kvwire.quantize_kv(vf, bits, gs)
+    return q, kvwire.stored_pages(kf), kvwire.stored_pages(vf), table, pos
 
 
-def _baseline(q, k_pg, v_pg, table, pos, d):
-    kk = kvwire.gather_pages(k_pg, table)
-    vv = kvwire.gather_pages(v_pg, table)
+def _baseline(q, k_pg, v_pg, table, pos, d, page_size=4):
+    kk = kvwire.gather_pages(k_pg, table, page_size, q.shape[2])
+    vv = kvwire.gather_pages(v_pg, table, page_size, q.shape[2])
     if isinstance(kk, dict):
         kk = kvwire.dequantize_kv(kk, d)
         vv = kvwire.dequantize_kv(vv, d)
@@ -88,9 +88,42 @@ def test_kernel_matches_xla_baseline(bits, lq):
     q, k_pg, v_pg, table, pos = _case(bits, lq=lq)
     want = _baseline(q, k_pg, v_pg, table, pos, q.shape[-1])
     got = paged_attn.paged_attention(q, k_pg, v_pg, table, pos,
-                                    interpret=True)
+                                     page_size=4, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+# the chat cells' page geometry: 16-token pages of 8 kv heads of 64 (the
+# page's 128 rows fill the lanes) and of 4 of 128 (64 rows: two feature
+# blocks share the lanes), 4-bit codes in 16-wide regions
+SERVING = {"kv8-d64": dict(kvh=8, gq=4, d=64),
+           "kv4-d128": dict(kvh=4, gq=8, d=128)}
+
+
+@pytest.mark.parametrize("geom", SERVING)
+def test_kernel_matches_xla_at_serving_geometry(geom):
+    """The kernel reads the stored layout as the pool keeps it, folded
+    feature blocks and all: parity with gather + dequant + attend."""
+    kw = SERVING[geom]
+    q, k_pg, v_pg, table, pos = _case(4, page_size=16, pps=3, **kw)
+    want = _baseline(q, k_pg, v_pg, table, pos, kw["d"], 16)
+    got = paged_attn.paged_attention(q, k_pg, v_pg, table, pos,
+                                     page_size=16, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_kernel_reads_the_layer_it_is_given():
+    """A scan-stacked leaf goes to the kernel whole with the layer's
+    index: the output is the one of that layer's pages alone."""
+    q, k_pg, v_pg, table, pos = _case(4)
+    k2, v2 = (jax.tree.map(lambda a: jnp.stack([a * 0 + 1, a]), t)
+              for t in (k_pg, v_pg))
+    want = paged_attn.paged_attention(q, k_pg, v_pg, table, pos,
+                                      page_size=4, interpret=True)
+    got = paged_attn.paged_attention(q, k2, v2, table, pos, page_size=4,
+                                     layer=jnp.asarray(1), interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def _poison(leaf, pages):
@@ -132,17 +165,19 @@ def test_entries_past_the_last_live_page_are_never_read(bits, dequant, lq):
     if bits is not None:
         kf, vf = kvwire.quantize_kv(kf, bits, 16), kvwire.quantize_kv(
             vf, bits, 16)
+    kf, vf = kvwire.stored_pages(kf), kvwire.stored_pages(vf)
     poisoned = np.asarray(stale)[dead]
     k_pg, v_pg = _poison(kf, poisoned), _poison(vf, poisoned)
 
     def run(table):
         return np.asarray(paged_attn.paged_attention(
-            q, k_pg, v_pg, table, pos, dequant=dequant, interpret=True))
+            q, k_pg, v_pg, table, pos, page_size=page_size, dequant=dequant,
+            interpret=True))
 
     got = run(stale)
     assert np.isfinite(got).all()
     np.testing.assert_array_equal(got, run(scratch))
-    want = _baseline(q, kf, vf, scratch, pos, d)
+    want = _baseline(q, kf, vf, scratch, pos, d, page_size)
     np.testing.assert_allclose(got, np.asarray(want), rtol=2e-5, atol=2e-5)
 
 
@@ -152,9 +187,11 @@ def test_lut_and_affine_dequant_agree(bits):
     affine dequant (section V): same pages, same scores, same output."""
     q, k_pg, v_pg, table, pos = _case(bits)
     affine = paged_attn.paged_attention(q, k_pg, v_pg, table, pos,
-                                        dequant="affine", interpret=True)
+                                        page_size=4, dequant="affine",
+                                        interpret=True)
     lut = paged_attn.paged_attention(q, k_pg, v_pg, table, pos,
-                                     dequant="lut", interpret=True)
+                                     page_size=4, dequant="lut",
+                                     interpret=True)
     np.testing.assert_allclose(np.asarray(lut), np.asarray(affine),
                                rtol=2e-5, atol=2e-5)
 
@@ -170,10 +207,10 @@ def test_auto_mode_selects_lut_at_low_bits():
 def test_rejects_bad_dequant_modes():
     q, k_pg, v_pg, table, pos = _case(8)
     with pytest.raises(ValueError, match="dequant"):
-        paged_attn.paged_attention(q, k_pg, v_pg, table, pos,
+        paged_attn.paged_attention(q, k_pg, v_pg, table, pos, page_size=4,
                                    dequant="nearest", interpret=True)
     with pytest.raises(ValueError, match="bits <= 4"):
-        paged_attn.paged_attention(q, k_pg, v_pg, table, pos,
+        paged_attn.paged_attention(q, k_pg, v_pg, table, pos, page_size=4,
                                    dequant="lut", interpret=True)
 
 

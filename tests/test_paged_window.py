@@ -27,15 +27,15 @@ def _pool(bits, b, kvh=2, d=32, gs=16):
     kf = kf.at[0].set(1e4)                     # scratch garbage
     vf = vf.at[0].set(-1e4)
     table = (1 + jnp.arange(b * PPS, dtype=jnp.int32)).reshape(b, PPS)
-    if bits is None:
-        return kf, vf, table
-    return kvwire.quantize_kv(kf, bits, gs), kvwire.quantize_kv(vf, bits,
-                                                                gs), table
+    if bits is not None:
+        kf, vf = kvwire.quantize_kv(kf, bits, gs), kvwire.quantize_kv(vf,
+                                                                      bits, gs)
+    return kvwire.stored_pages(kf), kvwire.stored_pages(vf), table
 
 
 def _xla(q, k_pg, v_pg, table, pos):
-    kk = kvwire.gather_pages(k_pg, table)
-    vv = kvwire.gather_pages(v_pg, table)
+    kk = kvwire.gather_pages(k_pg, table, PAGE, q.shape[2])
+    vv = kvwire.gather_pages(v_pg, table, PAGE, q.shape[2])
     if isinstance(kk, dict):
         kk = kvwire.dequantize_kv(kk, q.shape[-1])
         vv = kvwire.dequantize_kv(vv, q.shape[-1])
@@ -54,12 +54,13 @@ def test_windowed_kernel_matches_the_xla_path(bits, lq):
     q = jax.random.normal(jax.random.fold_in(KEY, 2), (b, lq, 2, 2, 32))
     want = _xla(q, k_pg, v_pg, table, pos)
     got = paged_attn.paged_attention(q, k_pg, v_pg, table, pos,
-                                     window=WINDOW, interpret=True)
+                                     page_size=PAGE, window=WINDOW,
+                                     interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
     # the window matters at these positions: unwindowed differs
     full = paged_attn.paged_attention(q, k_pg, v_pg, table, pos,
-                                      interpret=True)
+                                      page_size=PAGE, interpret=True)
     assert np.abs(np.asarray(full) - np.asarray(want))[2:].max() > 1e-3
 
 
@@ -70,12 +71,41 @@ def test_entries_before_the_first_live_page_are_never_read():
     k_pg, v_pg, table = _pool(4, 2)
     q = jax.random.normal(jax.random.fold_in(KEY, 3), (2, 1, 2, 2, 32))
     want = paged_attn.paged_attention(q, k_pg, v_pg, table, pos,
-                                      window=WINDOW, interpret=True)
+                                      page_size=PAGE, window=WINDOW,
+                                      interpret=True)
     first = (np.maximum(np.asarray(pos) - WINDOW + 1, 0) // PAGE)
     poked = np.asarray(table).copy()
     for b, f in enumerate(first):
         poked[b, :f] = 0
     got = paged_attn.paged_attention(q, k_pg, v_pg, jnp.asarray(poked), pos,
-                                     window=WINDOW, interpret=True)
+                                     page_size=PAGE, window=WINDOW,
+                                     interpret=True)
     assert first.min() > 0
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("kvh,gq,d", [(8, 4, 64), (4, 8, 128)],
+                         ids=["kv8-d64", "kv4-d128"])
+@pytest.mark.parametrize("window", [None, 20])
+def test_kernel_at_serving_geometry(kvh, gq, d, window):
+    """16-token pages at the chat cells' head geometries (128-row pages,
+    and 64-row pages whose feature blocks share the lanes), with and
+    without a window that starts inside a page."""
+    page, pps = 16, 3
+    n_pages = 2 * pps + 1
+    kf = jax.random.normal(KEY, (n_pages, page, kvh, d), jnp.float32)
+    vf = jax.random.normal(jax.random.fold_in(KEY, 1), kf.shape)
+    kf, vf = kf.at[0].set(1e4), vf.at[0].set(-1e4)      # scratch garbage
+    k_pg = kvwire.stored_pages(kvwire.quantize_kv(kf, 4, 16))
+    v_pg = kvwire.stored_pages(kvwire.quantize_kv(vf, 4, 16))
+    table = (1 + jnp.arange(2 * pps, dtype=jnp.int32)).reshape(2, pps)
+    pos = jnp.asarray([37, 47], jnp.int32)
+    q = jax.random.normal(jax.random.fold_in(KEY, 2), (2, 1, kvh, gq, d))
+    kk = kvwire.dequantize_kv(kvwire.gather_pages(k_pg, table, page, kvh), d)
+    vv = kvwire.dequantize_kv(kvwire.gather_pages(v_pg, table, page, kvh), d)
+    want = attention.decode_attention(q, kk, vv, pos, window=window)
+    got = paged_attn.paged_attention(q, k_pg, v_pg, table, pos,
+                                     page_size=page, window=window,
+                                     interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)

@@ -170,7 +170,8 @@ def _defrag_data_check(kv_map, sizes, victim):
         for r in rids:
             page = jnp.asarray([pool.pages_of(r)[0]])
             row = jnp.asarray([0])
-            leaf = kvwire.scatter_token(leaf, toks[r], page, row, **kw)
+            leaf = kvwire.scatter_token(leaf, toks[r], page, row,
+                                        page_size=PAGE_SIZE, **kw)
         seg[0]["self"]["k"] = jax.tree.map(lambda a: a[None], leaf)
 
     def slot_views():
@@ -182,7 +183,8 @@ def _defrag_data_check(kv_map, sizes, victim):
                 if r == victim and victim_freed[0]:
                     continue
                 tbl = jnp.asarray([pool.pages_of(r)], jnp.int32)
-                out[(s, r)] = kvwire.gather_pages(leaf, tbl)
+                out[(s, r)] = kvwire.gather_pages(leaf, tbl, PAGE_SIZE,
+                                                  TINY.n_kv_heads)
         return out
 
     victim_freed = [False]
@@ -242,13 +244,15 @@ def _truncate_data_check(kv_map, keep_tokens):
             page_idx = jnp.asarray([[ids[t // PAGE_SIZE]
                                      for t in range(total)]])
             row = jnp.asarray([[t % PAGE_SIZE for t in range(total)]])
-            leaf = kvwire.scatter_tokens(leaf, x, page_idx, row, **kw)
+            leaf = kvwire.scatter_tokens(leaf, x, page_idx, row,
+                                         page_size=PAGE_SIZE, **kw)
         seg[0]["self"]["k"] = jax.tree.map(lambda a: a[None], leaf)
 
     def rows_of(r):
         tbl = jnp.asarray([pool.pages_of(r)], jnp.int32)
         return [jax.tree.map(
-            lambda a: np.asarray(kvwire.gather_pages(a[0], tbl)),
+            lambda a: np.asarray(kvwire.gather_pages(
+                a[0], tbl, PAGE_SIZE, TINY.n_kv_heads)),
             seg[0]["self"]["k"])
             for seg in pool.pages["super_segments"]]
 
@@ -263,12 +267,14 @@ def _truncate_data_check(kv_map, keep_tokens):
     kept_pages = pool.pages_of(1)
     assert kept_pages == old_pages_1[:len(kept_pages)]   # no realloc
     dropped = [p for p in old_pages_1 if p not in kept_pages]
+    def logical(a):                  # (n_pages, page_size, KV, X)
+        return np.asarray(kvwire.logical_pages(a[0], PAGE_SIZE,
+                                               TINY.n_kv_heads))
+
     for s, seg in enumerate(pool.pages["super_segments"]):
-        leaf = jax.tree.map(lambda a: np.asarray(a[0]),
-                            seg[0]["self"]["k"])
+        leaf = jax.tree.map(logical, seg[0]["self"]["k"])
         fresh_leaf = jax.tree.map(
-            lambda a: np.asarray(a[0]),
-            fresh.pages["super_segments"][s][0]["self"]["k"])
+            logical, fresh.pages["super_segments"][s][0]["self"]["k"])
         view = before[1][s]          # gathered (1, total, ...) pre-rewind
         for t in range(len(kept_pages) * PAGE_SIZE):
             got = jax.tree.map(
@@ -314,15 +320,15 @@ def _poison_scratch(pool):
 
     def poison(a):
         bad = 255 if a.dtype == jnp.uint8 else SCRATCH_SENTINEL
-        # page axis: 1 on stacked super leaves (S, n_pages, ps, KV, ·),
-        # 0 on tail leaves (n_pages, ps, KV, ·)
-        return a.at[:, 0].set(bad) if a.ndim == 5 else a.at[0].set(bad)
+        # page axis: 1 on stacked super leaves (S, n_pages, ·, ·), 0 on
+        # tail leaves (n_pages, ·, ·)
+        return a.at[:, 0].set(bad) if a.ndim == 4 else a.at[0].set(bad)
 
     pool.pages = jax.tree.map(poison, pool.pages)
 
 
 def _iter_page_leaves(pool):
-    """Every (n_pages, page_size, ...) array of the pool, destacked."""
+    """Every (n_pages, ...) stored page array of the pool, destacked."""
     pages = pool.pages
     blocks = []
     if "super_segments" in pages:
@@ -354,7 +360,9 @@ def _assert_live_rows_clean(pool, rid):
     tbl = np.asarray(pool.table_array(rid, pool.n_pages))
     assert (tbl[:n] != 0).all()          # live prefix never maps to scratch
     for a in _iter_page_leaves(pool):
-        view = np.asarray(a)[tbl].reshape(-1, *a.shape[2:])[:live]
+        view = kvwire.logical_pages(np.asarray(a)[tbl], pool.page_size,
+                                    TINY.n_kv_heads)
+        view = np.asarray(view).reshape(-1, *view.shape[2:])[:live]
         assert not view.any(), \
             f"scratch bytes leaked into rid {rid}'s live rows"
 

@@ -81,8 +81,8 @@ def test_pool_defrag_preserves_contents(kv_bits):
                                       "v": pool.pages["super"][0]["self"]["v"]}),
                            ) + pool.pages["super"][1:]
     tbl_before = jnp.asarray([pool.table_array(2, 3)])
-    before = jax.tree.map(lambda a: kvwire.gather_pages(a[0], tbl_before),
-                          written)             # superblock 0's page view
+    before = jax.tree.map(lambda a: kvwire.gather_pages(   # superblock 0's
+        a[0], tbl_before, 4, TINY.n_kv_heads), written)     # page view
 
     pool.free(1)                               # leave a hole, then compact
     mapping = pool.defrag()
@@ -91,7 +91,7 @@ def test_pool_defrag_preserves_contents(kv_bits):
     assert len(mapping) == 4                   # covers every allocated page
     tbl_after = jnp.asarray([pool.table_array(2, 3)])
     after = jax.tree.map(
-        lambda a: kvwire.gather_pages(a[0], tbl_after),
+        lambda a: kvwire.gather_pages(a[0], tbl_after, 4, TINY.n_kv_heads),
         pool.pages["super"][0]["self"]["k"])
     jax.tree.map(lambda a, b: np.testing.assert_array_equal(
         np.asarray(a), np.asarray(b)), before, after)
@@ -122,14 +122,87 @@ def test_gather_scatter_token_roundtrip():
     new = jax.random.normal(jax.random.key(3), (2, 1, 2, 32))
     # slot 0 -> page 2 row 1 (pos 9, table [1,2]); slot 1 -> page 4 row 0
     leaf = kvwire.scatter_token(leaf, new, jnp.asarray([2, 4]),
-                                jnp.asarray([1, 0]), bits=8, group_size=16)
+                                jnp.asarray([1, 0]), page_size=4, bits=8,
+                                group_size=16)
     table = jnp.asarray([[1, 2], [4, 3]], jnp.int32)
-    view = kvwire.dequantize_kv(kvwire.gather_pages(leaf, table), 32)
+    view = kvwire.dequantize_kv(kvwire.gather_pages(leaf, table, 4, 2), 32)
     np.testing.assert_allclose(np.asarray(view[0, 5]),
                                np.asarray(new[0, 0]), rtol=0.05, atol=0.05)
     np.testing.assert_allclose(np.asarray(view[1, 0]),
                                np.asarray(new[1, 0]), rtol=0.05, atol=0.05)
     assert float(jnp.abs(view[0, 0]).max()) == 0      # untouched rows
+
+
+# (page_size, kv heads, head_dim, bits): the chat cells' 128-row pages,
+# 64-row pages (two feature blocks share the lanes), small pages folding
+# further, and fp pages
+STORED = {"r128": (16, 8, 64, 4), "r64": (16, 4, 128, 4),
+          "r8": (4, 2, 32, 8), "fp": (4, 2, 32, None)}
+
+
+@pytest.mark.parametrize("geom", STORED)
+def test_stored_layout_round_trip(geom):
+    """Every page op on the stored layout, read back through the logical
+    view, equals a straightforward write of the same tokens into logical
+    (n_pages, page_size, KV, X) pages: token scatter at one layer of a
+    stack, the prefill bucket's set, a rewind, a defrag permutation."""
+    ps, kvh, d, bits = STORED[geom]
+    n, gs = 9, 16
+    key = jax.random.key(5)
+    wire = ((lambda x: x) if bits is None
+            else (lambda x: kvwire.quantize_kv(x, bits, gs)))
+    kw = {} if bits is None else dict(bits=bits, group_size=gs)
+    logical = jax.tree.map(
+        lambda a: jnp.stack([a, a]),
+        jax.tree.map(jnp.zeros_like, wire(jnp.zeros((n, ps, kvh, d)))))
+    stored = jax.tree.map(lambda a: jnp.stack([a, a]),
+                          kvwire.make_paged_kv(n, ps, kvh, d, bits, gs))
+
+    def view(t):
+        return jax.tree.map(np.asarray, kvwire.logical_pages(t, ps, kvh))
+
+    def check():
+        jax.tree.map(np.testing.assert_array_equal, view(stored),
+                     jax.tree.map(np.asarray, logical))
+        jax.tree.map(np.testing.assert_array_equal,
+                     kvwire.stored_pages(kvwire.logical_pages(
+                         stored, ps, kvh)), stored)
+
+    check()
+    # a run of 3 tokens for 2 slots into layer 1; slot 1 crosses a page
+    new = jax.random.normal(key, (2, 3, kvh, d))
+    page = jnp.asarray([[2, 2, 2], [5, 5, 7]])
+    row = jnp.asarray([[0, 1, 2], [ps - 2, ps - 1, 0]])
+    stored = kvwire.scatter_tokens(stored, new, page, row, page_size=ps,
+                                   layer=jnp.asarray(1), **kw)
+    logical = jax.tree.map(lambda a, w: a.at[1, page, row].set(w),
+                           logical, wire(new))
+    check()
+    # the prefill bucket: 2 pages of one request into both layers
+    ids = jnp.asarray([3, 4])
+    bucket = jax.random.normal(jax.random.fold_in(key, 1),
+                               (2, 1, 2 * ps, kvh, d))
+    stored = kvwire.scatter_prefill(stored, wire(bucket), ids, stacked=True)
+    logical = jax.tree.map(
+        lambda a, c: a.at[:, ids].set(c.reshape(2, 2, ps, kvh, -1)),
+        logical, wire(bucket))
+    check()
+    # rewind a table [2, 3, 4, 0, ...] past its 5th token
+    table = jnp.asarray([2, 3, 4] + [0] * (n - 3), jnp.int32)
+    stored = kvwire.reset_table_rows(stored, table, jnp.asarray(5),
+                                     page_size=ps, kv_heads=kvh,
+                                     stacked=True)
+    t = np.arange(3 * ps)
+    for i in np.nonzero(t >= 5)[0]:
+        p = int(table[i // ps])
+        logical = jax.tree.map(
+            lambda a, p=p, r=i % ps: a.at[:, p, r].set(0), logical)
+    check()
+    # defrag: new page i takes old page perm[i]
+    perm = jnp.asarray([0, 4, 3, 2, 1, 8, 7, 6, 5])
+    stored = kvwire.permute_pages(stored, perm, stacked=True)
+    logical = jax.tree.map(lambda a: a[:, perm], logical)
+    check()
 
 
 # ---------------------------------------------------------------------------
@@ -334,7 +407,8 @@ def test_hetero_pool_defrag_preserves_contents():
         written.append(w)
     pool.pages["super_segments"] = segs
     tbl = jnp.asarray([pool.table_array(2, 3)])
-    before = [jax.tree.map(lambda a: kvwire.gather_pages(a[0], tbl), w)
+    before = [jax.tree.map(lambda a: kvwire.gather_pages(
+                               a[0], tbl, 4, TINY.n_kv_heads), w)
               for w in written]
 
     pool.free(1)
@@ -343,7 +417,7 @@ def test_hetero_pool_defrag_preserves_contents():
     tbl2 = jnp.asarray([pool.table_array(2, 3)])
     for s, want in enumerate(before):
         got = jax.tree.map(
-            lambda a: kvwire.gather_pages(a[0], tbl2),
+            lambda a: kvwire.gather_pages(a[0], tbl2, 4, TINY.n_kv_heads),
             pool.pages["super_segments"][s][0]["self"]["k"])
         jax.tree.map(lambda a, b: np.testing.assert_array_equal(
             np.asarray(a), np.asarray(b)), want, got)

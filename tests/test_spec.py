@@ -141,12 +141,12 @@ def test_truncate_restores_never_speculated_bytes(kv_map):
                 sample = jax.tree.leaves(leaf)[0]
                 stack = sample.shape[0]
                 one = jax.tree.map(lambda a: a[0], leaf)
-                bits = kvwire.kv_bits_of(one, TINY.head_dim) \
-                    if kvwire.is_quant_kv(one) else None
-                kw2 = ({} if bits is None
-                       else dict(bits=bits, group_size=16))
+                spec = kvwire.page_spec(one, TINY.head_dim,
+                                        4 * TINY.n_kv_heads)
+                kw2 = ({} if spec is None
+                       else dict(bits=spec[0], group_size=16))
                 one = kvwire.scatter_tokens(one, x[:, :n_rows], page_idx,
-                                            row, **kw2)
+                                            row, page_size=4, **kw2)
                 blk["self"]["k"] = jax.tree.map(
                     lambda a: jnp.broadcast_to(a[None],
                                                (stack,) + a.shape), one)
@@ -190,15 +190,15 @@ def test_paired_pool_defrag_permutes_draft_coherently():
     row = jnp.asarray([[0]])
     for side, bits in ((pool.pages, 8), (pool.draft.pages, 2)):
         leaf = jax.tree.map(lambda a: a[0], side["super"][0]["self"]["k"])
-        leaf = kvwire.scatter_tokens(leaf, x, page, row, bits=bits,
-                                     group_size=16)
+        leaf = kvwire.scatter_tokens(leaf, x, page, row, page_size=4,
+                                     bits=bits, group_size=16)
         side["super"][0]["self"]["k"] = jax.tree.map(lambda a: a[None],
                                                      leaf)
 
     def views():
         tbl = jnp.asarray([pool.pages_of(2)], jnp.int32)
         return [jax.tree.map(
-            lambda a: kvwire.gather_pages(a[0], tbl),
+            lambda a: kvwire.gather_pages(a[0], tbl, 4, TINY.n_kv_heads),
             side["super"][0]["self"]["k"])
             for side in (pool.pages, pool.draft.pages)]
 
