@@ -79,7 +79,7 @@ def check_decode_hlo(server):
     text = engine._step_paged.lower(
         engine.params, server.pool.pages, zeros,
         jnp.zeros((pcfg.max_slots, pcfg.pages_per_slot), jnp.int32), zeros,
-        jax.random.key(0)).compile().as_text()
+        (jax.random.key(0), np.uint32(0))).compile().as_text()
     print(f"info: decode step lower+compile for HLO: "
           f"{time.perf_counter() - t:.2f}s")
     calls = [line.split("=")[0].strip() for line in text.splitlines()
@@ -96,7 +96,7 @@ def check_live_pool_attention(server, args):
     pool as live requests left it, first and last layer."""
     from repro.core import kvwire
     from repro.kernels import paged_attention as paged_attn
-    from repro.models import attention
+    from repro.models import attention, transformer
     from repro.serve import RequestParams
 
     cfg, pcfg = server.engine.cfg, server.engine.pcfg
@@ -120,7 +120,8 @@ def check_live_pool_attention(server, args):
     q = jax.random.normal(jax.random.key(5), (len(live), 1, cfg.n_kv_heads,
                           cfg.n_heads // cfg.n_kv_heads, cfg.head_dim),
                           jnp.float32)
-    stack = server.pool.pages["super"][0]["self"]
+    (run,) = transformer.stack_runs(server.pool.pages)   # uniform 4-bit
+    stack = run[0]["self"]
     ps, kvh = pcfg.page_size, cfg.n_kv_heads
     for layer in (0, cfg.n_layers - 1):
         k, v = stack["k"], stack["v"]

@@ -497,7 +497,7 @@ def _cached_attend(q, k, v, cache, cache_pos, kind, causal, window):
         slot = cache_pos % s_len if ring else cache_pos
         with jax.named_scope("kv_write"):
             if spec:
-                # LQ-quantized cache (serve/kvcache.py): write the new slot
+                # LQ-quantized cache (core/kvwire.py): write the new slot
                 # in wire format, attend over the dequantized view.  HBM
                 # holds only packed codes + per-region affine.
                 new_cache = {

@@ -8,7 +8,7 @@ O(L) memory, lax.scan across chunks.  Decode is the O(1) recurrence
 
 LQR applicability (DESIGN.md section 4): in/out/x projections quantize like
 any Dense; there is no KV cache, so the serving-cache quantization feature
-maps to the recurrent state (serve/kvcache.py quantizes h with the same
+maps to the recurrent state (core/kvwire.py quantizes h with the same
 per-region format).
 """
 from __future__ import annotations

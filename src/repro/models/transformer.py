@@ -4,8 +4,9 @@ serving.
 Layer stacking: the repeating block ``pattern`` (P positions) is scan-stacked
 -- params for pattern position j are stacked (S, ...) over S = n_layers // P
 superblocks and iterated with ``lax.scan`` (compact HLO at 94-layer scale);
-the n_layers % P remainder is an unscanned tail.  Caches mirror the same
-(S, ...) layout.
+the n_layers % P remainder is an unscanned tail.  Caches and pools stack
+the same way, one (S_run, ...) run per stretch of superblocks sharing a kv
+wire format (:func:`stack_runs`).
 
 Public surface:
   init_params(cfg, key[, qcfg])          -> params (packed when qcfg given)
@@ -343,223 +344,146 @@ def _scan_run(blk_params, x, aux, cfg: ModelConfig, pattern, policies, *,
     return x, aux, new_pool if pool is not None else ys
 
 
-def _stack_apply(params, x, cfg: ModelConfig, pattern, *,
-                 policy: QuantPolicy, caches=None, cache_pos=None,
-                 enc_out=None, positions=None, page_table=None,
-                 page_size=None, fused=None, training=False):
-    """Run scan-stacked superblocks + tail.  Returns (x, caches, aux).
-
-    With a uniform :class:`QuantPolicy` (and unsegmented params) every
-    superblock runs one shared scan body.  A per-layer
-    :class:`PlanPolicy` — or params pre-segmented by
-    ``quantize_params(plan)`` — routes to the segmented walker, which
-    scans each run of identically-configured superblocks separately.
-    With a ``page_table`` the caches are the paged pool, carried whole
-    through the scan (:func:`_scan_run`).
-    """
-    if isinstance(policy, PlanPolicy) or "super_segments" in params \
-            or (caches is not None and "super_segments" in caches):
-        return _stack_apply_planned(
-            params, x, cfg, pattern, policy=policy, caches=caches,
-            cache_pos=cache_pos, enc_out=enc_out, positions=positions,
-            page_table=page_table, page_size=page_size, fused=fused,
-            training=training)
-    aux_total = _aux_zero(cfg)
-    kw = dict(cache_pos=cache_pos, enc_out=enc_out, positions=positions,
-              page_table=page_table, page_size=page_size, fused=fused)
-    paged = page_table is not None
-    new_sup = sup_caches = caches["super"] if caches is not None else None
-    if params["super"]:
-        # the scan's own ops (slicing each layer's weights, and a
-        # contiguous cache, out of the stack; stacking the new cache) sit
-        # under layer_scan; the body's ops under their layer kind's scope
-        with jax.named_scope("layer_scan"):
-            x, aux_total, new_sup = _scan_run(
-                params["super"], x, aux_total, cfg, pattern,
-                (policy,) * len(pattern),
-                caches=None if paged else sup_caches,
-                pool=sup_caches if paged else None, training=training,
-                **kw)
-
-    new_tail = []
-    for t, tp in enumerate(params["tail"]):
-        spec = pattern[t % len(pattern)]
-        ct = caches["tail"][t] if caches is not None else None
-        x, nc, aux = block_apply(tp, x, spec, cfg, policy=policy, cache=ct,
-                                 **kw)
-        aux_total = _aux_add(aux_total, aux)
-        new_tail.append(nc)
-
-    new_caches = None
-    if caches is not None:
-        new_caches = {"super": new_sup, "tail": new_tail}
-    return x, new_caches, aux_total
-
-
-# ---------------------------------------------------------------------------
-# per-layer (planned) stack walker
-# ---------------------------------------------------------------------------
-
 def plan_segments(configs, p_len: int, n_super: int) -> list:
     """Group consecutive superblocks whose per-position configs match.
 
     Returns ``[(start_super, size, per_position_cfgs), ...]`` — the
     maximal runs a single scan body can cover, so a mostly-uniform plan
-    stays nearly as compact as the uniform scan.  ``configs`` entries may
-    be any hashable per-layer key: plain :class:`QuantConfig` for a
-    weight-only plan, or ``(QuantConfig, kv_bits)`` pairs when the plan
-    also assigns per-layer cache bitwidths — a segment must be uniform in
-    *both* so its stacked cache leaves share one wire shape.
+    stays nearly as compact as one scan.  ``configs`` entries may be any
+    hashable per-layer key: plain :class:`QuantConfig` for a weight-only
+    plan, or ``(QuantConfig, kv_bits)`` pairs when the plan also assigns
+    per-layer cache bitwidths — a segment must be uniform in *both* so
+    its stacked cache leaves share one wire shape.
     """
     return kvwire.segment_runs(configs, p_len, n_super)
 
 
-def _policy_kv_list(policy, n_layers: int) -> tuple:
-    """Per-layer cache bits a (possibly uniform) policy implies."""
-    kv = getattr(policy, "kv_bits", ()) or ()
-    return tuple(kv) if kv else (None,) * n_layers
+def stack_runs(tree) -> list:
+    """The runs of scan-stacked superblocks of a decoder-stack tree, in
+    layer order: each a tuple of per-pattern-position subtrees whose
+    leaves stack the run's superblocks first.
+
+    Uniform params spell their one run ``{"super": run}``; plan-packed
+    params, and every cache and pool, list theirs under
+    ``"super_segments"`` (a uniform cache or pool is one run).
+    """
+    if "super" in tree:
+        return [tree["super"]]
+    return list(tree["super_segments"])
 
 
-def _combined_segments(per_layer, kv_list, p_len: int, n_super: int) -> list:
-    """Walker segments keyed on (weight cfg, kv bits) per layer."""
-    keys = [(pol.cfg, kv_list[i]) for i, pol in enumerate(per_layer)]
-    return plan_segments(keys, p_len, n_super)
+def _run_sizes(runs) -> list:
+    return [jax.tree.leaves(run)[0].shape[0] for run in runs]
 
 
-def _stack_apply_planned(params, x, cfg: ModelConfig, pattern, *, policy,
-                         caches=None, cache_pos=None, enc_out=None,
-                         positions=None, page_table=None, page_size=None,
-                         fused=None, training=False):
-    """Segmented stack walk: one lax.scan per run of identically-configured
-    superblocks, per-layer policies for the tail.  Cache layout is
-    IDENTICAL to the uniform path — segments slice and re-concatenate the
-    (n_super, ...) leading axis inside the jit, so serve pools, wire
-    scatter and checkpoints see the same pytrees either way.  A paged
-    pool (``page_table`` given) is never sliced: each segment carries the
-    stacked run that holds it whole, from the segment's first layer.
+def _run_of(sizes, start: int, size: int, what: str):
+    """The run holding superblocks [start, start+size), and start's
+    offset in it."""
+    run_start = 0
+    for r, n in enumerate(sizes):
+        if run_start <= start and start + size <= run_start + n:
+            return r, start - run_start
+        run_start += n
+    raise ValueError(
+        f"walker segment [{start}, {start + size}) straddles the {what}'s "
+        f"runs of {sizes} superblocks — plan/{what} mismatch")
+
+
+def _rows(run, off: int, size: int, whole: int):
+    if size == whole:
+        return run
+    return jax.tree.map(lambda a: a[off:off + size], run)
+
+
+def _concat(parts):
+    if len(parts) == 1:
+        return parts[0]
+    return jax.tree.map(lambda *leaves: jnp.concatenate(leaves, axis=0),
+                        *parts)
+
+
+def _stack_apply(params, x, cfg: ModelConfig, pattern, *, policy,
+                 caches=None, cache_pos=None, enc_out=None, positions=None,
+                 page_table=None, page_size=None, fused=None,
+                 training=False):
+    """Run the scan-stacked superblocks and the tail.  Returns (x, caches,
+    aux).
+
+    One ``lax.scan`` per segment — a run of superblocks alike in weight
+    config and cache bits — so a uniform :class:`QuantPolicy` is one scan
+    and a per-layer :class:`PlanPolicy` one per segment.  A segment reads
+    the param run and the cache run that hold it (:func:`stack_runs`),
+    sliced only where it is shorter than the run.  A paged pool
+    (``page_table`` given) is never sliced: the segment carries the whole
+    run from its first layer (:func:`_scan_run`).
     """
     p_len = len(pattern)
-    segmented = "super_segments" in params
+    param_runs = stack_runs(params)
+    param_sizes = _run_sizes(param_runs)
+    n_super = sum(param_sizes)
+    n_layers = n_super * p_len + len(params["tail"])
     if isinstance(policy, PlanPolicy):
-        per_layer = [policy.layer(i) for i in range(policy.n_layers)]
-        kv_list = _policy_kv_list(policy, policy.n_layers)
+        if policy.n_layers != n_layers:
+            raise ValueError(f"policy covers {policy.n_layers} layers but "
+                             f"params carry {n_layers}")
+        per_layer = [policy.layer(i) for i in range(n_layers)]
     else:
-        per_layer = [policy] * cfg.n_layers
-        kv_list = _policy_kv_list(policy, cfg.n_layers)
-    n_super = len(per_layer) // p_len
-    n_tail = len(per_layer) - n_super * p_len
-    if segmented:
-        seg_param_list = params["super_segments"]
-    segs = _combined_segments(per_layer, kv_list, p_len, n_super)
-    if segmented and len(segs) != len(seg_param_list):
+        per_layer = [policy] * n_layers
+    kv = getattr(policy, "kv_bits", ()) or (None,) * n_layers
+    segs = plan_segments([(pol.cfg, kv[i]) for i, pol in
+                          enumerate(per_layer)], p_len, n_super)
+    if len(param_runs) > 1 and len(segs) != len(param_runs):
         raise ValueError(
             f"policy implies {len(segs)} segments but params carry "
-            f"{len(seg_param_list)} — plan/params mismatch")
+            f"{len(param_runs)} — plan/params mismatch")
 
     kw = dict(cache_pos=cache_pos, enc_out=enc_out, positions=positions,
               page_table=page_table, page_size=page_size, fused=fused)
     paged = page_table is not None
     aux_total = _aux_zero(cfg)
-    sup_caches = cache_runs = None
-    if caches is not None:
-        if "super_segments" in caches:
-            # heterogeneous cache: one stacked tree per run of superblocks
-            # sharing a kv wire shape (serve/pool.py page geometry)
-            cache_runs = list(caches["super_segments"])
-            run_sizes = [jax.tree.leaves(r)[0].shape[0] for r in cache_runs]
-            run_starts = [sum(run_sizes[:i]) for i in range(len(run_sizes))]
-        else:
-            sup_caches = caches["super"]
-    new_sup_parts = []
-    new_run_parts = [[] for _ in (cache_runs or ())]
-
-    def _cache_run(start, size):
-        """The kv run holding walker segment [start, start+size)."""
-        for r, (rs, rn) in enumerate(zip(run_starts, run_sizes)):
-            if rs <= start and start + size <= rs + rn:
-                return r, start - rs
-        raise ValueError(
-            f"walker segment [{start}, {start + size}) straddles the "
-            f"cache's kv runs {list(zip(run_starts, run_sizes))} — "
-            f"plan/cache kv_bits mismatch")
-
-    def _rows(tree, off, size, whole):
-        if size == whole:
-            return tree
-        return jax.tree.map(lambda a: a[off:off + size], tree)
-
-    for k, (start, size, _) in enumerate(segs):
-        seg_policies = tuple(per_layer[start * p_len + j]
-                             for j in range(p_len))
-        if segmented:
-            seg_params = seg_param_list[k]
-        else:
-            seg_params = jax.tree.map(lambda a: a[start:start + size],
-                                      params["super"])
-        stack = off = run = None
-        if cache_runs is not None:
-            run, off = _cache_run(start, size)
-            stack, whole = cache_runs[run], run_sizes[run]
-        elif sup_caches is not None:
-            stack, off, whole = sup_caches, start, n_super
+    cache_runs = stack_runs(caches) if caches is not None else []
+    cache_sizes = _run_sizes(cache_runs)
+    new_parts = [[] for _ in cache_runs]
+    for start, size, _ in segs:
+        r, off = _run_of(param_sizes, start, size, "params")
+        seg_params = _rows(param_runs[r], off, size, param_sizes[r])
         seg_caches = pool = None
-        if stack is not None and paged:
-            pool = stack
-        elif stack is not None:
-            seg_caches = _rows(stack, off, size, whole)
-
-        # one named scope per walker segment: xprof attributes device time
-        # to the same stack runs serve_phase_ms{layer_run=...} reports;
-        # the scan's own ops sit under layer_scan (_stack_apply)
-        with jax.named_scope(f"segment{k}"), jax.named_scope("layer_scan"):
-            x, aux_total, new_seg = _scan_run(
-                seg_params, x, aux_total, cfg, pattern, seg_policies,
-                caches=seg_caches, pool=pool, layer0=off or 0,
+        c = c_off = 0
+        if caches is not None:
+            c, c_off = _run_of(cache_sizes, start, size, "cache")
+            if paged:
+                pool = cache_runs[c]
+            else:
+                seg_caches = _rows(cache_runs[c], c_off, size, cache_sizes[c])
+        # the scan's own ops (slicing each layer's weights, and a
+        # contiguous cache, out of the stack; stacking the new cache) sit
+        # under layer_scan; the body's ops under their layer kind's scope
+        with jax.named_scope("layer_scan"):
+            x, aux_total, new = _scan_run(
+                seg_params, x, aux_total, cfg, pattern,
+                tuple(per_layer[start * p_len:(start + 1) * p_len]),
+                caches=seg_caches, pool=pool, layer0=c_off,
                 training=training, **kw)
-        if pool is not None and cache_runs is not None:
-            cache_runs[run] = new_seg
-        elif pool is not None:
-            sup_caches = new_seg
-        elif cache_runs is not None:
-            new_run_parts[run].append(new_seg)
-        elif sup_caches is not None:
-            new_sup_parts.append(new_seg)
-
-    def _concat(parts):
-        if len(parts) == 1:
-            return parts[0]
-        return jax.tree.map(lambda *leaves: jnp.concatenate(leaves, axis=0),
-                            *parts)
-
-    new_sup = sup_caches
-    new_runs = None
-    if cache_runs is not None:
-        new_runs = [_concat(parts) if parts else cache_runs[r]
-                    for r, parts in enumerate(new_run_parts)]
-    elif sup_caches is not None and new_sup_parts:
-        new_sup = _concat(new_sup_parts)
+        if pool is not None:
+            cache_runs[c] = new
+        elif caches is not None:
+            new_parts[c].append(new)
+    if caches is not None and not paged:
+        cache_runs = [_concat(parts) if parts else cache_runs[c]
+                      for c, parts in enumerate(new_parts)]
 
     new_tail = []
-    tail_params = params["tail"]
-    if len(tail_params) != n_tail:
-        raise ValueError(f"policy covers {n_tail} tail layers but params "
-                         f"carry {len(tail_params)}")
-    for t, tp in enumerate(tail_params):
-        spec = pattern[t % p_len]
+    for t, tp in enumerate(params["tail"]):
         ct = caches["tail"][t] if caches is not None else None
-        with jax.named_scope(f"tail{t}"):
-            x, nc, aux = block_apply(tp, x, spec, cfg,
-                                     policy=per_layer[n_super * p_len + t],
-                                     cache=ct, **kw)
+        x, nc, aux = block_apply(tp, x, pattern[t % p_len], cfg,
+                                 policy=per_layer[n_super * p_len + t],
+                                 cache=ct, **kw)
         aux_total = _aux_add(aux_total, aux)
         new_tail.append(nc)
 
     new_caches = None
     if caches is not None:
-        if cache_runs is not None:
-            new_caches = {"super_segments": new_runs, "tail": new_tail}
-        else:
-            new_caches = {"super": new_sup, "tail": new_tail}
+        new_caches = {"super_segments": cache_runs, "tail": new_tail}
     return x, new_caches, aux_total
 
 
@@ -665,86 +589,58 @@ def _logits(params, cfg: ModelConfig, x, policy):
     return constrain(logits.astype(jnp.float32), "batch", "seq", "vocab")
 
 
-def normalize_kv_quant(cfg: ModelConfig, kv_quant):
-    """Canonicalize a cache-quantization spec.
+def normalize_kv_quant(cfg: ModelConfig, kv_quant) -> tuple:
+    """The per-layer form of a cache-quantization spec.
 
-    ``kv_quant`` is ``None`` (fp), ``(bits, group_size)`` (uniform), or
-    ``(per_layer_bits, group_size)`` with a length-``n_layers`` sequence of
-    ``bits | None`` entries.  A per-layer map whose entries all agree
-    collapses to the uniform form, so a plan with a uniform ``kv_bits``
-    map builds the exact same cache/pool pytree as the plain path.
+    ``kv_quant`` is ``None`` (fp), ``(bits, group_size)`` (every layer),
+    or ``(per_layer_bits, group_size)`` with a length-``n_layers``
+    sequence of ``bits | None`` entries.  Returns ``(per_layer_bits,
+    group_size)`` with ``None`` for an fp layer.
     """
-    if kv_quant is None:
-        return None
-    bits, gs = kv_quant
-    if isinstance(bits, (tuple, list)):
-        bits = tuple(bits)
-        if len(bits) != cfg.n_layers:
-            raise ValueError(f"per-layer kv_bits has {len(bits)} entries "
-                             f"for {cfg.n_layers} layers")
-        for b in bits:
-            kvwire.check_kv_bits(b)
-        if any(b != bits[0] for b in bits):
-            return (bits, gs)
-        bits = bits[0]
-    if bits is None:
-        return None
-    kvwire.check_kv_bits(bits)
-    return (bits, gs)
+    bits, gs = kv_quant if kv_quant is not None else (None, None)
+    if not isinstance(bits, (tuple, list)):
+        bits = (bits,) * cfg.n_layers
+    elif len(bits) != cfg.n_layers:
+        raise ValueError(f"per-layer kv_bits has {len(bits)} entries "
+                         f"for {cfg.n_layers} layers")
+    for b in bits:
+        kvwire.check_kv_bits(b)
+    return tuple(bits), gs
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int,
                dtype=None, kv_quant=None, whole: bool = False) -> dict:
-    """Decode cache.  ``kv_quant=(bits, group_size)`` stores attention K/V
-    in the LQ wire format (bits in {8,4,2,1}; group_size divides head_dim);
-    ``bits`` may be a per-layer sequence (see :func:`normalize_kv_quant`),
-    in which case superblocks are stacked per run of identical kv bits
-    under a ``"super_segments"`` key — packed wire shapes differ across
-    bitwidths, so heterogeneous layers cannot share one stacked array.
-    ``whole`` gives sliding-window layers every one of ``max_len``
-    positions instead of a ``window``-long ring (the paged prefill's
-    bucket, scattered page for page into the pool).
+    """Decode cache ``{"super_segments": [run, ...], "tail": [...],
+    "pos": ()}``.  ``kv_quant=(bits, group_size)`` stores attention K/V
+    in the LQ wire format (bits in {8,4,2,1}; group_size divides
+    head_dim); ``bits`` may be a per-layer sequence (see
+    :func:`normalize_kv_quant`).  Superblocks stack per run of identical
+    kv bits — packed wire shapes differ across bitwidths — so a uniform
+    spec is one run.  ``whole`` gives sliding-window layers every one of
+    ``max_len`` positions instead of a ``window``-long ring (the paged
+    prefill's bucket, scattered page for page into the pool).
     """
     dtype = dtype or cfg.activation_dtype
     cross = cfg.n_enc_layers > 0
-    kv_quant = normalize_kv_quant(cfg, kv_quant)
+    bits, gs = normalize_kv_quant(cfg, kv_quant)
     p_len = len(cfg.pattern)
-    per_layer = kv_quant is not None and isinstance(kv_quant[0], tuple)
 
-    def layer_kvq(i: int):
-        if not per_layer:
-            return kv_quant
-        b = kv_quant[0][i]
-        return None if b is None else (b, kv_quant[1])
-
-    def stacked(stack: int, spec, kvq):
-        one = _block_cache(cfg, spec, batch, max_len, cross, dtype, kvq,
-                           whole)
+    def block(spec, b, stack=None):
+        one = _block_cache(cfg, spec, batch, max_len, cross, dtype,
+                           None if b is None else (b, gs), whole)
+        if stack is None:
+            return one
         return jax.tree.map(
             lambda a: jnp.zeros((stack,) + a.shape, a.dtype), one)
 
-    tail = [_block_cache(cfg, cfg.pattern[(cfg.n_super * p_len + t) % p_len],
-                         batch, max_len, cross, dtype,
-                         layer_kvq(cfg.n_super * p_len + t), whole)
+    n0 = cfg.n_super * p_len
+    tail = [block(cfg.pattern[(n0 + t) % p_len], bits[n0 + t])
             for t in range(cfg.n_tail)]
     out = {"tail": tail, "pos": jnp.zeros((), jnp.int32)}
-    if per_layer:
-        runs = plan_segments(list(kv_quant[0]), p_len, cfg.n_super)
-        out["super_segments"] = [
-            tuple(stacked(size, spec,
-                          None if key[j] is None else (key[j], kv_quant[1]))
-                  for j, spec in enumerate(cfg.pattern))
-            for _, size, key in runs]
-    else:
-        out["super"] = tuple(stacked(cfg.n_super, spec, kv_quant)
-                             for spec in cfg.pattern)
+    out["super_segments"] = [
+        tuple(block(spec, key[j], size) for j, spec in enumerate(cfg.pattern))
+        for _, size, key in plan_segments(list(bits), p_len, cfg.n_super)]
     return out
-
-
-def _layer_caches(cache) -> dict:
-    """The decoder-stack view of a cache dict (either super layout)."""
-    key = "super_segments" if "super_segments" in cache else "super"
-    return {key: cache[key], "tail": cache["tail"]}
 
 
 def prefill(params, cfg: ModelConfig, batch, cache, *,
@@ -769,7 +665,7 @@ def prefill(params, cfg: ModelConfig, batch, cache, *,
     with jax.named_scope("prefill"):
         x, new_caches, _ = _stack_apply(
             params["decoder"], x, cfg, cfg.pattern, policy=policy,
-            caches=_layer_caches(cache),
+            caches=cache,
             cache_pos=None, enc_out=enc_out, positions=None)
     if logits_pos is None:
         x = x[:, -1:]
@@ -793,7 +689,7 @@ def decode_step(params, cfg: ModelConfig, tokens, cache, *,
     with jax.named_scope("decode_step"):
         x, new_caches, _ = _stack_apply(
             params["decoder"], x, cfg, cfg.pattern, policy=policy,
-            caches=_layer_caches(cache),
+            caches=cache,
             cache_pos=pos, enc_out=None, positions=None)
     with jax.named_scope("lm_head"):
         x = _norm_apply(cfg, params["final_norm"], x)
@@ -807,16 +703,16 @@ def paged_decode_step(params, cfg: ModelConfig, tokens, pages, page_table,
                       fused=None):
     """One continuous-batching decode step over a paged KV pool.
 
-    tokens (B, 1) int32; pages {'super': ..., 'tail': ...} with shared
-    page leaves in the stored layout (``core/kvwire.py``: (n_pages, ...)
-    per layer, an (n_super, ...) lead for the scan-stacked ones), updated
-    in place; page_table (B, P) int32
-    physical page ids per slot (scratch page 0 pads unused entries); pos
-    (B,) int32 — the absolute position each slot's token is written at;
-    ``page_size`` the pool's tokens per page.
-    Inactive slots point at the scratch page and are masked by the caller.
-    ``fused`` ('pallas' | 'interpret' | None) routes every layer's
-    attention through the fused paged kernel instead of gather+dequant.
+    tokens (B, 1) int32; pages {'super_segments': [run, ...], 'tail': ...}
+    with shared page leaves in the stored layout (``core/kvwire.py``:
+    (n_pages, ...) per layer, a run's superblocks stacked ahead of that),
+    updated in place; page_table (B, P) int32 physical page ids per slot
+    (scratch page 0 pads unused entries); pos (B,) int32 — the absolute
+    position each slot's token is written at; ``page_size`` the pool's
+    tokens per page.  Inactive slots point at the scratch page and are
+    masked by the caller.  ``fused`` ('pallas' | 'interpret' | None)
+    routes every layer's attention through the fused paged kernel
+    instead of gather+dequant.
     Returns (logits (B, 1, V), new pages, aux): ``aux`` as
     :func:`_aux_zero`, summed over the layers.
     """
@@ -828,7 +724,7 @@ def paged_decode_step(params, cfg: ModelConfig, tokens, pages, page_table,
     with jax.named_scope("paged_decode_step"):
         x, new_pages, aux = _stack_apply(
             params["decoder"], x, cfg, cfg.pattern, policy=policy,
-            caches=_layer_caches(pages),
+            caches=pages,
             cache_pos=pos, enc_out=None, positions=pos[:, None],
             page_table=page_table, page_size=page_size, fused=fused)
     with jax.named_scope("lm_head"):
@@ -862,7 +758,7 @@ def paged_decode_multi(params, cfg: ModelConfig, tokens, pages, page_table,
     with jax.named_scope("paged_decode_multi"):
         x, new_pages, _ = _stack_apply(
             params["decoder"], x, cfg, cfg.pattern, policy=policy,
-            caches=_layer_caches(pages),
+            caches=pages,
             cache_pos=pos, enc_out=None, positions=positions,
             page_table=page_table, page_size=page_size, fused=fused)
     with jax.named_scope("lm_head"):
@@ -933,8 +829,8 @@ def quantize_params(params, cfg: ModelConfig, qcfg, *,
     whole tree, or a :class:`repro.plan.QuantPlan` (anything exposing
     ``resolve(cfg)``): decoder layers are packed per the plan, with
     consecutive identically-configured superblocks re-stacked into
-    ``super_segments`` so the planned scan walker keeps one compiled body
-    per segment; non-decoder leaves (embed / lm_head / encoder) stay fp.
+    ``super_segments`` so the stack walker keeps one compiled body per
+    segment; non-decoder leaves (embed / lm_head / encoder) stay fp.
 
     ``leaf_cache`` dedups packed leaves across plans over ONE shared base
     checkpoint: segment subtrees are keyed on ``(start, size, position,
@@ -995,7 +891,7 @@ def _quantize_params_plan(params, cfg: ModelConfig, plan, *,
         return leaf_cache[key]
 
     # segment on the combined (weight, kv) key so param segments line up
-    # with the planned walker's — a kv boundary splits the scan even when
+    # with the stack walker's — a kv boundary splits the scan even when
     # the weight scheme is unchanged across it
     segs = plan_segments(list(zip(configs, kv)), p_len, cfg.n_super)
     seg_trees = []

@@ -67,31 +67,22 @@ class NumericsConfig:
 # ---------------------------------------------------------------------------
 
 def layer_blocks(tree, cfg):
-    """Yield ``(layer_idx, block)`` over a cache/pool/params decoder tree.
-
-    Handles the homogeneous ``"super"`` layout (per-position trees whose
-    leaves stack ``n_super`` first) and the heterogeneous
-    ``"super_segments"`` layout (one such tuple per run of superblocks);
-    blocks come out with the stack dim sliced away, in layer order
-    ``superblock * p_len + position`` then the tail.
+    """Yield ``(layer_idx, block)`` over a cache/pool/params decoder tree,
+    in layer order: each of ``transformer.stack_runs(tree)`` with the
+    stack dim sliced away (``superblock * p_len + position``), then the
+    tail.
     """
     p_len = len(cfg.pattern)
-    if "super_segments" in tree:
-        start = 0
-        for seg in tree["super_segments"]:
-            size = jax.tree.leaves(seg[0])[0].shape[0]
-            for s in range(size):
-                for j, block in enumerate(seg):
-                    yield ((start + s) * p_len + j,
-                           jax.tree.map(lambda a, s=s: a[s], block))
-            start += size
-    else:
-        for s in range(cfg.n_super):
-            for j, block in enumerate(tree["super"]):
-                yield (s * p_len + j,
+    start = 0
+    for run in transformer.stack_runs(tree):
+        size = jax.tree.leaves(run)[0].shape[0]
+        for s in range(size):
+            for j, block in enumerate(run):
+                yield ((start + s) * p_len + j,
                        jax.tree.map(lambda a, s=s: a[s], block))
+        start += size
     for t, block in enumerate(tree["tail"]):
-        yield (cfg.n_super * p_len + t, block)
+        yield (start * p_len + t, block)
 
 
 def _layer_label(i: int) -> str:

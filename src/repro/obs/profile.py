@@ -89,19 +89,15 @@ def xprof_capture(out_dir: str):
 def _pool_runs(pages) -> list:
     """``[(layer_run, block_tuple, stacked)]`` over a pool's stack runs.
 
-    One entry per scan run of the pool (homogeneous ``super``, or one per
-    heterogeneous ``super_segments`` run) plus one per tail block — the
-    same granularity the planned-stack walker compiles at, so phase times
-    attribute to the units that can actually be optimized separately.
+    One entry per run of the pool (``transformer.stack_runs``: one per kv
+    wire format) plus one per tail block — the granularity the stack
+    walker scans at, so phase times attribute to the units that can
+    actually be optimized separately.
     """
-    runs = []
-    if "super_segments" in pages:
-        for r, seg in enumerate(pages["super_segments"]):
-            runs.append((f"run{r}", seg, True))
-    elif pages.get("super"):
-        runs.append(("run0", pages["super"], True))
-    for t, block in enumerate(pages["tail"]):
-        runs.append((f"tail{t}", (block,), False))
+    runs = [(f"run{r}", run, True)
+            for r, run in enumerate(transformer.stack_runs(pages))]
+    runs += [(f"tail{t}", (block,), False)
+             for t, block in enumerate(pages["tail"])]
     return runs
 
 
@@ -143,7 +139,7 @@ class PhaseProfiler:
         self._lm_head = None
         pcfg = self.core.pcfg
         g = cfg.n_heads // cfg.n_kv_heads
-        key = jax.random.key(0)
+        key = self._key = jax.random.key(0)
         # fixed synthetic query / pre-lm-head activation: phase cost
         # depends on shapes and cache contents, not these values
         self._q = jax.random.normal(
@@ -306,8 +302,9 @@ class PhaseProfiler:
             # full-step replay through the engine's own compiled jit: same
             # shapes as the serving calls, so no new trace is cut
             # (decode_compilations stays 1) and the probe's own key never
-            # advances the scheduler's sampling stream.  The step takes its
-            # pages donated, so it replays on a copy: the live pool is
+            # advances the scheduler's sampling stream; its inputs are host
+            # arrays, as the engine's own calls pass them.  The step takes
+            # its pages donated, so it replays on a copy: the live pool is
             # left as it was
             look = getattr(self.engine, "lookahead_tokens", 1)
             pages = jax.block_until_ready(jax.tree.map(jnp.copy, pool.pages))
@@ -316,12 +313,12 @@ class PhaseProfiler:
                     run = np.tile(tokens[:, None], (1, look))
                     _, replay_ms = self._timed(
                         self.core._multi_paged, self.core.params,
-                        pages, jnp.asarray(run), jtable, jpos)
+                        pages, run, table, pos)
                 else:
                     _, replay_ms = self._timed(
                         self.core._step_paged, self.core.params, pages,
-                        jnp.asarray(tokens), jtable, jpos,
-                        jax.random.fold_in(jax.random.key(0), self.steps))
+                        tokens, table, pos,
+                        (self._key, np.uint32(self.steps)))
             m.histogram("serve_step_replay_ms").record(replay_ms)
             # the device time the sub-phase replays do not account for
             # (embed, QKV/out/FFN matmuls, scatter, sampling)

@@ -171,9 +171,10 @@ def _iter_layer_params(params, model_cfg):
     """Yield (block_params, spec) per decoder layer, unstacking the scan."""
     dec = params["decoder"]
     p_len = len(model_cfg.pattern)
-    for s in range(model_cfg.n_super):
-        for j, spec in enumerate(model_cfg.pattern):
-            yield jax.tree.map(lambda a, s=s: a[s], dec["super"][j]), spec
+    for run in transformer.stack_runs(dec):
+        for s in range(jax.tree.leaves(run)[0].shape[0]):
+            for j, spec in enumerate(model_cfg.pattern):
+                yield jax.tree.map(lambda a, s=s: a[s], run[j]), spec
     for t, blk in enumerate(dec["tail"]):
         yield blk, model_cfg.pattern[t % p_len]
 

@@ -261,18 +261,24 @@ class PagedEngine(Engine):
         """Scatter a contiguous B=1 prefill cache into pool pages.
 
         The bucket cache and the pool share one decoder-stack layout
-        (homogeneous ``"super"`` or heterogeneous ``"super_segments"`` —
-        both built from the engine's kv spec), so the copy is structural:
-        ``scatter_prefill`` tree-maps leaf-for-leaf at whatever wire
-        format each layer carries.
+        (both built from the engine's kv spec), so the copy is
+        structural: ``scatter_prefill`` tree-maps leaf-for-leaf at
+        whatever wire format each layer carries.
         """
-        sup_key = "super_segments" if "super_segments" in pages else "super"
         with jax.named_scope("kv_write"):
-            return {sup_key: kvwire.scatter_prefill(pages[sup_key],
-                                                    cache[sup_key], page_ids,
-                                                    stacked=True),
+            return {"super_segments": kvwire.scatter_prefill(
+                        pages["super_segments"], cache["super_segments"],
+                        page_ids, stacked=True),
                     "tail": kvwire.scatter_prefill(pages["tail"],
                                                    cache["tail"], page_ids)}
+
+    def _sample_last(self, logits, key):
+        """Sample the last position's logits with ``fold_in(*key)``.
+        ``key`` is the caller's (key, fold count) pair: the step programs
+        fold it, so the loop between programs makes no eager JAX call."""
+        base, fold = key
+        with jax.named_scope("sample"):
+            return self._sample(logits[:, -1], jax.random.fold_in(base, fold))
 
     def _prefill_paged_impl(self, params, tokens, pages, page_ids,
                             logits_pos, key):
@@ -282,16 +288,14 @@ class PagedEngine(Engine):
             params, self.cfg, {"tokens": tokens}, cache, policy=self.policy,
             logits_pos=logits_pos)
         pages = self._scatter_bucket(pages, cache, page_ids)
-        with jax.named_scope("sample"):
-            return self._sample(logits[:, -1], key), pages
+        return self._sample_last(logits, key), pages
 
     def _step_paged_impl(self, params, pages, tokens, page_table, pos, key):
         logits, pages, aux = transformer.paged_decode_step(
             params, self.cfg, tokens[:, None], pages, page_table, pos,
             page_size=self.pcfg.page_size, policy=self.policy,
             fused=self.fused_mode)
-        with jax.named_scope("sample"):
-            toks = self._sample(logits[:, -1], key)
+        toks = self._sample_last(logits, key)
         if self.counts_experts:
             return (toks, jnp.stack([aux["experts_hit"],
                                      aux["expert_rows_max"]])), pages
@@ -306,9 +310,10 @@ class PagedEngine(Engine):
 
     # --------------------------------------------------------------- host
     def prefill_request(self, pool: PagedKVPool, tokens, page_ids,
-                        key) -> int:
-        """Prefill one request into its pages; returns the sampled first
-        continuation token.  ``tokens`` is the (unpadded) int prompt."""
+                        key, fold: int) -> int:
+        """Prefill one request into its pages; returns the first
+        continuation token, sampled with ``fold_in(key, fold)``.
+        ``tokens`` is the (unpadded) int prompt."""
         bucket = self.pcfg.max_context
         if len(tokens) > bucket:
             raise ValueError(f"prompt len {len(tokens)} > bucket {bucket}")
@@ -316,7 +321,7 @@ class PagedEngine(Engine):
         sw = Stopwatch(obs.clock) if obs.enabled else None
         with obs.tracer.span("prefill", n_tokens=len(tokens),
                              **self.obs_metric_labels):
-            tok = self._prefill_host(pool, tokens, page_ids, key)
+            tok = self._prefill_host(pool, tokens, page_ids, key, fold)
             if sw is not None:
                 # measured wall clock brackets the compiled step end to
                 # end: the scattered pages, not just the token
@@ -328,14 +333,14 @@ class PagedEngine(Engine):
         return tok
 
     def _prefill_host(self, pool: PagedKVPool, tokens, page_ids,
-                      key) -> int:
+                      key, fold: int) -> int:
         padded = np.zeros((1, self.pcfg.max_context), np.int32)
         padded[0, :len(tokens)] = tokens
         ids = np.zeros((self.pcfg.pages_per_slot,), np.int32)
         ids[:len(page_ids)] = page_ids
         tok, pool.pages = self._prefill_paged(
-            self.params, jnp.asarray(padded), pool.pages,
-            jnp.asarray(ids), jnp.asarray(len(tokens) - 1, jnp.int32), key)
+            self.params, padded, pool.pages, ids, np.int32(len(tokens) - 1),
+            (key, np.uint32(fold)))
         return int(self._fetch(tok)[0])
 
     def _fetch(self, toks, counts=None) -> np.ndarray:
@@ -362,16 +367,17 @@ class PagedEngine(Engine):
                 return np.asarray(toks)
 
     def decode_step_batch(self, pool: PagedKVPool, tokens, page_table, pos,
-                          key) -> np.ndarray:
+                          key, fold: int) -> np.ndarray:
         """Advance every slot one token.  tokens/pos (max_slots,),
-        page_table (max_slots, pages_per_slot).  Returns sampled tokens."""
+        page_table (max_slots, pages_per_slot).  Returns the tokens
+        sampled with ``fold_in(key, fold)``."""
         obs = self.obs
         sw = Stopwatch(obs.clock) if obs.enabled else None
         with obs.tracer.span("decode_step"):
             toks, pool.pages = self._step_paged(
-                self.params, pool.pages, jnp.asarray(tokens, jnp.int32),
-                jnp.asarray(page_table, jnp.int32),
-                jnp.asarray(pos, jnp.int32), key)
+                self.params, pool.pages, np.asarray(tokens, np.int32),
+                np.asarray(page_table, np.int32), np.asarray(pos, np.int32),
+                (key, np.uint32(fold)))
         counts = None
         if self.counts_experts:
             toks, counts = toks
@@ -391,8 +397,8 @@ class PagedEngine(Engine):
         (max_slots, L) — all L candidates' K/V are written to the pool, so
         rejected suffixes must be un-written via ``pool.truncate``."""
         toks, pool.pages = self._multi_paged(
-            self.params, pool.pages, jnp.asarray(tokens, jnp.int32),
-            jnp.asarray(page_table, jnp.int32), jnp.asarray(pos, jnp.int32))
+            self.params, pool.pages, np.asarray(tokens, np.int32),
+            np.asarray(page_table, np.int32), np.asarray(pos, np.int32))
         return np.asarray(toks)
 
     # ------------------------------------------------------- scheduler API
@@ -403,13 +409,14 @@ class PagedEngine(Engine):
         return 1
 
     def advance_slots(self, pool: PagedKVPool, tokens, page_table, pos,
-                      key, budget=None):
+                      key, fold: int, budget=None):
         """Scheduler step contract: advance every slot, returning
         ``(emitted, rejected)`` — per-slot lists of emitted tokens and
         per-slot rejected-draft counts.  The plain engine emits exactly
         one token per slot and never rejects; ``budget`` (per-slot max
         tokens to emit) is honored trivially."""
-        toks = self.decode_step_batch(pool, tokens, page_table, pos, key)
+        toks = self.decode_step_batch(pool, tokens, page_table, pos, key,
+                                      fold)
         return [[int(t)] for t in toks], [0] * len(toks)
 
     @property

@@ -2,9 +2,9 @@
 
 The pool owns every layer's K/V storage as shared page arrays in one
 stored layout (core/kvwire.py: a page's features on sublanes, its
-page_size*KV rows on lanes) — ``(n_super, n_pages, ...)`` for
-scan-stacked superblock positions, ``(n_pages, ...)`` for the unscanned
-tail — in the LQ wire format when ``kv_bits`` is set or fp otherwise.
+page_size*KV rows on lanes) — ``(run_size, n_pages, ...)`` per run of
+scan-stacked superblocks, ``(n_pages, ...)`` for the unscanned tail —
+in the LQ wire format when ``kv_bits`` is set or fp otherwise.
 The engine's step programs take the pool donated and update it in
 place.  Requests own ordered page lists (page tables); the device-side
 gather/scatter lives in core/kvwire.py and models/attention.py; this class
@@ -47,17 +47,17 @@ def _check_paged_support(cfg: ModelConfig):
 
 def make_pool_pages(cfg: ModelConfig, *, n_pages: int, page_size: int,
                     kv_bits=None, kv_group: int = 64, dtype=None):
-    """Build the zero-initialized page pytree of a :class:`PagedKVPool`.
+    """Build the zero-initialized page pytree of a :class:`PagedKVPool`:
+    ``{"super_segments": [run, ...], "tail": [...]}``, the layout of
+    ``transformer.init_cache``.
 
     ``kv_bits`` is ``None`` (fp), one int (every layer shares the wire
     format), or a per-layer sequence of ``bits | None`` — the
     heterogeneous page geometry of a mixed-KV :class:`~repro.plan.QuantPlan`.
-    Homogeneous pools stack superblock leaves under ``"super"`` as before;
-    a genuinely mixed map stores one stacked leaf per run of superblocks
-    sharing a wire shape under ``"super_segments"`` (packed widths differ,
-    so heterogeneous layers cannot share an array), mirroring
-    ``transformer.init_cache``.  Page ids stay *global*: page ``p`` of
-    every layer's array belongs to the same request, whatever that
+    Superblock leaves stack per run of superblocks sharing a wire shape
+    (packed widths differ, so heterogeneous layers cannot share an
+    array): a uniform map is one run.  Page ids stay *global*: page ``p``
+    of every layer's array belongs to the same request, whatever that
     layer's bitwidth — only the bytes behind a page differ per layer.
 
     Module-level so callers can price a pool without materializing it:
@@ -70,41 +70,30 @@ def make_pool_pages(cfg: ModelConfig, *, n_pages: int, page_size: int,
     _check_paged_support(cfg)
     if n_pages < 2:
         raise ValueError("need at least one allocatable page + scratch")
-    kvq = normalize_kv_quant(cfg, (kv_bits, kv_group))
-    per_layer = kvq is not None and isinstance(kvq[0], tuple)
-    if kvq is not None and cfg.head_dim % kv_group:
+    bits, _ = normalize_kv_quant(cfg, (kv_bits, kv_group))
+    if any(b is not None for b in bits) and cfg.head_dim % kv_group:
         raise ValueError(f"head_dim={cfg.head_dim} not divisible by "
                          f"kv_group={kv_group}")
     dtype = dtype or cfg.activation_dtype
 
-    def leaf(stack: int | None, bits):
+    def leaf(b, stack):
         one = kvwire.make_paged_kv(n_pages, page_size, cfg.n_kv_heads,
-                                   cfg.head_dim, bits, kv_group, dtype)
+                                   cfg.head_dim, b, kv_group, dtype)
         if stack is None:
             return one
         return jax.tree.map(
             lambda a: jnp.zeros((stack,) + a.shape, a.dtype), one)
 
-    p_len = len(cfg.pattern)
-    if per_layer:
-        bits_list = kvq[0]
-        runs = kvwire.segment_runs(list(bits_list), p_len, cfg.n_super)
-        sup = [tuple({"self": {"k": leaf(size, key[j]),
-                               "v": leaf(size, key[j])}}
-                     for j in range(p_len))
-               for _, size, key in runs]
-        tail = [{"self": {"k": leaf(None, bits_list[cfg.n_super * p_len + t]),
-                          "v": leaf(None, bits_list[cfg.n_super * p_len + t])}}
-                for t in range(cfg.n_tail)]
-        return {"super_segments": sup, "tail": tail}
+    def block(b, stack=None):
+        return {"self": {"k": leaf(b, stack), "v": leaf(b, stack)}}
 
-    bits = None if kvq is None else kvq[0]
-    sup = tuple({"self": {"k": leaf(cfg.n_super, bits),
-                          "v": leaf(cfg.n_super, bits)}}
-                for _ in cfg.pattern)
-    tail = [{"self": {"k": leaf(None, bits), "v": leaf(None, bits)}}
-            for _ in range(cfg.n_tail)]
-    return {"super": sup, "tail": tail}
+    p_len = len(cfg.pattern)
+    runs = kvwire.segment_runs(list(bits), p_len, cfg.n_super)
+    n0 = cfg.n_super * p_len
+    return {"super_segments": [tuple(block(key[j], size)
+                                     for j in range(p_len))
+                               for _, size, key in runs],
+            "tail": [block(bits[n0 + t]) for t in range(cfg.n_tail)]}
 
 
 def pool_nbytes(cfg: ModelConfig, *, n_pages: int, page_size: int,
@@ -142,17 +131,14 @@ class PagedKVPool:
         self.pages = make_pool_pages(cfg, n_pages=n_pages,
                                      page_size=page_size, kv_bits=kv_bits,
                                      kv_group=kv_group, dtype=dtype)
-        sup_key = ("super_segments" if "super_segments" in self.pages
-                   else "super")
         self._permute = jax.jit(lambda pages, perm: {
-            sup_key: kvwire.permute_pages(pages[sup_key], perm,
-                                          stacked=True),
+            "super_segments": kvwire.permute_pages(
+                pages["super_segments"], perm, stacked=True),
             "tail": kvwire.permute_pages(pages["tail"], perm)})
         self._reset_table = jax.jit(lambda pages, table, keep: {
-            sup_key: kvwire.reset_table_rows(pages[sup_key], table, keep,
-                                             page_size=page_size,
-                                             kv_heads=cfg.n_kv_heads,
-                                             stacked=True),
+            "super_segments": kvwire.reset_table_rows(
+                pages["super_segments"], table, keep, page_size=page_size,
+                kv_heads=cfg.n_kv_heads, stacked=True),
             "tail": kvwire.reset_table_rows(pages["tail"], table, keep,
                                             page_size=page_size,
                                             kv_heads=cfg.n_kv_heads)})

@@ -227,9 +227,12 @@ class Scheduler:
     def _requeue_front(self, req: Request):
         self._lanes.setdefault(req.priority, deque()).appendleft(req)
 
-    def _fold_key(self):
+    def _next_fold(self) -> int:
+        """The next fold of the sampling key: the engine's program folds
+        it into ``self._key``, so the loop between programs makes no
+        eager JAX call."""
         self._key_folds += 1
-        return jax.random.fold_in(self._key, self._key_folds)
+        return self._key_folds
 
     # -------------------------------------------------------------- admit
     def _admit(self, events: list[Completion]):
@@ -267,8 +270,8 @@ class Scheduler:
             self.obs.tracer.complete("queued", req.t_queued, wait,
                                      tid=req.trace_tid, rid=req.rid)
         first = self.engine.prefill_request(
-            self.pool, tokens, self.pool.pages_of(req.rid),
-            self._fold_key())
+            self.pool, tokens, self.pool.pages_of(req.rid), self._key,
+            self._next_fold())
         slot = self._slots.index(None)
         req.state = RUNNING
         if resume:
@@ -394,7 +397,7 @@ class Scheduler:
                          n_slots=len(active), **live) as span:
             emitted, rejected = self.engine.advance_slots(
                 self.pool, self._last_tok, table, pos.astype(np.int32),
-                self._fold_key(), budget=budget)
+                self._key, self._next_fold(), budget=budget)
             counts = getattr(self.engine, "step_counts", None)
             if recording and counts:
                 span.set_metadata(**counts)

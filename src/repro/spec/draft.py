@@ -19,7 +19,7 @@ import numpy as np
 
 
 def draft_proposals(draft_engine, draft_pool, tokens, page_table, pos,
-                    k: int, key) -> np.ndarray:
+                    k: int, key, fold: int) -> np.ndarray:
     """Propose ``k`` greedy continuations per slot.
 
     ``tokens``/``pos`` are (max_slots,) — each slot's pending token and
@@ -33,6 +33,6 @@ def draft_proposals(draft_engine, draft_pool, tokens, page_table, pos,
     out = np.zeros((cur.shape[0], k), np.int32)
     for i in range(k):
         cur = draft_engine.decode_step_batch(draft_pool, cur, page_table,
-                                             pos + i, key)
+                                             pos + i, key, fold)
         out[:, i] = cur
     return out

@@ -201,11 +201,12 @@ class SpeculativeEngine:
                             draft_kv_group=dg, obs=self._obs)
 
     def prefill_request(self, pool: PairedKVPool, tokens, page_ids,
-                        key) -> int:
+                        key, fold: int) -> int:
         """Prefill the prompt into BOTH sides' pages (same ids); the
         emitted first token is the verifier's (token-exactness)."""
-        self.draft.prefill_request(pool.draft, tokens, page_ids, key)
-        return self.verifier.prefill_request(pool, tokens, page_ids, key)
+        self.draft.prefill_request(pool.draft, tokens, page_ids, key, fold)
+        return self.verifier.prefill_request(pool, tokens, page_ids, key,
+                                             fold)
 
     # ------------------------------------------------------- scheduler API
     @property
@@ -214,7 +215,7 @@ class SpeculativeEngine:
         return self.spec_k + 1
 
     def advance_slots(self, pool: PairedKVPool, tokens, page_table, pos,
-                      key, budget=None):
+                      key, fold: int, budget=None):
         """One speculative cycle for every slot: draft k, verify once,
         accept the longest matching prefix.  Returns per-slot emission
         lists (1..k verifier-greedy tokens each) and per-slot rejected
@@ -225,7 +226,7 @@ class SpeculativeEngine:
         sw = Stopwatch(obs.clock) if obs.enabled else None
         with obs.tracer.span("draft", k=k):
             props = draft_proposals(self.draft, pool.draft, tokens,
-                                    page_table, pos, k, key)
+                                    page_table, pos, k, key, fold)
             if sw is not None:
                 jax.block_until_ready(pool.draft.pages)
         if sw is not None:

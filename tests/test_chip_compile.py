@@ -275,7 +275,8 @@ def _paged_program(one_chip, arch, n_layers, fused, program="decode",
         lambda a: _shape(one_chip, a.shape, a.dtype), t)
     b, pps = pcfg.max_slots, pcfg.pages_per_slot
     i32 = jnp.int32
-    key = jax.eval_shape(lambda: jax.random.key(0))
+    key = (jax.eval_shape(lambda: jax.random.key(0)),
+           jax.ShapeDtypeStruct((), jnp.uint32))
     if program == "prefill":
         args = put((eng.params, jax.ShapeDtypeStruct((1, pcfg.max_context),
                                                      i32), pages,

@@ -157,7 +157,7 @@ def test_mixed_kv_pricing_matches_exact_pool_bytes(params):
     tenant = reg.register(spec)
     assert tenant.pool_bytes == want_p == tenant.pool.nbytes()
     # the engine's actual pool is genuinely heterogeneous
-    assert "super_segments" in tenant.pool.pages
+    assert len(transformer.stack_runs(tenant.pool.pages)) > 1
 
 
 def test_mixed_kv_tenants_fit_where_uniform8_do_not(params):

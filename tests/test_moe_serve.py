@@ -62,7 +62,7 @@ def _served(model, fused):
         ids[:len(pool.pages_of(b))] = pool.pages_of(b)
         lg, pool.pages = eng._prefill_paged(
             eng.params, jnp.asarray(padded), pool.pages, jnp.asarray(ids),
-            jnp.asarray(n - 1, jnp.int32), key)
+            jnp.asarray(n - 1, jnp.int32), (key, np.uint32(b)))
         got[b].append(np.asarray(lg[0]))
     for j in range(STEPS):
         pos = np.asarray([n + j for n in PROMPTS], np.int32)
@@ -73,7 +73,7 @@ def _served(model, fused):
         toks = np.asarray([seqs[b][pos[b]] for b in (0, 1)], np.int32)
         (lg, counts), pool.pages = eng._step_paged(
             eng.params, pool.pages, jnp.asarray(toks), jnp.asarray(table),
-            jnp.asarray(pos), key)
+            jnp.asarray(pos), (key, np.uint32(j)))
         assert int(counts[0]) >= md["top_k"]
         for b in range(2):
             got[b].append(np.asarray(lg[b]))

@@ -13,7 +13,7 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core import schemes
+from repro.core import kvwire, schemes
 from repro.models import transformer
 from repro.models.config import ModelConfig
 from repro.obs import (FlightRecorder, MetricsServer, Observability,
@@ -142,6 +142,70 @@ def test_layer_blocks_enumerates_params_in_order(params):
     blocks = dict(layer_blocks(params["decoder"], TINY))
     leaves = jax.tree.leaves(blocks[0])
     assert all(leaf.ndim >= 1 for leaf in leaves)    # stack dim sliced away
+
+
+# two pattern positions over five layers: two superblocks and a tail layer
+TWO = ModelConfig(name="two", family="dense", n_layers=5, d_model=64,
+                  vocab_size=256, n_heads=4, n_kv_heads=2, head_dim=16,
+                  d_ff=128, dtype="float32", remat="none", window=8,
+                  pattern=(("attn", "swiglu"), ("attn_local", "swiglu")))
+
+
+def _weight_bits(block):
+    return getattr(block["mixer"]["wq"]["w"], "bits", None)
+
+
+def _cache_bits(block):
+    k = block["self"]["k"]
+    return kvwire.kv_bits_of(k, TWO.head_dim) if kvwire.is_quant_kv(k) \
+        else None
+
+
+def _page_bits(block):
+    spec = kvwire.page_spec(block["self"]["k"], TWO.head_dim,
+                            4 * TWO.n_kv_heads)
+    return spec[0] if spec else None
+
+
+def _stack_tree(kind):
+    """(tree, per-layer bits, bits reader, run sizes) of one tree kind."""
+    from repro.plan import QuantPlan
+    from repro.serve import make_pool_pages
+    if kind == "uniform_params":
+        tree = transformer.init_params(TWO, jax.random.key(0))["decoder"]
+        return tree, (None,) * 5, _weight_bits, [2]
+    if kind == "plan_params":
+        c = candidates_for(TWO, ["lq8w", "lq4w"])
+        plan = QuantPlan.from_assignment(
+            {"layer.0": c["lq8w"], "layer.1": c["lq8w"]}, default=c["lq4w"])
+        fp = transformer.init_params(TWO, jax.random.key(0))
+        tree = transformer.quantize_params(fp, TWO, plan)["decoder"]
+        return tree, (8, 8, 4, 4, 4), _weight_bits, [1, 1]
+    if kind == "contiguous_cache":
+        bits = (8, 8, 2, 2, 4)
+        return (transformer.init_cache(TWO, 1, 16, kv_quant=(bits, 16)),
+                bits, _cache_bits, [1, 1])
+    bits = 4 if kind == "uniform_pool" else (8, 8, None, None, 2)
+    pages = make_pool_pages(TWO, n_pages=4, page_size=4, kv_bits=bits,
+                            kv_group=16)
+    if kind == "uniform_pool":
+        return pages, (4,) * 5, _page_bits, [2]
+    return pages, bits, _page_bits, [1, 1]
+
+
+@pytest.mark.parametrize("kind", ["uniform_params", "plan_params",
+                                  "contiguous_cache", "uniform_pool",
+                                  "mixed_pool"])
+def test_stack_runs_give_every_layer_once_in_order(kind):
+    """``transformer.stack_runs`` reads any decoder-stack tree as its runs
+    in layer order, and ``layer_blocks`` walks them layer by layer."""
+    tree, want_bits, bits_of, want_sizes = _stack_tree(kind)
+    runs = transformer.stack_runs(tree)
+    sizes = [jax.tree.leaves(run)[0].shape[0] for run in runs]
+    assert sizes == want_sizes and sum(sizes) == TWO.n_super
+    blocks = list(layer_blocks(tree, TWO))
+    assert [i for i, _ in blocks] == list(range(TWO.n_layers))
+    assert tuple(bits_of(block) for _, block in blocks) == want_bits
 
 
 # ---------------------------------------------------------------------------

@@ -279,7 +279,7 @@ def test_fused_survives_preemption_mid_stream(params):
 
 
 def test_fused_hetero_kv_plan_matches_baseline(params):
-    """Per-layer kv bits (super_segments layout): each stack run launches
+    """Per-layer kv bits (one pool run per format): each stack run launches
     the fused kernel on its own wire format; tokens still exact."""
     plan = QuantPlan.uniform("fp32").with_kv(
         {"layer.0": 8, "layer.2": 2}, default=None, kv_group=16)
@@ -288,7 +288,7 @@ def test_fused_hetero_kv_plan_matches_baseline(params):
     prompts, max_new = _prompts(), [10, 6, 8]
     base = EngineConfig(max_len=32, plan=plan, backend="ref")
     ref, rsrv = _serve(params, base, pcfg, prompts, max_new)
-    assert "super_segments" in rsrv.pool.pages     # genuinely mixed
+    assert len(transformer.stack_runs(rsrv.pool.pages)) > 1  # mixed
     out, srv = _serve(params,
                       dataclasses.replace(base, fused_attention=True),
                       pcfg, prompts, max_new)

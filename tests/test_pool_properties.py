@@ -24,6 +24,7 @@ except ModuleNotFoundError:
     HAVE_HYPOTHESIS = False
 
 from repro.core import kvwire
+from repro.models import transformer
 from repro.models.config import ModelConfig
 from repro.serve import PagedKVPool, pool_nbytes
 
@@ -139,11 +140,18 @@ if HAVE_HYPOTHESIS:
 
 
 def _segment_bits(kv_map):
-    """The kv bits of each ``super_segments`` run of the pool: runs of
-    equal consecutive layers share one stacked array, so segment ``s``
-    is not layer ``s`` once a bit width repeats."""
+    """The kv bits of each run of the pool (``transformer.stack_runs``):
+    runs of equal consecutive layers share one stacked array, so run
+    ``s`` is not layer ``s`` once a bit width repeats."""
     return [key[0] for _, _, key in
             kvwire.segment_runs(list(kv_map), 1, TINY.n_layers)]
+
+
+def _runs(pool, kv_map):
+    """The pool's runs, one per run of equal consecutive kv bits."""
+    runs = transformer.stack_runs(pool.pages)
+    assert len(runs) == len(_segment_bits(kv_map))
+    return runs
 
 
 def _defrag_data_check(kv_map, sizes, victim):
@@ -163,8 +171,7 @@ def _defrag_data_check(kv_map, sizes, victim):
     toks = {r: jax.random.normal(jax.random.key(r),
                                  (1, 1, TINY.n_kv_heads, TINY.head_dim))
             for r in rids}
-    for bits, seg in zip(_segment_bits(kv_map),
-                         pool.pages["super_segments"]):
+    for bits, seg in zip(_segment_bits(kv_map), _runs(pool, kv_map)):
         kw = {} if bits is None else dict(bits=bits, group_size=KV_GROUP)
         leaf = jax.tree.map(lambda a: a[0], seg[0]["self"]["k"])
         for r in rids:
@@ -177,7 +184,7 @@ def _defrag_data_check(kv_map, sizes, victim):
     def slot_views():
         """{(seg, rid): full gathered wire view of rid's pages}."""
         out = {}
-        for s, seg in enumerate(pool.pages["super_segments"]):
+        for s, seg in enumerate(_runs(pool, kv_map)):
             leaf = jax.tree.map(lambda a: a[0], seg[0]["self"]["k"])
             for r in rids:
                 if r == victim and victim_freed[0]:
@@ -235,8 +242,7 @@ def _truncate_data_check(kv_map, keep_tokens):
     total = n_pages_each * PAGE_SIZE
     x = jax.random.normal(jax.random.key(7),
                           (1, total, TINY.n_kv_heads, TINY.head_dim))
-    for bits, seg in zip(_segment_bits(kv_map),
-                         pool.pages["super_segments"]):
+    for bits, seg in zip(_segment_bits(kv_map), _runs(pool, kv_map)):
         kw = {} if bits is None else dict(bits=bits, group_size=KV_GROUP)
         leaf = jax.tree.map(lambda a: a[0], seg[0]["self"]["k"])
         for r in rids:
@@ -254,7 +260,7 @@ def _truncate_data_check(kv_map, keep_tokens):
             lambda a: np.asarray(kvwire.gather_pages(
                 a[0], tbl, PAGE_SIZE, TINY.n_kv_heads)),
             seg[0]["self"]["k"])
-            for seg in pool.pages["super_segments"]]
+            for seg in _runs(pool, kv_map)]
 
     before = {r: rows_of(r) for r in rids}
     old_pages_1 = pool.pages_of(1)
@@ -271,10 +277,10 @@ def _truncate_data_check(kv_map, keep_tokens):
         return np.asarray(kvwire.logical_pages(a[0], PAGE_SIZE,
                                                TINY.n_kv_heads))
 
-    for s, seg in enumerate(pool.pages["super_segments"]):
+    for s, seg in enumerate(_runs(pool, kv_map)):
         leaf = jax.tree.map(logical, seg[0]["self"]["k"])
         fresh_leaf = jax.tree.map(
-            logical, fresh.pages["super_segments"][s][0]["self"]["k"])
+            logical, _runs(fresh, kv_map)[s][0]["self"]["k"])
         view = before[1][s]          # gathered (1, total, ...) pre-rewind
         for t in range(len(kept_pages) * PAGE_SIZE):
             got = jax.tree.map(
@@ -330,12 +336,8 @@ def _poison_scratch(pool):
 def _iter_page_leaves(pool):
     """Every (n_pages, ...) stored page array of the pool, destacked."""
     pages = pool.pages
-    blocks = []
-    if "super_segments" in pages:
-        for seg in pages["super_segments"]:
-            blocks.extend((blk, True) for blk in seg)
-    elif pages.get("super"):
-        blocks.extend((blk, True) for blk in pages["super"])
+    blocks = [(blk, True) for run in transformer.stack_runs(pages)
+              for blk in run]
     for blk in pages.get("tail", ()):
         blocks.append((blk, False))
     for blk, stacked in blocks:

@@ -301,7 +301,7 @@ def decode_ir(request, params):
     lowered = eng._step_paged.lower(
         eng.params, server.pool.pages, jnp.zeros((2,), jnp.int32),
         jnp.zeros((2, 8), jnp.int32), jnp.zeros((2,), jnp.int32),
-        jax.random.key(0))
+        (jax.random.key(0), np.uint32(0)))
     return lowered.compiler_ir().operation.get_asm(enable_debug_info=True)
 
 

@@ -108,6 +108,42 @@ def test_temperature_sampling_runs(setup):
     assert int(out.max()) < 256
 
 
+def test_serving_loop_traces_nothing_between_programs(setup):
+    """After eager vmapped ops elsewhere in the process (the kind
+    tests/test_expert_matmul.py runs), a warm Server's steps record no
+    jaxpr trace: the sampling key is folded inside the step programs and
+    host arrays go to them as they are, so the loop between programs
+    makes no eager JAX call."""
+    from repro.kernels import ref as kref
+    from repro.serve import PagedConfig, RequestParams, Server
+    params, _ = setup
+    w = jax.random.normal(jax.random.key(3), (4, 128, 32), jnp.float32)
+    packed, scale, zmin = jax.vmap(
+        lambda ww: kref.quantize_weight(ww, 4, 128))(w)
+    jax.block_until_ready(jax.vmap(
+        lambda p, s, z: kref.dequantize_weight(p, s, z, 4, 128))(
+            packed, scale, zmin))
+    srv = Server(TINY, params, EngineConfig(max_len=32, backend="ref"),
+                 PagedConfig(max_slots=2, page_size=4, n_pages=24,
+                             max_context=32), seed=0)
+    srv.submit([1, 2, 3, 4, 5], RequestParams(max_new_tokens=3))
+    srv.drain(max_steps=50)                   # compiles both programs
+    traced = []
+
+    def on_event(name, *_, **__):
+        if name == "/jax/core/compile/jaxpr_trace_duration":
+            traced.append(name)
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        for prompt in ([7, 8, 9], [10, 11, 12, 13, 14, 15]):
+            srv.submit(prompt, RequestParams(max_new_tokens=4))
+        srv.drain(max_steps=50)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert traced == []
+
+
 def test_lut_serving_path(setup):
     """Paper section V: 8-bit weights + 2-bit LUT activations serve."""
     params, batch = setup

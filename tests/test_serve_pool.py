@@ -68,7 +68,9 @@ def test_pool_defrag_preserves_contents(kv_bits):
                        kv_group=16)
     pool.alloc(1, 2), pool.alloc(2, 3), pool.alloc(3, 1)
     # write recognizable data into request 2's pages (layer pattern pos 0)
-    leaf = pool.pages["super"][0]["self"]["k"]
+    runs = transformer.stack_runs(pool.pages)
+    assert len(runs) == 1                      # a uniform map is one run
+    leaf = runs[0][0]["self"]["k"]
     x = jax.random.normal(jax.random.key(0),
                           (TINY.n_super, 3 * 4, TINY.n_kv_heads,
                            TINY.head_dim))
@@ -76,10 +78,7 @@ def test_pool_defrag_preserves_contents(kv_bits):
               else kvwire.quantize_kv(x[:, None], kv_bits, 16))
     ids = jnp.asarray(pool.pages_of(2), jnp.int32)
     written = kvwire.scatter_prefill(leaf, contig, ids, stacked=True)
-    pool.pages["super"] = (dict(pool.pages["super"][0],
-                                self={"k": written,
-                                      "v": pool.pages["super"][0]["self"]["v"]}),
-                           ) + pool.pages["super"][1:]
+    runs[0][0]["self"]["k"] = written
     tbl_before = jnp.asarray([pool.table_array(2, 3)])
     before = jax.tree.map(lambda a: kvwire.gather_pages(   # superblock 0's
         a[0], tbl_before, 4, TINY.n_kv_heads), written)     # page view
@@ -92,7 +91,7 @@ def test_pool_defrag_preserves_contents(kv_bits):
     tbl_after = jnp.asarray([pool.table_array(2, 3)])
     after = jax.tree.map(
         lambda a: kvwire.gather_pages(a[0], tbl_after, 4, TINY.n_kv_heads),
-        pool.pages["super"][0]["self"]["k"])
+        transformer.stack_runs(pool.pages)[0][0]["self"]["k"])
     jax.tree.map(lambda a, b: np.testing.assert_array_equal(
         np.asarray(a), np.asarray(b)), before, after)
     assert pool.n_free == pool.n_allocatable - pool.n_allocated
@@ -366,12 +365,11 @@ def _kv_plan(kv_map, default=None):
 
 def test_hetero_pool_layout_and_bytes():
     """A mixed kv map stores one stacked leaf per run of same-format
-    superblocks; a uniform map collapses to the homogeneous layout."""
+    superblocks; a uniform map collapses to one run."""
     from repro.serve import cache_nbytes, make_pool_pages, pool_nbytes
     mixed = PagedKVPool(TINY, n_pages=8, page_size=4, kv_bits=(8, None, 2),
                         kv_group=16)
-    assert list(mixed.pages) == ["super_segments", "tail"]
-    assert len(mixed.pages["super_segments"]) == 3
+    assert len(transformer.stack_runs(mixed.pages)) == 3
     assert pool_nbytes(TINY, n_pages=8, page_size=4, kv_bits=(8, None, 2),
                        kv_group=16) == mixed.nbytes()
     uni = make_pool_pages(TINY, n_pages=8, page_size=4, kv_bits=(2, 2, 2),
@@ -379,6 +377,7 @@ def test_hetero_pool_layout_and_bytes():
     ref = make_pool_pages(TINY, n_pages=8, page_size=4, kv_bits=2,
                           kv_group=16)
     assert jax.tree.structure(uni) == jax.tree.structure(ref)
+    assert len(transformer.stack_runs(ref)) == 1
     assert cache_nbytes(uni) == cache_nbytes(ref)
     # mixed sits strictly between its narrowest and widest uniform pools
     lo = pool_nbytes(TINY, n_pages=8, page_size=4, kv_bits=2, kv_group=16)
@@ -395,7 +394,8 @@ def test_hetero_pool_defrag_preserves_contents():
     x = jax.random.normal(jax.random.key(0),
                           (1, 3 * 4, TINY.n_kv_heads, TINY.head_dim))
     ids = jnp.asarray(pool.pages_of(2), jnp.int32)
-    segs = list(pool.pages["super_segments"])
+    segs = transformer.stack_runs(pool.pages)
+    assert len(segs) == 3
     written = []
     for s, seg in enumerate(segs):
         leaf = seg[0]["self"]["k"]
@@ -403,9 +403,8 @@ def test_hetero_pool_defrag_preserves_contents():
         contig = (x[:, None] if bits is None
                   else kvwire.quantize_kv(x[:, None], bits, 16))
         w = kvwire.scatter_prefill(leaf, contig, ids, stacked=True)
-        segs[s] = (dict(seg[0], self={"k": w, "v": seg[0]["self"]["v"]}),)
+        seg[0]["self"]["k"] = w
         written.append(w)
-    pool.pages["super_segments"] = segs
     tbl = jnp.asarray([pool.table_array(2, 3)])
     before = [jax.tree.map(lambda a: kvwire.gather_pages(
                                a[0], tbl, 4, TINY.n_kv_heads), w)
@@ -418,7 +417,7 @@ def test_hetero_pool_defrag_preserves_contents():
     for s, want in enumerate(before):
         got = jax.tree.map(
             lambda a: kvwire.gather_pages(a[0], tbl2, 4, TINY.n_kv_heads),
-            pool.pages["super_segments"][s][0]["self"]["k"])
+            transformer.stack_runs(pool.pages)[s][0]["self"]["k"])
         jax.tree.map(lambda a, b: np.testing.assert_array_equal(
             np.asarray(a), np.asarray(b)), want, got)
 
@@ -449,8 +448,8 @@ def test_uniform_kv_plan_matches_uniform_kv_engine(params, kv_bits):
         outs.append([done[r] for r in rids])
         assert srv.engine.decode_compilations == 1
     assert outs[0] == outs[1] == ref
-    # and the plan's pool collapsed to the homogeneous layout
-    assert "super" in srv_plan.engine.new_pool().pages
+    # and the plan's pool collapsed to one run
+    assert len(transformer.stack_runs(srv_plan.engine.new_pool().pages)) == 1
 
 
 def test_mixed_kv_paged_matches_solo_reference(params):
@@ -482,7 +481,7 @@ def test_mixed_kv_paged_matches_solo_reference(params):
     for rid, want in zip((r0, r1, r2), solo):
         assert outs[rid] == want
     assert srv.engine.decode_compilations == 1
-    assert "super_segments" in srv.pool.pages  # genuinely heterogeneous
+    assert len(transformer.stack_runs(srv.pool.pages)) > 1  # heterogeneous
 
 
 def test_mixed_weights_and_kv_paged_matches_solo(params):

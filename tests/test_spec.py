@@ -88,7 +88,7 @@ def test_decode_multi_matches_sequential_steps(params, kv_bits):
         pool = eng.new_pool()
         assert pool.alloc(0, 4)
         first = eng.prefill_request(pool, prompt, pool.pages_of(0),
-                                    jax.random.key(0))
+                                    jax.random.key(0), 1)
         return pool, first
 
     pool_a, first = prefilled()
@@ -103,7 +103,7 @@ def test_decode_multi_matches_sequential_steps(params, kv_bits):
     seq = []
     for i in range(run.shape[1]):
         toks = eng.decode_step_batch(pool_b, run[:, i], table, pos0 + i,
-                                     jax.random.key(1))
+                                     jax.random.key(1), i)
         seq.append(toks)
     seq = np.stack(seq, axis=1)
     np.testing.assert_array_equal(greedy_multi[0], seq[0])
@@ -131,12 +131,10 @@ def test_truncate_restores_never_speculated_bytes(kv_map):
         wpos = np.arange(n_rows)
         page_idx = jnp.asarray([[ids[p // 4] for p in wpos]])
         row = jnp.asarray([wpos % 4])
-        sup_key = ("super_segments" if "super_segments" in pool.pages
-                   else "super")
-        segs = list(pool.pages[sup_key])
-        for s, seg in enumerate(segs):
-            seg = list(seg) if isinstance(seg, tuple) else [seg]
-            for j, blk in enumerate(seg):
+        runs = transformer.stack_runs(pool.pages)
+        assert len(runs) == (3 if kv_map == (8, None, 2) else 1)
+        for seg in runs:
+            for blk in seg:
                 leaf = blk["self"]["k"]
                 sample = jax.tree.leaves(leaf)[0]
                 stack = sample.shape[0]
@@ -150,9 +148,6 @@ def test_truncate_restores_never_speculated_bytes(kv_map):
                 blk["self"]["k"] = jax.tree.map(
                     lambda a: jnp.broadcast_to(a[None],
                                                (stack,) + a.shape), one)
-            segs[s] = tuple(seg)
-        pool.pages[sup_key] = (segs if sup_key == "super_segments"
-                               else tuple(segs))
         return pool
 
     full = build_and_write(10)
@@ -188,18 +183,21 @@ def test_paired_pool_defrag_permutes_draft_coherently():
                           (1, 1, TINY.n_kv_heads, TINY.head_dim))
     page = jnp.asarray([[pool.pages_of(2)[0]]])
     row = jnp.asarray([[0]])
+    def block0(side):
+        (run,) = transformer.stack_runs(side)  # a uniform map is one run
+        return run[0]["self"]
+
     for side, bits in ((pool.pages, 8), (pool.draft.pages, 2)):
-        leaf = jax.tree.map(lambda a: a[0], side["super"][0]["self"]["k"])
+        leaf = jax.tree.map(lambda a: a[0], block0(side)["k"])
         leaf = kvwire.scatter_tokens(leaf, x, page, row, page_size=4,
                                      bits=bits, group_size=16)
-        side["super"][0]["self"]["k"] = jax.tree.map(lambda a: a[None],
-                                                     leaf)
+        block0(side)["k"] = jax.tree.map(lambda a: a[None], leaf)
 
     def views():
         tbl = jnp.asarray([pool.pages_of(2)], jnp.int32)
         return [jax.tree.map(
             lambda a: kvwire.gather_pages(a[0], tbl, 4, TINY.n_kv_heads),
-            side["super"][0]["self"]["k"])
+            block0(side)["k"])
             for side in (pool.pages, pool.draft.pages)]
 
     before = views()
@@ -380,7 +378,7 @@ def test_draft_shadow_mirrors_verifier_plan_kv_map(params):
     assert eng.draft._kv_layout == eng.verifier._kv_layout
     pool = eng.new_pool()
     assert pool.draft_nbytes() == pool.nbytes()     # same wire geometry
-    assert "super_segments" in pool.draft.pages     # genuinely per-layer
+    assert len(transformer.stack_runs(pool.draft.pages)) > 1  # per-layer
     fp = PagedKVPool(TINY, n_pages=PCFG.n_pages,
                      page_size=PCFG.page_size).nbytes()
     assert pool.draft_nbytes() < fp                 # not fp fallback
